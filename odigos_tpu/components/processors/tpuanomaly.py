@@ -87,10 +87,10 @@ class TpuAnomalyProcessor(Processor):
     pipeline_depth / bucket_ladder / warm_ladder:
         forwarded to EngineConfig (pipeline_depth 2 = double-buffered
         scoring: host packing overlaps device execution)
-    failover: circuit-broken CPU fallback (ISSUE 13) — ``true`` or a
+    failover: circuit-broken fallback model (ISSUE 13) — ``true`` or a
         {window_s, trip_errors, probe_interval_s, recovery_successes,
         fallback_model} mapping; a persistent device fault hot-swaps
-        scoring to the zscore CPU route, raises ModelFailover, and
+        scoring to the zscore fallback, raises ModelFailover, and
         half-open probes the primary back (serving/failover.py)
     shared_engine: reuse one engine across processor instances (default True)
     """

@@ -188,9 +188,11 @@ class AnomalyStageConfiguration:
     # ({} = defaults; keys per serving/failover.FailoverConfig —
     # window_s, trip_errors, probe_interval_s, recovery_successes,
     # fallback_model) rendered as the tpuanomaly processor's
-    # ``failover:`` knob. A persistent device fault then hot-swaps
-    # scoring to the zscore CPU route (ModelFailover condition,
+    # ``failover:`` knob. A persistent fault of the primary model then
+    # hot-swaps scoring to the zscore fallback (ModelFailover condition,
     # odigos_failover_* metrics) and half-open probes the primary back.
+    # zscore is a jitted JAX kernel on the process's default device —
+    # the same chip on a TPU host, not the CPU.
     # None renders nothing — existing configs stay byte-identical.
     failover: Optional[dict] = None
 

@@ -187,7 +187,7 @@ def inject_device_fault(env: E2EEnvironment,
                         message: str = "chaos: device lost") -> None:
     """Persistent device loss on every gateway scoring engine: each
     PRIMARY-backend dispatch raises until cleared. With a failover
-    breaker configured the engine trips to its CPU fallback
+    breaker configured the engine trips to its zscore fallback
     (ModelFailover); without one, frames forward unscored with the
     error counted — both are scenarios in the matrix."""
     engines = _gateway_engines(env)

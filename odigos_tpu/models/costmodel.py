@@ -24,14 +24,19 @@ back in; the ledger publishes:
   reads its fraction of that — how far each bucket runs from what the
   hardware demonstrably does on this very computation).
 
-Everything degrades to a graceful no-op where the backend exposes no
-analysis (``cost_analysis`` absent, raising, or returning nothing):
-the skip is counted, no row is written, serving is never disturbed.
+Capture runs on the serving path (a cold dispatch), so it never
+raises — but it tells its two ways of writing no row apart. A backend
+that exposes no analysis (jax returns ``None``) is *unavailable*: the
+skip is counted. Anything that raises — a callable that is not a jit,
+a lowering the compiler refuses — is a *failure*: counted separately,
+its exception text kept in the snapshot and logged, so that an empty
+ledger on a new backend says why it is empty.
 Deliberately jax-free at import time, like jitstats.
 """
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Any, Optional
@@ -48,13 +53,7 @@ XLA_EFFICIENCY_METRIC = "odigos_xla_achieved_efficiency"
 # grow an unbounded dict
 MAX_ROWS = 256
 
-
-def _cost_dict(analysis: Any) -> dict:
-    """Normalize ``cost_analysis()``'s return across jax versions: a
-    dict on ``Lowered``, a one-element list of dicts on ``Compiled``."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    return analysis if isinstance(analysis, dict) else {}
+_log = logging.getLogger(__name__)
 
 
 class CostLedger:
@@ -65,6 +64,8 @@ class CostLedger:
         self._rows: dict[tuple, dict] = {}
         self._best_flops_per_s: dict[str, float] = {}
         self._skipped = 0
+        self._failed = 0
+        self._last_error: Optional[str] = None
 
     # ---------------------------------------------------------- capture
 
@@ -73,28 +74,44 @@ class CostLedger:
                 n_real: Optional[int] = None,
                 n_padded: Optional[int] = None,
                 memory: bool = False) -> Optional[dict]:
-        """Lower ``fn`` for ``args`` and record XLA's cost model for the
-        (site, bucket). ``Lowered.cost_analysis()`` needs no compile;
-        ``memory=True`` additionally AOT-compiles for
-        ``memory_analysis()`` — a second executable, so callers only arm
-        it where a compile is being paid anyway and attribution asked
-        for depth. Returns the row, or None on graceful no-op."""
+        """Lower ``fn`` (a jitted callable) for ``args`` and record
+        XLA's cost model for the (site, bucket). Backends that price a
+        lowering answer from ``Lowered.cost_analysis()`` with no
+        compile; those that only price executables (it returns ``None``
+        there) are asked again after an AOT compile, which
+        ``memory=True`` pays anyway for ``memory_analysis()``. The AOT
+        executable is a second one beside the jit's own, so callers
+        capture only where a compile is being paid already (ladder
+        warming, a cold fused key). Returns the row, or None when no
+        row was written."""
         try:
             lowered = fn.lower(*args, **(kwargs or {}))
-            cost = _cost_dict(lowered.cost_analysis())
-            flops = float(cost.get("flops", 0.0) or 0.0)
-            bytes_accessed = float(cost.get("bytes accessed", 0.0) or 0.0)
+            cost = lowered.cost_analysis()
+            compiled = lowered.compile() \
+                if (cost is None or memory) else None
+            if cost is None:
+                cost = compiled.cost_analysis()
             mem = None
             if memory:
-                stats = lowered.compile().memory_analysis()
-                mem = {
-                    k: int(getattr(stats, f"{k}_in_bytes", 0) or 0)
-                    for k in ("generated_code_size", "argument_size",
-                              "output_size", "temp_size")}
-        except Exception:  # noqa: BLE001 — backend exposes no analysis
+                stats = compiled.memory_analysis()
+                if stats is not None:
+                    mem = {
+                        k: int(getattr(stats, f"{k}_in_bytes"))
+                        for k in ("generated_code_size", "argument_size",
+                                  "output_size", "temp_size")}
+        except Exception as e:  # noqa: BLE001 — a cold dispatch keeps serving
+            error = f"{site}[{bucket}]: {type(e).__name__}: {e}"
+            with self._lock:
+                self._failed += 1
+                self._last_error = error
+            _log.warning("xla cost capture failed for %s", error)
+            return None
+        if cost is None:  # backend exposes no analysis
             with self._lock:
                 self._skipped += 1
             return None
+        flops = float(cost.get("flops", 0.0))
+        bytes_accessed = float(cost.get("bytes accessed", 0.0))
         if flops <= 0.0 and bytes_accessed <= 0.0:
             with self._lock:
                 self._skipped += 1
@@ -187,16 +204,20 @@ class CostLedger:
         with self._lock:
             rows = [dict(r) for r in self._rows.values()]
             best = dict(self._best_flops_per_s)
-            skipped = self._skipped
+            skipped, failed = self._skipped, self._failed
+            last_error = self._last_error
         rows.sort(key=lambda r: (r["site"], r["bucket"]))
         return {"rows": rows, "best_flops_per_s": best,
-                "captures_skipped": skipped}
+                "captures_skipped": skipped, "captures_failed": failed,
+                "last_error": last_error}
 
     def reset(self) -> None:
         with self._lock:
             self._rows.clear()
             self._best_flops_per_s.clear()
             self._skipped = 0
+            self._failed = 0
+            self._last_error = None
 
 
 cost_ledger = CostLedger()

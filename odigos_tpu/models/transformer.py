@@ -37,23 +37,6 @@ SHAPE_BUCKETING = {
 }
 
 
-def serving_donation(argnums: tuple[int, ...],
-                     enabled: bool) -> tuple[int, ...]:
-    """Donate per-call input buffers on TPU only, and only when the owner
-    opted in (the serving engine does — its pack stage materializes fresh
-    arrays every call, so donated buffers are never reused). Donation is a
-    no-op-with-a-warning on CPU, and callers that re-time the same staged
-    arrays (tools/quant_geometry.py, tools/layer_ablation.py, eval loops)
-    must keep it off or the second call reads a deleted buffer."""
-    if not enabled:
-        return ()
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — no device runtime: serve undonated
-        return ()
-    return argnums if backend == "tpu" else ()
-
-
 @dataclass(frozen=True)
 class TransformerConfig:
     service_vocab: int = 512
@@ -99,16 +82,16 @@ class TraceTransformer:
     def __init__(self, config: TransformerConfig | None = None):
         self.cfg = config or TransformerConfig()
         self.module = _TraceTransformerModule(self.cfg)
-        self._score_packed_jit = None  # built lazily: donation is opt-in
-        self._donate_inputs = False
-
-    def enable_input_donation(self) -> None:
-        """Opt this instance into donating packed input buffers on TPU
-        (serving engine only — every engine call passes freshly
-        materialized arrays). Must be called before the first
-        ``score_packed`` to take effect on the compiled function."""
-        self._donate_inputs = True
-        self._score_packed_jit = None
+        # packed-rows scoring (features.pack_sequences): block-diagonal
+        # attention per trace chunk; returns (R, L) span probabilities.
+        # Jitted per instance (it closes over this model's module), and
+        # the jit object itself is the public entry so callers can also
+        # lower it (models/costmodel.py). Inputs are NOT donated: on the
+        # v5e XLA refused three of the four packed buffers (shapes differ
+        # from the output) and the fourth bought nothing measurable.
+        score_packed = jax.jit(self._score_packed_impl)
+        self.score_packed = jitstats.track_jit("transformer.score_packed",
+                                               score_packed)
 
     def init(self, rng: jax.Array, sample_cat=None, sample_cont=None,
              sample_mask=None):
@@ -136,32 +119,13 @@ class TraceTransformer:
 
     def _score_packed_impl(self, variables, categorical, continuous,
                            segments, positions):
+        """The traced body of ``score_packed``. The per-row trace head is
+        meaningless under packing and skipped."""
         mask = segments > 0
         span_logit, _ = self.module.apply(
             variables, categorical, continuous, mask,
             positions=positions, segments=segments)
         return jax.nn.sigmoid(span_logit)
-
-    def score_packed(self, variables, categorical, continuous, segments,
-                     positions):
-        """Packed-rows scoring (features.pack_sequences): block-diagonal
-        attention per trace chunk; returns (R, L) span probabilities. The
-        per-row trace head is meaningless under packing and skipped.
-
-        Jitted lazily so the packed input buffers (not the variables —
-        those persist across calls) can be donated on TPU when the owner
-        opted in via ``enable_input_donation``: the serving engine
-        re-materializes inputs every call, so their HBM can host the
-        output instead of churning allocations at north-star call rates.
-        """
-        if self._score_packed_jit is None:
-            self._score_packed_jit = jitstats.track_jit(
-                "transformer.score_packed", jax.jit(
-                    self._score_packed_impl,
-                    donate_argnums=serving_donation((1, 2, 3, 4),
-                                                    self._donate_inputs)))
-        return self._score_packed_jit(variables, categorical, continuous,
-                                      segments, positions)
 
     def loss_fn(self, variables, categorical, continuous, mask,
                 span_labels, trace_labels, rngs=None):

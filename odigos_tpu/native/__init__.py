@@ -1,14 +1,20 @@
 """Native (C++) runtime pieces, built on demand with g++.
 
-The compiled library is cached under ``native/build/`` and rebuilt when the
-source is newer — the ``go build``-like experience the reference gets from
-its toolchain. Import ``lib()`` to get the ctypes handle; the higher-level
-Python API lives in ``odigos_tpu.transport``.
+The compiled library is cached under ``native/build/`` (ignored by git)
+under a name keyed on what produced it: the bytes of ``spanring.cpp``,
+the compiler flags, and — because ``-march=native`` makes the output a
+function of the machine — the host CPU's feature flags. A tree copied
+from another machine or another revision therefore rebuilds unless all
+three match; file times are never consulted. Import ``lib()`` to get
+the ctypes handle; the higher-level Python API lives in
+``odigos_tpu.transport``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,7 +22,7 @@ import threading
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "spanring.cpp")
 _BUILD_DIR = os.path.join(_HERE, "build")
-_SO = os.path.join(_BUILD_DIR, "libspanring.so")
+_FLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC", "-march=native")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -30,15 +36,43 @@ u8 = ctypes.c_uint8
 p = ctypes.POINTER
 
 
-def _build() -> None:
+def _host_cpu_flags() -> bytes:
+    """What ``-march=native`` resolves against on this machine."""
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"flags", b"Features")):
+                    return line
+    except OSError:
+        pass
+    return b""
+
+
+def _so_path() -> str:
+    """The library path for the CURRENT source, flags and host CPU."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_host_cpu_flags())
+    return os.path.join(_BUILD_DIR, f"libspanring-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = f"{_SO}.{os.getpid()}.tmp"  # per-process: concurrent cold builds
+    tmp = f"{so}.{os.getpid()}.tmp"  # per-process: concurrent cold builds
     # race only through the atomic os.replace, never through the same file
-    subprocess.run(
-        ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-march=native",
-         _SRC, "-o", tmp],
-        check=True, capture_output=True)
-    os.replace(tmp, _SO)
+    subprocess.run(["g++", *_FLAGS, _SRC, "-o", tmp],
+                   check=True, capture_output=True)
+    os.replace(tmp, so)
+    # libraries built for another key are dead weight (unlinking one a
+    # live process still has mapped is safe)
+    for stale in glob.glob(os.path.join(_BUILD_DIR, "libspanring*.so")):
+        if stale != so:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
 
 
 def _signatures(lib: ctypes.CDLL) -> None:
@@ -68,9 +102,9 @@ def lib() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            _build()
-        _lib = ctypes.CDLL(_SO)
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
+        _lib = ctypes.CDLL(so)
         _signatures(_lib)
         return _lib

@@ -45,12 +45,15 @@ def mesh_key(shape) -> str:
 
 
 def ensure_host_devices(n_devices: int) -> int:
-    """CPU-fallback mesh (ISSUE 7 satellite): force an n-device virtual
-    host platform so the dp×tp serving path runs without real TPUs
-    (tier-1 / driver dryruns). Must run before the jax backend
-    initializes — XLA_FLAGS is only read once; afterwards this degrades
-    to reporting the device count that actually exists. Returns the
-    live device count so callers can size their mesh to reality."""
+    """Force an n-device virtual CPU platform, for an EXPLICIT CPU dry
+    run of the dp×tp serving path (``__graft_entry__.dryrun_multichip``,
+    ``tools/multichip_bench.py --simulated``). It takes the process off
+    any accelerator, so nothing may call it because a probe failed or a
+    device was not found: a run that was meant for the chip fails
+    instead. Must run before the jax backend initializes — XLA_FLAGS is
+    only read once; afterwards this degrades to reporting the device
+    count that actually exists. Returns the live device count so
+    callers can size their mesh to reality."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

@@ -20,11 +20,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -60,16 +57,10 @@ def _ring_body(q, k, v, kv_mask, axis_name, scale):
     B, Lq, H, D = q.shape
 
     # accumulators start replicated; mark them device-varying over the ring
-    # axis so the fori_loop carry type stays stable (jax>=0.9 vma typing)
-    if hasattr(jax.lax, "pcast"):
-        def _vary(x):
-            return jax.lax.pcast(x, axis_name, to="varying")
-    elif hasattr(jax.lax, "pvary"):  # pragma: no cover - jax 0.5-0.8
-        def _vary(x):
-            return jax.lax.pvary(x, axis_name)
-    else:  # jax <= 0.4: shard_map has no vma typing; no marking needed
-        def _vary(x):
-            return x
+    # axis so the fori_loop carry type stays stable (shard_map vma typing)
+    def _vary(x):
+        return jax.lax.pcast(x, axis_name, to="varying")
+
     o = _vary(jnp.zeros((B, Lq, H, D), jnp.float32))
     m = _vary(jnp.full((B, H, Lq), NEG_INF, jnp.float32))
     s = _vary(jnp.zeros((B, H, Lq), jnp.float32))

@@ -15,9 +15,8 @@ XLA inserts the all-reduces (psum over "model" after attention-out and
 mlp-down) — we only annotate placements, per the scaling-book recipe cited
 in the build brief. ``compile_plan`` graduates the rules from a demo
 helper into the ScoringEngine's device layer: one plan per (model, mesh)
-holding the rule-matched param placements, the explicit in/out shardings
-of the packed scoring call, and the donation vector threaded through the
-models' ``enable_input_donation`` plumbing.
+holding the rule-matched param placements and the explicit in/out
+shardings of the packed scoring call.
 
 Numerics contract: "data"-axis sharding is BITWISE identical to single
 device (rows are independent; each shard runs the same per-row program).
@@ -207,27 +206,23 @@ def _shard_inputs(mesh: Mesh, arrays: tuple) -> tuple:
 # ------------------------------------------------------- scoring plans
 
 
-def _packed_score_jit(model, mesh: Mesh, donate: bool):
+def _packed_score_jit(model, mesh: Mesh):
     """Compile the packed-scoring fn for one (model, mesh) pairing:
     params ride their committed placement (``place_variables`` has
     already device_put them per the rule table — an explicit in_sharding
     would just restate it); inputs and output are pinned to "data" so
     the call NEVER silently runs replicated even if a caller hands host
-    arrays. The donation vector follows the model's
-    ``enable_input_donation`` opt-in (TPU-gated by serving_donation)."""
+    arrays."""
     impl = getattr(model, "_score_packed_impl", None)
     if impl is None:
         return None
-    from ..models.transformer import serving_donation
-
     row = NamedSharding(mesh, P("data", None))
     row3 = NamedSharding(mesh, P("data", None, None))
     return jitstats.track_jit(
         f"parallel.plan.score_packed[{mesh_key(mesh)}]",
         jax.jit(impl,
                 in_shardings=(None, row3, row3, row, row),
-                out_shardings=row,
-                donate_argnums=serving_donation((1, 2, 3, 4), donate)))
+                out_shardings=row))
 
 
 class ScoringPlan:
@@ -238,16 +233,14 @@ class ScoringPlan:
     Owns: the rule-matched param PartitionSpecs, an identity-cached
     ``place_variables`` (params move to device once per weight pytree,
     not per call), the packed scoring fn jitted with EXPLICIT in/out
-    shardings (inputs on "data", scores on "data", params per rules) and
-    the donation vector from the model's ``enable_input_donation``
-    plumbing, and a propagation-sharded ``score_spans`` for the
+    shardings (inputs on "data", scores on "data", params per rules),
+    and a propagation-sharded ``score_spans`` for the
     sequence (autoencoder) route. Neither entry blocks on the device:
     the engine harvests against the next in-flight call.
     """
 
     def __init__(self, model: Any, mesh: Mesh,
-                 rules: tuple = PARTITION_RULES,
-                 donate: bool = False):
+                 rules: tuple = PARTITION_RULES):
         self.model = model
         self.mesh = mesh
         self.rules = rules
@@ -259,7 +252,7 @@ class ScoringPlan:
         # and serve stale weights — so the cache holds a strong ref to
         # the source pytree and revalidates by identity against it.
         self._cache: dict[str, Any] = {"source": None, "placed": None}
-        self._packed_jit = _packed_score_jit(model, mesh, donate)
+        self._packed_jit = _packed_score_jit(model, mesh)
 
     def param_specs(self, variables: Any) -> Any:
         """Rule-matched PartitionSpec pytree for a weight pytree
@@ -322,14 +315,10 @@ class ScoringPlan:
         return self.model.score_spans(v, categorical, continuous, mask)
 
 
-def compile_plan(model, mesh: Mesh, *, rules: tuple = PARTITION_RULES,
-                 donate: Optional[bool] = None) -> ScoringPlan:
-    """Build the (model, mesh) serving plan. ``donate=None`` follows the
-    model's ``enable_input_donation`` opt-in (the engine calls it before
-    compiling the plan, so the donation vector rides through here)."""
-    if donate is None:
-        donate = bool(getattr(model, "_donate_inputs", False))
-    return ScoringPlan(model, mesh, rules=rules, donate=donate)
+def compile_plan(model, mesh: Mesh, *,
+                 rules: tuple = PARTITION_RULES) -> ScoringPlan:
+    """Build the (model, mesh) serving plan."""
+    return ScoringPlan(model, mesh, rules=rules)
 
 
 # ------------------------------------------------ legacy factory seams
@@ -385,7 +374,7 @@ def make_sharded_packed_score_fn(model, mesh: Mesh, block: bool = True):
     call so the transfer overlaps device execution. R is unpadded (the
     divisibility check guarantees it), so no trailing-slice is needed.
     """
-    plan = compile_plan(model, mesh, donate=False)
+    plan = compile_plan(model, mesh)
 
     def score(variables, cat, cont, segments, positions):
         R = np.asarray(segments).shape[0]

@@ -35,10 +35,14 @@ def main(argv=None) -> int:
                          "(0 = disabled)")
     args = ap.parse_args(argv)
 
+    from ..utils.jaxruntime import configure_compile_cache
     from .service import Collector
 
     with open(args.config) as f:
         config = json.load(f)
+    # before the graph builds: an in-process scoring engine compiles at
+    # start, and a restarted collector should find those programs again
+    configure_compile_cache()
     collector = Collector(config).start()
     print(f"collector up: {len(collector.graph.all_components())} "
           f"components", flush=True)

@@ -372,7 +372,7 @@ def build_gateway_config(
             }
             if getattr(anomaly, "failover", None) is not None:
                 # failover breaker (ISSUE 13): the engine arms a
-                # circuit breaker with a CPU fallback route; None
+                # circuit breaker with a zscore fallback route; None
                 # renders nothing (byte-stable configs)
                 config["processors"]["tpuanomaly"]["failover"] = dict(
                     anomaly.failover)

@@ -45,6 +45,7 @@ from collections import Counter, deque
 from dataclasses import dataclass
 from typing import Any, Optional
 
+from ..utils.jaxruntime import backend_initialized
 from ..utils.telemetry import labeled_key, meter
 
 SAMPLES_METRIC = "odigos_profiler_samples_total"
@@ -376,11 +377,11 @@ class DeviceRuntimeCollector:
 
     ``collect_once()`` is the unit of work (also called synchronously by
     tests and the diagnose bundle); ``start()`` runs it on an interval
-    daemon thread. Everything device-side is best-effort: no jax in
-    ``sys.modules`` means nothing device-related is touched (importing
-    jax from a telemetry thread would pay seconds and may initialize a
-    device runtime the process never asked for), and a CPU backend
-    without ``memory_stats`` is a graceful no-op."""
+    daemon thread. Everything device-side is best-effort: device facts
+    are read only once this process's own engine has initialised a JAX
+    backend (a telemetry thread must never be what imports jax, and
+    never what claims the chip — see ``_collect_jax``), and a CPU
+    backend without ``memory_stats`` is a graceful no-op."""
 
     def __init__(self, config: Optional[DeviceRuntimeConfig] = None):
         self.cfg = config or DeviceRuntimeConfig()
@@ -496,8 +497,14 @@ class DeviceRuntimeCollector:
 
     @staticmethod
     def _collect_jax() -> dict[str, float]:
-        if "jax" not in sys.modules:
-            return {}  # never the importer — sampling must stay passive
+        if not backend_initialized():
+            # passive by construction: never the importer, and never the
+            # process that INITIALISES a backend. jax being imported
+            # proves neither — with ``model: remote`` the chip belongs
+            # to the sidecar, and asking jax for its devices from here
+            # would claim it (or, the sidecar already holding it, fail
+            # on the libtpu lockfile every interval)
+            return {}
         import jax
 
         out: dict[str, float] = {}
@@ -637,7 +644,8 @@ def device_snapshot() -> dict[str, Any]:
     out: dict[str, Any] = {
         "attribution": [],
         "cost": {"rows": [], "best_flops_per_s": {},
-                 "captures_skipped": 0},
+                 "captures_skipped": 0, "captures_failed": 0,
+                 "last_error": None},
         "compiles": [],
         "tables": {},
     }

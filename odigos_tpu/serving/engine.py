@@ -41,6 +41,7 @@ odigos_tpu.serving.sidecar.
 
 from __future__ import annotations
 
+import logging
 import math
 import queue
 import threading
@@ -63,6 +64,8 @@ from ..selftelemetry.profiler import engines as _engine_registry
 from ..selftelemetry.tracer import (
     NULL_SPAN, is_selftelemetry_batch, tracer)
 from ..utils.telemetry import labeled_key, meter
+
+_log = logging.getLogger(__name__)
 
 
 def _record_compile_seconds(site: str, seconds: float) -> None:
@@ -159,9 +162,10 @@ class EngineConfig:
     bucket_ladder: int = 4      # geometric row buckets above trace_bucket
     warm_ladder: bool = False   # compile the whole ladder at start()
     # failover supervisor (ISSUE 13): a circuit breaker over the
-    # dispatch/harvest error path that hot-swaps scoring to a CPU
-    # fallback route on a persistent device fault and half-open probes
-    # the primary back (serving/failover.py). Accepts True (defaults)
+    # dispatch/harvest error path that hot-swaps scoring to the
+    # fallback model (zscore; same default device as the primary) on a
+    # persistent fault and half-open probes the primary back
+    # (serving/failover.py). Accepts True (defaults)
     # or a {window_s, trip_errors, probe_interval_s,
     # recovery_successes, fallback_model} mapping; normalized hashable
     # in __post_init__ (shared-engine keying hashes the config); None/
@@ -430,11 +434,6 @@ class SequenceBackend:
         # pack longer rows than the (possibly restored) model can embed
         self.max_len = min(cfg.max_len, self.model.cfg.max_len)
         self.device_label = str(jax.devices()[0])
-        # the engine owns this model instance and materializes fresh input
-        # arrays every call — safe to donate their device buffers on TPU
-        donate = getattr(self.model, "enable_input_donation", None)
-        if donate is not None:
-            donate()
         # rungs lcm-aligned to the data axis: the pack stage then emits
         # dp-divisible row groups and the sharded call never re-pads
         dp = int(mesh.shape.get("data", 1)) if mesh is not None else 1
@@ -464,16 +463,14 @@ class SequenceBackend:
 
             self._quantized = QuantizedTraceScorer(self.model,
                                                    self.variables)
-            self._quantized.enable_input_donation()
             self.jit_site = "quantized.score_packed"  # the jit that runs
         if mesh is not None:
             from ..parallel import compile_plan
 
             # partition-rule dp×tp plan: params per PARTITION_RULES,
-            # packed rows on "data", donation following the
-            # enable_input_donation opt-in above. Non-blocking by design:
-            # the engine harvests the device array itself so the fetch
-            # overlaps the next in-flight call.
+            # packed rows on "data". Non-blocking by design: the engine
+            # harvests the device array itself so the fetch overlaps the
+            # next in-flight call.
             self._plan = compile_plan(self.model, mesh)
             if cfg.model == "transformer":
                 # per-mesh compile attribution: each mesh shape warms its
@@ -598,25 +595,22 @@ class SequenceBackend:
             self._capture_warm_cost(site, R, zero)
 
     def _capture_warm_cost(self, site: str, R: int, zero) -> None:
-        """Ask XLA's cost model about the rung just warmed (graceful
-        no-op where the jit under this route exposes no analysis —
-        mesh plans and remote/mock backends simply record nothing)."""
+        """Ask XLA's cost model about the rung just warmed. The mesh
+        plan and the int8 scorer wrap their jits behind their own call
+        graphs and record nothing here; their rows come from the fused
+        route's cold-key capture instead."""
         from ..models.costmodel import cost_ledger
 
+        if self._plan is not None or self._quantized is not None:
+            return
         if self.cfg.model == "transformer":
-            if self._plan is not None or self._quantized is not None:
-                # plan/quantized wrap their jits behind their own call
-                # graphs; their cost rows come from the fused route's
-                # cold-key capture instead
-                return
             fn = self.model.score_packed
             args = (self.variables, zero.categorical, zero.continuous,
                     zero.segments, zero.positions)
         else:
-            if self._plan is not None:
-                return
-            fn = self.model.score_spans
-            args = (self.variables, *zero)
+            # score_spans is jitted on the class with the model static
+            fn = type(self.model).score_spans
+            args = (self.model, self.variables, *zero)
         cost_ledger.capture(site, f"r{R}", fn, args)
 
 
@@ -849,17 +843,25 @@ class ScoringEngine:
                                       model=self.cfg.model))
         self.backend = _BACKENDS[self.cfg.model](self.cfg, mesh=self.mesh)
         # failover supervisor (ISSUE 13): circuit breaker over the
-        # dispatch/harvest error path with a CPU fallback backend — a
-        # persistent device fault degrades to zscore scoring instead of
-        # forwarding every frame unscored forever. The supervisor never
-        # imports this module; the engine constructs the fallback and
-        # hands both backends in.
+        # dispatch/harvest error path with a fallback backend — a
+        # persistent fault of the primary degrades to zscore scoring
+        # instead of forwarding every frame unscored forever. The
+        # fallback is NOT a CPU route: zscore is a jitted JAX kernel and
+        # runs on this process's default device, which on a TPU host is
+        # the same chip. It outlives a fault of the primary's PROGRAM (a
+        # compile refusal, a poisoned executable, a wedged mesh), not
+        # the loss of the device. The supervisor never imports this
+        # module; the engine constructs the fallback and hands both
+        # backends in.
         self.failover = None
         # chaos hook (e2e/chaos.py inject_device_fault): a non-None
         # message makes every PRIMARY-backend dispatch raise — the
         # deterministic stand-in for a dead device that the failover
         # breaker (and the sustained-failure tests) exercise
         self._device_fault: Optional[str] = None
+        # text of the most recent dispatch/harvest failure (see
+        # _note_error); None while every call has succeeded
+        self.last_error: Optional[str] = None
         if self.cfg.failover is not None:
             from .failover import FailoverConfig, FailoverSupervisor
 
@@ -1197,6 +1199,8 @@ class ScoringEngine:
             out["mesh"] = dict(self.cfg.mesh)
         if self.failover is not None:
             out["failover"] = self.failover.status()
+        if self.last_error is not None:
+            out["last_error"] = self.last_error
         return out
 
     # -------------------------------------------------------------- worker
@@ -1230,8 +1234,21 @@ class ScoringEngine:
                     grp = inflight.popleft()
                     self._inflight_count = len(inflight)
                     self._retire(grp)
-            except Exception:
-                meter.add("odigos_anomaly_engine_errors_total")
+            except Exception as e:
+                self._note_error("worker", e)
+
+    def _note_error(self, stage: str, exc: BaseException) -> None:
+        """A scoring call failed. The frames forward unscored — that is
+        the contract — so the counter alone makes a compile refusal or a
+        dead buffer look like a healthy collector: say what was raised.
+        A failure mode is logged when it first appears and again
+        whenever it changes, not once per frame."""
+        meter.add("odigos_anomaly_engine_errors_total")
+        text = f"{stage}: {type(exc).__name__}: {exc}"
+        if text != self.last_error:
+            self.last_error = text
+            _log.error("engine/%s %s (frames forward unscored)",
+                       self.cfg.model, text)
 
     def _collect(self, block: bool) -> Optional[list[ScoreRequest]]:
         """Pack-stage intake: one request (blocking briefly only when the
@@ -1311,7 +1328,7 @@ class ScoringEngine:
         if self._t_run0 is None:
             self._t_run0 = t0
         # failover (ISSUE 13): the breaker picks the backend PER GROUP —
-        # primary while closed, the CPU fallback while tripped, and one
+        # primary while closed, the fallback while tripped, and one
         # half-open probe group per interval while recovering
         if self.failover is not None:
             backend, probe = self.failover.select()
@@ -1344,7 +1361,7 @@ class ScoringEngine:
                 # carrying requests on a backend with a fused kernel
                 # scores in one featurize→pack→score device call. The
                 # decision is per group AND per selected backend: a
-                # failover trip to the CPU fallback (no fused kernel)
+                # failover trip to the zscore fallback (no fused kernel)
                 # converts the same requests on the host path below.
                 fused = (getattr(backend, "supports_fused", False)
                          and all(r.columns is not None for r in reqs))
@@ -1427,7 +1444,7 @@ class ScoringEngine:
                         waste = getattr(backend, "last_padding_waste",
                                         None)
         except Exception as e:
-            meter.add("odigos_anomaly_engine_errors_total")
+            self._note_error("dispatch", e)
             if self.failover is not None:
                 self.failover.observe(
                     backend, ok=False,
@@ -1490,7 +1507,7 @@ class ScoringEngine:
                 scores = harvest(grp.handle) if harvest is not None \
                     else grp.handle
         except Exception as e:
-            meter.add("odigos_anomaly_engine_errors_total")
+            self._note_error("harvest", e)
             if self.failover is not None:
                 self.failover.observe(backend, ok=False,
                                       n_spans=grp.n_spans,

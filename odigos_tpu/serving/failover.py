@@ -1,4 +1,4 @@
-"""Failover supervisor: circuit-broken model serving with a CPU fallback.
+"""Failover supervisor: circuit-broken model serving with a fallback model.
 
 The engine's error path before this module was a counter and a shrug: a
 persistent device fault (PJRT client death, a wedged TPU runtime, an
@@ -11,10 +11,11 @@ the degradation rung between "engine errors" and "pipeline dies"
 
 * a **circuit breaker** watches the engine's dispatch/harvest results
   over a sliding window. ``trip_errors`` failures inside ``window_s``
-  trip it: scoring hot-swaps to a CPU fallback backend (zscore by
-  default — the streaming route that needs no device, no XLA program
-  and no recompile; the ``BucketLadder``/``ScoringPlan`` machinery means
-  nothing else in the engine changes shape). The swap is per *device
+  trip it: scoring hot-swaps to a fallback backend (zscore by default —
+  the streaming route whose one small jitted kernel runs on the
+  process's default JAX device, the same chip as the primary on a TPU
+  host; it needs no ladder, no plan and no recompile, so nothing else
+  in the engine changes shape). The swap is per *device
   call*: the worker selects a backend per coalesced group, in-flight
   primary calls still harvest against the primary, and the fallback's
   depth-1 eager scoring rides the existing no-dispatch path.
@@ -343,7 +344,7 @@ def failover_conditions() -> dict[str, tuple[str, str, str]]:
         if st["state"] != CLOSED:
             out[name] = (
                 "Degraded", "ModelFailover",
-                f"scoring on {st['fallback_model']} CPU fallback "
+                f"scoring on {st['fallback_model']} fallback "
                 f"({st['state']} {st['since_s']:.1f}s, trips "
                 f"{st['trips']}"
                 + (f"; last error: {st['last_error']}"

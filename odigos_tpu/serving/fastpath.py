@@ -359,7 +359,7 @@ class IngestFastPath:
         self._predicted_key = labeled_key(PREDICTED_SHED_METRIC,
                                           pipeline=pipeline)
         # fused route (ISSUE 19): capability is a property of the
-        # PRIMARY backend (failover's CPU fallback converts columns
+        # PRIMARY backend (failover's zscore fallback converts columns
         # host-side in the engine's pack stage); keys precomputed —
         # the closed reason set makes the fallback counter's label
         # space enumerable at build time

@@ -76,6 +76,37 @@ FALLBACK_REASONS = (
     "misaligned_columns",  # non-contiguous / foreign-dtype u64 columns
 )
 
+# host-route vs fused-route score parity, per serving precision (both
+# pinned by tests/test_fused.py). True float32: the device twin's f32
+# split-clock duration is a few ULP off the host's f64, which the forward
+# cannot amplify past ~1e-5 relative — (rtol, atol). Reduced precision:
+# the same few ULP can flip a rounding boundary, so single spans may move
+# by a quantum while the population agrees tightly — (max |d|, mean |d|).
+PARITY_F32 = (2e-5, 1e-6)
+PARITY_REDUCED = (0.05, 5e-3)
+
+
+def serves_reduced_precision(backend: Any) -> bool:
+    """Whether the backend's matmuls see operands narrower than float32:
+    a bfloat16 or int8 model anywhere, and ANY model on a TPU, whose
+    default matmul precision rounds float32 operands to bfloat16."""
+    import jax
+
+    return (np.dtype(backend.model.cfg.dtype).itemsize < 4
+            or backend._quantized is not None
+            or jax.default_backend() == "tpu")
+
+
+def routes_agree(got: np.ndarray, want: np.ndarray, reduced: bool) -> bool:
+    """The parity verdict for one group scored on both routes."""
+    if reduced:
+        diff = np.abs(got - want)
+        return bool(diff.max() < PARITY_REDUCED[0]
+                    and diff.mean() < PARITY_REDUCED[1])
+    return bool(np.allclose(got, want, rtol=PARITY_F32[0],
+                            atol=PARITY_F32[1]))
+
+
 # the uint64 columns the device kernel splits host-side; each must be a
 # C-contiguous little-endian uint64 array or the split view is invalid
 _U64_COLUMNS = ("span_id", "parent_span_id", "trace_id_hi", "trace_id_lo",

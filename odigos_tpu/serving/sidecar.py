@@ -402,6 +402,9 @@ def main(argv: Optional[list[str]] = None) -> None:
                          "(slower start, zero steady-state recompiles)")
     args = ap.parse_args(argv)
 
+    from ..utils.jaxruntime import configure_compile_cache
+
+    configure_compile_cache()
     engine = ScoringEngine(EngineConfig(
         model=args.model, checkpoint_path=args.checkpoint,
         max_len=args.max_len, trace_bucket=args.trace_bucket,

@@ -1,5 +1,5 @@
 """odigosauth-analog token validation + tier enforcement at the CLI
-(VERDICT r2 item 6; reference: odigosauth/odigosauth.go:69)."""
+(round-2 review item 6; reference: odigosauth/odigosauth.go:69)."""
 
 import base64
 import json

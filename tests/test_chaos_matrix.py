@@ -121,7 +121,7 @@ def env_config(*, anomaly=None, alerts=(), export_retry=None
 
 def anomaly_cfg(failover=None) -> AnomalyStageConfiguration:
     # timeout_ms 5000: the oracle is about degradation, not the 5 ms
-    # budget — a CPU fallback's first (jit-compiling) call must not
+    # budget — the fallback's first (jit-compiling) call must not
     # read as an unscored pass-through
     return AnomalyStageConfiguration(enabled=True, model="zscore",
                                      timeout_ms=5000.0,

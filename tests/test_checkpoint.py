@@ -1,7 +1,7 @@
 """Serving-bundle bridge (training/checkpoint.py): save/restore round trip,
 metadata-driven model rebuild, and the engine's checkpoint_path seam — the
 fast-path coverage for the loop that tests/test_northstar_auc.py proves at
-full model scale (VERDICT r1 item 1).
+full model scale (round-1 review item 1).
 """
 
 import numpy as np

@@ -567,7 +567,7 @@ class TestReviewRegressions:
 
 class TestTpuCoScheduling:
     """North star: the autoscaler co-schedules gateway replicas with TPU
-    devices (VERDICT r1 item 6; reference pattern:
+    devices (round-1 review item 6; reference pattern:
     clustercollector/hpa.go:36-68 + virtual-device affinity,
     distros/yamls/golang-community.yaml:15-18)."""
 
@@ -645,7 +645,7 @@ class TestTpuCoScheduling:
 
 
 class TestRemainingRuleKinds:
-    """custom-instrumentation and otel-sdk rules (VERDICT r2 item 6;
+    """custom-instrumentation and otel-sdk rules (round-2 review item 6;
     reference: api/odigos/v1alpha1/instrumentationrules/)."""
 
     def test_custom_instrumentation_probes_validated(self):

@@ -1,4 +1,4 @@
-"""Dashboard JS contract tests (VERDICT r3 item 3).
+"""Dashboard JS contract tests (round-3 review item 3).
 
 No JS engine ships in this image (no node/quickjs/browser), so the page's
 inline script cannot be *executed* here; these tests implement the next

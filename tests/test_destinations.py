@@ -202,7 +202,7 @@ class TestExtensionsWiring:
 
 
 class TestBlobExporter:
-    """Generic blob-writer behind the azureblob/gcs entries (VERDICT r2
+    """Generic blob-writer behind the azureblob/gcs entries (round-2 review
     item 10; reference: collector/exporters/azureblobstorageexporter,
     common/config/gcs.go)."""
 
@@ -295,7 +295,7 @@ def _plausible_value(field_name: str) -> str:
 class TestEveryDestinationTypeBuilds:
     """The full registry/configer/factory contract: for EVERY one of the 63
     destination types, the generated exporter entries must resolve to
-    registered factories that build and start (VERDICT r3: adding a real
+    registered factories that build and start (round-3 review: adding a real
     backend produced configs the graph builder rejected — the reference
     compiles one upstream exporter per backend, builder-config.yaml)."""
 
@@ -474,7 +474,7 @@ class TestBlobLogsDispatch:
 
 
 class TestBlobHttpUploader:
-    """HTTP PUT path against a real socket (VERDICT r3 item 5; reference:
+    """HTTP PUT path against a real socket (round-3 review item 5; reference:
     collector/exporters/azureblobstorageexporter over the Azure SDK's HTTPS
     transport — here the exporter speaks the PUT contract directly)."""
 

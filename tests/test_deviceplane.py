@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from odigos_tpu.models import TransformerConfig, jitstats
+from odigos_tpu.models.autoencoder import AutoencoderConfig
 from odigos_tpu.models.costmodel import CostLedger, cost_ledger
 from odigos_tpu.models.jitstats import (
     STORM_THRESHOLD, record_compile_event, recent_compiles)
@@ -134,25 +135,65 @@ class TestCostLedger:
         f = jax.jit(lambda x: x * 2.0)
         row = led.capture("t.mem", "r8", f, (jnp.ones((8, 8)),),
                           memory=True)
-        # memory=True AOT-compiles; either the stats landed as ints or
-        # the whole capture degraded to the counted no-op — never a raise
-        if row is not None:
-            assert row["memory"] is None or all(
-                isinstance(v, int) for v in row["memory"].values())
-        else:
-            assert led.snapshot()["captures_skipped"] == 1
+        # memory=True AOT-compiles and reads the executable's own stats
+        assert row is not None
+        assert set(row["memory"]) == {
+            "generated_code_size", "argument_size", "output_size",
+            "temp_size"}
+        assert all(isinstance(v, int) for v in row["memory"].values())
+        assert row["memory"]["argument_size"] == 8 * 8 * 4
 
-    def test_graceful_noop_without_analysis(self):
+    def test_failed_capture_is_counted_and_explained(self):
+        """A capture that RAISES writes no row and never propagates (it
+        runs on a cold dispatch), but it is not the same thing as a
+        backend without analysis: it is counted apart and the snapshot
+        keeps the exception text — the warm-ladder capture handed a
+        plain method for eighteen PRs and an all-skipped ledger could
+        not say so."""
         led = CostLedger()
 
-        def plain(x):  # no .lower(): the analysis-free backend stand-in
+        def plain(x):  # not a jit: no .lower()
             return x
 
         assert led.capture("t.plain", "r1", plain, (1.0,)) is None
-        assert led.snapshot()["captures_skipped"] == 1
+        snap = led.snapshot()
+        assert snap["captures_failed"] == 1
+        assert snap["captures_skipped"] == 0
+        assert snap["last_error"].startswith("t.plain[r1]: AttributeError")
         # observing a never-captured (site, bucket) is a None, not a row
         assert led.observe_device_ms("t.plain", "r1", 1.0) is None
-        assert led.snapshot()["rows"] == []
+        assert snap["rows"] == []
+
+    def test_backend_without_lowering_analysis_prices_the_executable(self):
+        """The TPU backend answers ``Lowered.cost_analysis()`` with None
+        and prices only executables; the ledger then asks the compiled
+        one instead of writing nothing."""
+        led = CostLedger()
+        f = jax.jit(lambda x: x @ x)
+        x = jnp.ones((16, 16), jnp.float32)
+
+        class NoLoweredAnalysis:
+            def __init__(self, lowered):
+                self._lowered = lowered
+                self.compiles = 0
+
+            def cost_analysis(self):
+                return None
+
+            def compile(self):
+                self.compiles += 1
+                return self._lowered.compile()
+
+        class Fn:
+            def lower(self, *a, **kw):
+                self.last = NoLoweredAnalysis(f.lower(*a, **kw))
+                return self.last
+
+        fn = Fn()
+        row = led.capture("t.tpu", "r16", fn, (x,))
+        assert row is not None and row["flops"] > 0
+        assert fn.last.compiles == 1
+        assert led.snapshot()["captures_skipped"] == 0
 
     def test_reset(self):
         led = CostLedger()
@@ -160,7 +201,31 @@ class TestCostLedger:
         assert led.capture("t.r", "r4", f, (jnp.ones((4,)),)) is not None
         led.reset()
         assert led.snapshot() == {"rows": [], "best_flops_per_s": {},
-                                  "captures_skipped": 0}
+                                  "captures_skipped": 0,
+                                  "captures_failed": 0,
+                                  "last_error": None}
+
+    @pytest.mark.parametrize("model", ["transformer", "autoencoder"])
+    def test_warm_ladder_prices_every_rung(self, model):
+        """Ladder warming is a capture site: every warmed rung gets a
+        row under the backend's jit site, and nothing fails."""
+        cost_ledger.reset()
+        mc = (TransformerConfig if model == "transformer"
+              else AutoencoderConfig)(
+            d_model=32, n_heads=2, n_layers=1, d_ff=64, max_len=16,
+            dtype=jnp.float32)
+        eng = ScoringEngine(EngineConfig(
+            model=model, model_config=mc, max_len=16, trace_bucket=8,
+            bucket_ladder=2, warm_ladder=True))
+        try:
+            eng.backend.warm()
+            snap = cost_ledger.snapshot()
+            assert snap["captures_failed"] == 0, snap["last_error"]
+            got = {(r["site"], r["bucket"]) for r in snap["rows"]}
+            assert got == {(eng.backend.jit_site, f"r{R}")
+                           for R in eng.backend.ladder.buckets}
+        finally:
+            cost_ledger.reset()
 
 
 # --------------------------------------------------------------------------

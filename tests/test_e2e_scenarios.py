@@ -157,7 +157,7 @@ class TestChaos:
             assert mock.rejected_batches > 0
 
     def test_backpressure_rejection_drives_scale_up(self):
-        """The full backpressure loop over the real wire (VERDICT r2 item 4;
+        """The full backpressure loop over the real wire (round-2 review item 4;
         reference: configgrpc fork -> odigos_gateway_memory_limiter_
         rejections_total -> hpa.go custom metric): chaos memory pressure ->
         pre-decode REJECTED at the otlp front door -> rejection metric ->

@@ -327,3 +327,38 @@ def test_depth1_backends_keep_serial_behavior():
     assert eng2._depth == 1
     eng3 = ScoringEngine(tiny_cfg())
     assert eng3._depth == 2
+
+
+def test_failed_dispatch_is_logged_with_its_exception_text(caplog):
+    """A dispatch that raises forwards its frames unscored and counts an
+    engine error — the product's contract — but it must also SAY what
+    was raised (ISSUE 21): on a new backend a compile refusal otherwise
+    looks like a healthy collector. One line per failure mode, not per
+    frame."""
+    import logging
+
+    eng = ScoringEngine(EngineConfig(model="mock")).start()
+    batch = synthesize_traces(4, seed=1)
+    try:
+        errors0 = meter.counter("odigos_anomaly_engine_errors_total")
+        assert eng.last_error is None
+        assert "last_error" not in eng.pipeline_stats()
+        eng.inject_device_fault("chip says no")
+        with caplog.at_level(logging.ERROR, logger="odigos_tpu.serving.engine"):
+            for _ in range(3):
+                assert eng.score_sync(batch, timeout_s=5.0) is None
+        assert meter.counter(
+            "odigos_anomaly_engine_errors_total") - errors0 == 3
+        lines = [r.getMessage() for r in caplog.records
+                 if r.name == "odigos_tpu.serving.engine"]
+        assert lines == ["engine/mock dispatch: DeviceFaultInjected: "
+                         "chip says no (frames forward unscored)"]
+        assert eng.pipeline_stats()["last_error"] == \
+            "dispatch: DeviceFaultInjected: chip says no"
+        # a different failure is a new line
+        eng.inject_device_fault("still no")
+        with caplog.at_level(logging.ERROR, logger="odigos_tpu.serving.engine"):
+            assert eng.score_sync(batch, timeout_s=5.0) is None
+        assert "still no" in caplog.records[-1].getMessage()
+    finally:
+        eng.shutdown()

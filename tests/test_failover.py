@@ -7,7 +7,7 @@ Contracts pinned:
   half-open probe in flight at a time, failed probe re-opens + re-arms,
   ``recovery_successes`` consecutive successes close;
 * engine integration: a persistent device fault trips the breaker, the
-  CPU fallback keeps scoring (requests resolve with scores, not
+  zscore fallback keeps scoring (requests resolve with scores, not
   pass-throughs), a group dispatched through the primary before the
   trip harvests against the PRIMARY, and clearing the fault recovers
   via traffic-riding probes;

@@ -1,6 +1,6 @@
 """Operator API layer (frontend/): HTTP/JSON over the store, SSE push, and
 the collector-metrics consumer fed by the gateway's otlp/ui stream over the
-real wire (VERDICT r1 item 5; reference: frontend/main.go:155,217 +
+real wire (round-1 review item 5; reference: frontend/main.go:155,217 +
 services/collector_metrics).
 """
 
@@ -179,7 +179,7 @@ def test_mutating_endpoints(env_with_frontend):
 
 def test_dashboard_page_serves(env_with_frontend):
     """The webapp analog: the dashboard page serves at / and wires itself to
-    the data endpoints the page's JS polls (VERDICT r2 item 2)."""
+    the data endpoints the page's JS polls (round-2 review item 2)."""
     env, fe = env_with_frontend
     with urllib.request.urlopen(fe.url + "/", timeout=10) as r:
         assert r.status == 200
@@ -332,7 +332,7 @@ def test_destination_secret_env_lifecycle_over_socket(monkeypatch):
 
 
 class TestFrontendAuth:
-    """Bearer/session middleware (VERDICT r4 item 6; reference OIDC
+    """Bearer/session middleware (round-4 review item 6; reference OIDC
     middleware frontend/main.go:130): with auth configured, mutations
     and SSE require a token; reads stay open; open servers unchanged."""
 

@@ -49,8 +49,8 @@ from odigos_tpu.serving import EngineConfig, ScoringEngine  # noqa: E402
 from odigos_tpu.serving.fastpath import (  # noqa: E402
     FUSED_FALLBACK_METRIC, FUSED_FRAMES_METRIC, SCORE_ATTR, IngestFastPath)
 from odigos_tpu.serving.fused import (  # noqa: E402
-    FALLBACK_REASONS, _device_tables, _split_u64, extract_columns,
-    fused_enabled)
+    FALLBACK_REASONS, PARITY_F32, PARITY_REDUCED, _device_tables,
+    _split_u64, extract_columns, fused_enabled)
 from odigos_tpu.utils.telemetry import labeled_key, meter  # noqa: E402
 from odigos_tpu.wire.codec import decode_frame, encode_batch, frame  # noqa: E402
 from odigos_tpu.wire.server import REJECTED  # noqa: E402
@@ -163,6 +163,12 @@ class TestColumnTwins:
 
 class TestBackendParity:
     """dispatch_columns == dispatch/harvest, per span, every backend."""
+
+    def test_product_bounds_are_the_ones_pinned_here(self):
+        """chip_smoke.py, bench.py and the soak's parity gate judge with
+        serving/fused.py's bounds; this file is where they are set."""
+        assert PARITY_F32 == (FUSED_RTOL, FUSED_ATOL)
+        assert PARITY_REDUCED == (0.05, 5e-3)  # test_quantized_backend_parity
 
     @pytest.mark.parametrize("make_cfg", [tf_cfg, ae_cfg],
                              ids=["transformer", "autoencoder"])

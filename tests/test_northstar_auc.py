@@ -39,7 +39,7 @@ def test_northstar_auc(trained):
 
 def test_northstar_auc_quantized(trained):
     """The int8 serving path meets the same AUC bar on the same trained
-    checkpoint (VERDICT r3 item 6: max-|dp| parity alone does not bound
+    checkpoint (round-3 review item 6: max-|dp| parity alone does not bound
     ranking quality; assert the detection metric directly)."""
     from odigos_tpu.training.evaluate import quantized_transformer_scorer
 
@@ -51,7 +51,7 @@ def test_northstar_auc_quantized(trained):
 
 
 def test_train_serve_loop_flags_faults_into_tracedb(trained):
-    """The VERDICT-r1 critical path: checkpoint → pipeline → anomaly stream."""
+    """The round-1 review critical path: checkpoint → pipeline → anomaly stream."""
     _, _, bundle_path = trained
 
     # the bundle carries the trained geometry — serving needs only the path

@@ -1,4 +1,4 @@
-"""OpAMP across a real process boundary (VERDICT r2 item 3): the socket
+"""OpAMP across a real process boundary (round-2 review item 3): the socket
 transport carries the same messages the in-process client exchanges, and
 the socket's lifetime is the agent's liveness signal (reference:
 opampserver/pkg/server/server.go:23, handlers.go:43 connection handling).

@@ -1,4 +1,4 @@
-"""Operator-style single-resource installer (VERDICT r2 item 7;
+"""Operator-style single-resource installer (round-2 review item 7;
 reference: operator/api/v1alpha1/odigos_types.go:26,105 +
 internal/controller/odigos_controller.go): apply one Odigos resource →
 full install; delete it → uninstall."""

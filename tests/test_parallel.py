@@ -128,7 +128,7 @@ def test_ring_attention_fully_masked_rows_safe():
 
 
 def test_dp_packed_scoring_matches_single_device():
-    """Serving-path DP (VERDICT r1 item 7): SequenceBackend with
+    """Serving-path DP (round-1 review item 7): SequenceBackend with
     data_parallel=8 scores identically to single-device on the 8-virtual-
     device CPU mesh (BASELINE config #5)."""
     from odigos_tpu.pdata import synthesize_traces
@@ -178,7 +178,7 @@ def test_dp_aligns_bucket_ladder_to_mesh():
 
 def test_dp_serving_flagship_geometry_under_load():
     """DP serving at the FLAGSHIP geometry (d_model 256, bucket 256,
-    max_len 64 — VERDICT r2 weak item 8): many uneven traces pack into
+    max_len 64 — round-2 review weak item 8): many uneven traces pack into
     row counts that exercise the trace_bucket % data_parallel interaction
     with pack_sequences padding, and scores must match single-device
     bit-for-bit at fp32."""

