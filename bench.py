@@ -647,8 +647,8 @@ def steady_state_allocs_bench() -> dict:
 
 def fused_path_bench() -> dict:
     """Fused columns→scores A/B (ISSUE 19): host featurize+pack+dispatch
-    vs ``extract_columns``+``dispatch_columns`` on the SOAK transformer
-    geometry, PAIRED interleaved rounds on the same warmed backend. The
+    vs ``extract_columns``+``dispatch_columns`` on the flagship
+    transformer, PAIRED interleaved rounds on the same warmed backend. The
     timer covers exactly the per-frame HOST work each route pays before
     the non-blocking device enqueue returns (harvest blocks outside the
     timer — async dispatch means the enqueue cost, not device compute,
@@ -656,27 +656,17 @@ def fused_path_bench() -> dict:
     at the dispatch seam, and allocs/frame comes from the real fast-path
     route with pools on and the fused knob armed — the same exact
     miss+fallback counters as ``steady_state_allocs``."""
-    import jax.numpy as jnp
-
     from odigos_tpu.features import bufferpool, featurize
-    from odigos_tpu.models import TransformerConfig
     from odigos_tpu.pdata import synthesize_traces
     from odigos_tpu.serving.engine import EngineConfig, ScoringEngine
     from odigos_tpu.serving.fastpath import (FUSED_FRAMES_METRIC,
                                              IngestFastPath)
     from odigos_tpu.serving.fused import (
-        extract_columns, routes_agree, serves_reduced_precision)
+        extract_columns, routes_agree, served_precision)
     from odigos_tpu.utils.telemetry import labeled_key, meter
 
-    # the SOAK config geometry (tools/e2e_soak.py --model transformer)
-    soak_tf = TransformerConfig(d_model=64, n_layers=2, d_ff=256,
-                                n_heads=4, max_len=32, dtype=jnp.float32)
-
     def engine_cfg(**kw) -> EngineConfig:
-        base = dict(model="transformer", model_config=soak_tf, max_len=32,
-                    trace_bucket=64)
-        base.update(kw)
-        return EngineConfig(**base)
+        return EngineConfig(model="transformer", **kw)  # the flagship
 
     N_VARIANTS = 4
     WARM_ROUNDS = 3
@@ -719,12 +709,12 @@ def fused_path_bench() -> dict:
 
     # warm: jit compiles, hash tables, ladder buckets — and a parity
     # spot-check (the bound for the precision served, serving/fused.py)
-    reduced = serves_reduced_precision(backend)
+    precision = served_precision(backend)
     for _ in range(WARM_ROUNDS):
         for b in batches:
             want = backend.harvest(host_frame(b))
             got = backend.harvest(fused_frame(b))
-            if not routes_agree(got, want, reduced):
+            if not routes_agree(got, want, precision):
                 raise RuntimeError("fused/host parity trip in bench warm")
 
     calls["host"] = calls["fused"] = 0
@@ -800,7 +790,7 @@ def fused_path_bench() -> dict:
 
     out["fused_path_note"] = (
         "per-frame host wall before the non-blocking device enqueue "
-        "returns, paired interleaved rounds on one warmed SOAK-geometry "
+        "returns, paired interleaved rounds on one warmed flagship "
         "transformer backend: host = featurize+pack+dispatch, fused = "
         "extract_columns+dispatch_columns (17 pooled column copies + one "
         "jitted featurize→pack→score call); harvest blocks outside the "
@@ -818,7 +808,7 @@ def fused_path_bench() -> dict:
 
 def device_attribution_overhead_bench() -> dict:
     """Sampled intra-fused attribution A/B (ISSUE 20): per-frame host
-    wall of ``dispatch_columns`` on the warmed SOAK-geometry fused
+    wall of ``dispatch_columns`` on the warmed flagship fused
     transformer route with the 1-in-32 sampler armed vs disarmed,
     PAIRED interleaved on the same warmed backend (the identical frame
     dispatched in both modes back to back, within-pair order
@@ -828,17 +818,11 @@ def device_attribution_overhead_bench() -> dict:
     one. The sampled frame's own cost (a blocking fused stamp plus five
     sub-stage replays) is reported separately — it is the price of the
     waterfall, deliberately not hidden inside the median."""
-    import jax.numpy as jnp
-
-    from odigos_tpu.models import TransformerConfig
     from odigos_tpu.pdata import synthesize_traces
     from odigos_tpu.serving.engine import EngineConfig, ScoringEngine
     from odigos_tpu.serving.fused import extract_columns
 
-    soak_tf = TransformerConfig(d_model=64, n_layers=2, d_ff=256,
-                                n_heads=4, max_len=32, dtype=jnp.float32)
-    cfg = EngineConfig(model="transformer", model_config=soak_tf,
-                       max_len=32, trace_bucket=64,
+    cfg = EngineConfig(model="transformer",  # the flagship
                        device_attribution=True,
                        device_attribution_stride=32)
     eng = ScoringEngine(cfg)  # unstarted: direct backend A/B
@@ -896,7 +880,7 @@ def device_attribution_overhead_bench() -> dict:
         "device_attrib_reconcile_ratio": wf.get("reconcile_ratio"),
         "device_attrib_note": (
             "paired armed/disarmed dispatch_columns host wall on one "
-            "warmed SOAK-geometry fused backend, stride 32, within-pair "
+            "warmed flagship fused backend, stride 32, within-pair "
             "order alternating; overhead_ratio_p50 = median paired "
             "ratio (the tier-1 guard bound, <1.02). sampled_frame_ms is "
             "the 1-in-32 sampled frame's own sub-stage replay cost — "

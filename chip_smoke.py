@@ -77,6 +77,9 @@ class Geometry:
 
 
 FLAGSHIP = Geometry()
+# past this the run dumps every thread's stack and exits non-zero: under
+# the chip check's 1200 s, so a hang names itself instead of being killed
+BUDGET_S = 1100.0
 
 
 class SmokeFailure(Exception):
@@ -493,15 +496,15 @@ def parity_and_fused_warm(engine, traffic: Traffic,
     from odigos_tpu.features.featurizer import SpanFeatures
     from odigos_tpu.pdata.spans import concat_batches
     from odigos_tpu.serving.fused import (
-        extract_columns, routes_agree, serves_reduced_precision)
+        extract_columns, routes_agree, served_precision)
 
     backend = engine.backend
     fz = engine.cfg.featurizer
     by_size = sorted(traffic.frames(6), key=len)
     # the pack stage adds frames while the group is under the cap
     k_max = (engine.cfg.max_batch_spans - 1) // len(by_size[0]) + 1
-    reduced = serves_reduced_precision(backend)
-    out = {"groups": [], "precision": "reduced" if reduced else "float32"}
+    precision = served_precision(backend)
+    out = {"groups": [], "precision": precision}
     seen_keys = set()
     for k in range(1, min(k_max, len(by_size)) + 1):
         for group in (by_size[:k], by_size[-k:]):
@@ -522,7 +525,7 @@ def parity_and_fused_warm(engine, traffic: Traffic,
             diff = np.abs(got - want)
             ok_range = bool(np.isfinite(got).all() and (got >= 0).all()
                             and (got <= 1).all())
-            ok = routes_agree(got, want, reduced)
+            ok = routes_agree(got, want, precision)
             row = {"frames": k, "spans": len(got),
                    "span_bucket": key[0], "rows": key[1][0],
                    "first_call_s": round(dt, 3) if key not in seen_keys
@@ -596,18 +599,20 @@ def describe_engine(engine) -> dict:
 
 def cost_ledger_gate(engine, failures: list[str]) -> list[str]:
     """A row per warmed rung and per fused key this engine compiled.
-    (A mesh plan keeps its jit behind its own call graph and records
-    none — see SequenceBackend._capture_warm_cost.)"""
+    A mesh plan keeps its jit behind its own call graph and has no fused
+    kernel, so it prices nothing (SequenceBackend._capture_warm_cost)
+    and there is nothing to gate."""
     from odigos_tpu.models.costmodel import cost_ledger
 
     backend = engine.backend
     snap = cost_ledger.snapshot()
     rows = {(r["site"], r["bucket"]) for r in snap["rows"]}
-    want = set()
-    if engine.mesh is None:
-        want |= {(backend.jit_site, f"r{R}") for R in backend.ladder.buckets}
-        want |= {(backend.fused_site, f"r{R}x{backend.max_len}")
-                 for (_n, R) in backend._fused_shapes}
+    if engine.mesh is not None:
+        say(f"cost ledger: not gated under a mesh ({len(rows)} rows)")
+        return sorted(f"{s}[{b}]" for s, b in rows)
+    want = {(backend.jit_site, f"r{R}") for R in backend.ladder.buckets}
+    want |= {(backend.fused_site, f"r{R}x{backend.max_len}")
+             for (_n, R) in backend._fused_shapes}
     missing = sorted(want - rows)
     say(f"cost ledger: {len(rows)} rows, {len(want)} expected, "
         f"failed={snap['captures_failed']} "
@@ -775,12 +780,9 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("--mesh", default=None, metavar="data=N",
                     help="serve on an N-chip data-parallel mesh (host "
                          "route only); default one chip")
-    ap.add_argument("--budget-s", type=float, default=1100.0,
-                    help="dump every thread's stack and exit non-zero if "
-                         "the run is still going after this long")
     args = ap.parse_args(argv)
     mesh_data = parse_mesh(args.mesh)
-    faulthandler.dump_traceback_later(args.budget_s, exit=True)
+    faulthandler.dump_traceback_later(BUDGET_S, exit=True)
     t0 = time.perf_counter()
     try:
         import odigos_tpu  # noqa: F401 — the repo must be around this file

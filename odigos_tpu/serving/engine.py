@@ -597,8 +597,9 @@ class SequenceBackend:
     def _capture_warm_cost(self, site: str, R: int, zero) -> None:
         """Ask XLA's cost model about the rung just warmed. The mesh
         plan and the int8 scorer wrap their jits behind their own call
-        graphs and record nothing here; their rows come from the fused
-        route's cold-key capture instead."""
+        graphs and record nothing here. The int8 engine's rows come
+        from the fused route's cold-key capture instead; a mesh plan
+        has no fused kernel, so its ledger stays empty."""
         from ..models.costmodel import cost_ledger
 
         if self._plan is not None or self._quantized is not None:

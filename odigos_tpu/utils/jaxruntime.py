@@ -48,10 +48,14 @@ def configure_compile_cache() -> str:
     Also lowers ``jax_persistent_cache_min_compile_time_secs`` to 0: at
     its default of 1 s the fused route's small sub-jits and the floor
     ladder rung compile under the threshold and are never stored, so a
-    restarted process would pay them again every time."""
+    restarted process would pay them again every time. Like the
+    directory, a floor the operator set from outside
+    (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``, which jax reads
+    itself) is left alone."""
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if not os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return jax.config.jax_compilation_cache_dir

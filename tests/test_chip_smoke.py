@@ -158,6 +158,17 @@ class TestRehearsal:
         assert e.value.failures[0].startswith(
             "[mesh] a device reports no bytes_in_use")
 
+    def test_cost_ledger_is_not_gated_under_a_mesh(self, capsys):
+        """A mesh plan has no fused kernel and prices no rung: the
+        smoke says so instead of passing a gate that expects nothing."""
+        from types import SimpleNamespace
+
+        failures: list = []
+        chip_smoke.cost_ledger_gate(
+            SimpleNamespace(mesh=object(), backend=None), failures)
+        assert not failures
+        assert "cost ledger: not gated under a mesh" in capsys.readouterr().out
+
     def test_a_mesh_wider_than_the_host_fails(self):
         with pytest.raises(chip_smoke.SmokeFailure, match="needs 64"):
             chip_smoke.run(chip_smoke.Geometry(**TINY),

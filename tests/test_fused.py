@@ -49,8 +49,9 @@ from odigos_tpu.serving import EngineConfig, ScoringEngine  # noqa: E402
 from odigos_tpu.serving.fastpath import (  # noqa: E402
     FUSED_FALLBACK_METRIC, FUSED_FRAMES_METRIC, SCORE_ATTR, IngestFastPath)
 from odigos_tpu.serving.fused import (  # noqa: E402
-    FALLBACK_REASONS, PARITY_F32, PARITY_REDUCED, _device_tables,
-    _split_u64, extract_columns, fused_enabled)
+    FALLBACK_REASONS, PARITY_BF16, PARITY_F32, PARITY_INT8, _device_tables,
+    _split_u64, extract_columns, fused_enabled, routes_agree,
+    served_precision)
 from odigos_tpu.utils.telemetry import labeled_key, meter  # noqa: E402
 from odigos_tpu.wire.codec import decode_frame, encode_batch, frame  # noqa: E402
 from odigos_tpu.wire.server import REJECTED  # noqa: E402
@@ -168,7 +169,8 @@ class TestBackendParity:
         """chip_smoke.py, bench.py and the soak's parity gate judge with
         serving/fused.py's bounds; this file is where they are set."""
         assert PARITY_F32 == (FUSED_RTOL, FUSED_ATOL)
-        assert PARITY_REDUCED == (0.05, 5e-3)  # test_quantized_backend_parity
+        assert PARITY_BF16 == (1e-2, 1e-4)  # test_bfloat16_backend_parity
+        assert PARITY_INT8 == (0.05, 5e-3)  # test_quantized_backend_parity
 
     @pytest.mark.parametrize("make_cfg", [tf_cfg, ae_cfg],
                              ids=["transformer", "autoencoder"])
@@ -196,8 +198,30 @@ class TestBackendParity:
         cols, reason = extract_columns(b, FeaturizerConfig())
         assert reason is None
         got = backend.harvest(backend.dispatch_columns([cols]))
-        assert np.max(np.abs(got - want)) < 0.05
-        assert np.mean(np.abs(got - want)) < 5e-3
+        assert served_precision(backend) == "int8"
+        assert np.max(np.abs(got - want)) < PARITY_INT8[0]
+        assert np.mean(np.abs(got - want)) < PARITY_INT8[1]
+        assert routes_agree(got, want, "int8")
+
+    def test_bfloat16_backend_parity(self):
+        """bfloat16 route: its own bound, set from the chip's reading on
+        the flagship and far inside int8's. A route wrong on 2% of its
+        spans by a tenth of the score range random weights produce
+        (0.44..0.70) passes int8's bound and must not pass this one."""
+        backend = ScoringEngine(tf_cfg(model_config=replace(
+            TINY_TF, dtype=jnp.bfloat16))).backend
+        assert served_precision(backend) == "bfloat16"
+        assert served_precision(ScoringEngine(tf_cfg()).backend) == "float32"
+        b = synthesize_traces(40, seed=5)
+        want = backend.score(b, featurize(b))
+        cols, reason = extract_columns(b, FeaturizerConfig())
+        assert reason is None
+        got = backend.harvest(backend.dispatch_columns([cols]))
+        assert routes_agree(got, want, "bfloat16")
+        wrong = got.copy()
+        wrong[::50] += 0.026
+        assert routes_agree(wrong, want, "int8")
+        assert not routes_agree(wrong, want, "bfloat16")
 
     def test_truncated_traces_parity(self):
         """Traces longer than max_len: the device next-fit must chunk

@@ -30,7 +30,8 @@ _PROBE = (
 
 def _probe(**env):
     base = {k: v for k, v in os.environ.items()
-            if k != "JAX_COMPILATION_CACHE_DIR"}
+            if k not in ("JAX_COMPILATION_CACHE_DIR",
+                         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS")}
     r = subprocess.run([sys.executable, "-c", _PROBE], capture_output=True,
                        text=True, timeout=120, env={**base, **env},
                        cwd="/")
@@ -57,6 +58,10 @@ class TestCompileCache:
         # the floor rung
         for env in ({}, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)}):
             assert _probe(**env)["min_s"] == 0.0
+
+    def test_a_floor_set_from_outside_is_left_alone(self):
+        got = _probe(JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="2.5")
+        assert got["min_s"] == 2.5
 
     def test_fixed_path_is_ignored_by_git_and_by_the_chip_copy(self):
         for listing in (".gitignore", ".chiprunignore"):

@@ -418,8 +418,7 @@ def run_soak(args, fast_path: bool) -> dict:
 
         from odigos_tpu.features import featurize
         from odigos_tpu.serving.fused import (
-            PARITY_F32, extract_columns, fused_enabled, routes_agree,
-            serves_reduced_precision)
+            extract_columns, fused_enabled, routes_agree, served_precision)
 
         if not fused_enabled():
             raise RuntimeError(
@@ -437,10 +436,8 @@ def run_soak(args, fast_path: bool) -> dict:
         fused_parity = {
             "spans": len(pb),
             "max_abs_diff": round(float(np.max(np.abs(got - want))), 8),
-            "rtol_bound": PARITY_F32[0],
-            "reduced_precision": serves_reduced_precision(backend),
-            "passed": routes_agree(got, want,
-                                   serves_reduced_precision(backend)),
+            "precision": served_precision(backend),
+            "passed": routes_agree(got, want, served_precision(backend)),
         }
 
     # pre-synthesize a few distinct batches per sender (generation must not
