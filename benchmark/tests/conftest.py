@@ -1,0 +1,11 @@
+"""The benchmark's own checks run on the CPU, in seconds:
+``python -m pytest benchmark/``."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
