@@ -1,0 +1,9 @@
+"""Model step on the device's own clock: mean duration of the window's
+executable runs (XLA Modules events), in ms. Read by hosttrace.py from
+the traced window; nothing to read until the harness hands it the trace
+(obs.host)."""
+
+
+def read(obs):
+    host = getattr(obs, "host", None)
+    return None if host is None else host.step_ms

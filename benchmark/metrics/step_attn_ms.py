@@ -1,0 +1,8 @@
+"""Model step, attention: XLA Ops time under the scope ``attn`` (LayerNorm,
+projections, attention core, residual of every block), mean per
+executable run of the window, in ms."""
+
+
+def read(obs):
+    host = getattr(obs, "host", None)
+    return None if host is None else host.part_ms("attn")

@@ -49,7 +49,11 @@ serves JSON (terminal-first operators curl it):
                            site × shape bucket), recent compile events
                            with trace ids, the sampled intra-fused
                            attribution waterfall per engine, and the
-                           device-resident table/plan footprint
+                           device-resident table/plan footprint;
+                           ``?trace_s=<1-30>`` captures one profiler
+                           trace of that many seconds from this process
+                           and answers with its directory (409 while
+                           one runs)
 
 Debug-only: binds loopback. Config: ``endpoint``/``host``/``port``.
 """
@@ -180,8 +184,14 @@ class ZPagesExtension(HttpExtension):
         return 200, out
 
     def _xlaz(self, q: dict[str, str]) -> tuple[int, dict]:
-        from ...selftelemetry.profiler import device_snapshot
+        from ...selftelemetry.profiler import capture_trace, device_snapshot
 
+        if "trace_s" in q:  # one profiler trace of this process
+            try:
+                seconds = float(q["trace_s"])
+            except ValueError:
+                return 400, {"error": "trace_s must be a number"}
+            return capture_trace(seconds)
         return 200, device_snapshot()
 
     def pages(self) -> dict[str, Page]:

@@ -7,7 +7,7 @@ functions, how many cached executables each holds (one per traced input
 shape — the cache growing past the declared bucket ladder is the
 unbounded-recompile hazard showing up live), and how many cumulative
 seconds each site has spent compiling (observed where code can see a
-compile happen: the engine's first-call split, ladder warming).
+compile happen: ladder warming, a fused cold key).
 
 Deliberately jax-free at import time: the DeviceRuntimeCollector reads
 these tables from a telemetry thread that must never be the reason jax
@@ -68,8 +68,8 @@ def track_jit(site: str, fn: Callable) -> Callable:
 
 
 def record_compile_seconds(site: str, seconds: float) -> None:
-    """Accumulate observed compile time for a site (engine first-call
-    split, ladder warm passes)."""
+    """Accumulate observed compile time for a site (ladder warm passes,
+    a fused cold key's dispatch)."""
     if seconds <= 0:
         return
     with _lock:

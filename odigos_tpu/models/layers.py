@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -68,17 +69,27 @@ class EncoderBlock(nn.Module):
     def __call__(self, x: jnp.ndarray, attn_mask: jnp.ndarray,
                  deterministic: bool = True) -> jnp.ndarray:
         # attn_mask: (T, 1, L, L) bool, True where attention is allowed
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        h = nn.MultiHeadDotProductAttention(
-            num_heads=self.n_heads, dtype=self.dtype,
-            dropout_rate=self.dropout, deterministic=deterministic,
-        )(h, h, mask=attn_mask)
-        x = x + h
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        h = nn.Dense(self.d_ff, dtype=self.dtype)(h)
-        h = nn.gelu(h)
-        h = nn.Dense(self.d_model, dtype=self.dtype)(h)
-        return x + h
+        with jax.named_scope("attn"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+            h = nn.MultiHeadDotProductAttention(
+                num_heads=self.n_heads, dtype=self.dtype,
+                dropout_rate=self.dropout, deterministic=deterministic,
+            )(h, h, mask=attn_mask)
+            x = x + h
+        with jax.named_scope("mlp"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+            h = nn.Dense(self.d_ff, dtype=self.dtype)(h)
+            h = nn.gelu(h)
+            h = nn.Dense(self.d_model, dtype=self.dtype)(h)
+            return x + h
+
+
+# the parts of a scoring call, as ``jax.named_scope`` writes them into
+# every operation's metadata (and so into the device trace): the
+# benchmark's step_attn_ms / step_mlp_ms / step_rest_ms fold operations
+# by the first of these names on their path. Scopes only: flax's
+# parameter paths do not see them.
+PARTS = ("embed", "attn_mask", "attn", "mlp", "final_norm", "head")
 
 
 class Encoder(nn.Module):
@@ -104,22 +115,25 @@ class Encoder(nn.Module):
         that keeps MXU density high regardless of trace length distribution.
         ``positions`` overrides the positional-embedding index (within-trace
         position for packed rows)."""
-        x = SpanEmbedder(self.service_vocab, self.name_vocab, self.attr_vocab,
-                         self.d_model, self.dtype, name="embed")(
-            categorical, continuous)
-        L = categorical.shape[-2]
-        pos_ids = positions if positions is not None else jnp.arange(L)
-        pos = nn.Embed(self.max_len, self.d_model, dtype=self.dtype,
-                       name="pos_embed")(pos_ids)
-        x = x + pos
-        x = x * mask[..., None].astype(self.dtype)
-        if segments is not None:
-            attn_mask = ((segments[..., None] == segments[..., None, :])
-                         & mask[..., None] & mask[..., None, :])[:, None]
-        else:
-            attn_mask = (mask[:, None, None, :] & mask[:, None, :, None])
+        with jax.named_scope("embed"):
+            x = SpanEmbedder(self.service_vocab, self.name_vocab,
+                             self.attr_vocab, self.d_model, self.dtype,
+                             name="embed")(categorical, continuous)
+            L = categorical.shape[-2]
+            pos_ids = positions if positions is not None else jnp.arange(L)
+            pos = nn.Embed(self.max_len, self.d_model, dtype=self.dtype,
+                           name="pos_embed")(pos_ids)
+            x = x + pos
+            x = x * mask[..., None].astype(self.dtype)
+        with jax.named_scope("attn_mask"):
+            if segments is not None:
+                attn_mask = ((segments[..., None] == segments[..., None, :])
+                             & mask[..., None] & mask[..., None, :])[:, None]
+            else:
+                attn_mask = (mask[:, None, None, :] & mask[:, None, :, None])
         for i in range(self.n_layers):
             x = EncoderBlock(self.d_model, self.n_heads, self.d_ff,
                              self.dtype, name=f"block_{i}")(
                 x, attn_mask, deterministic)
-        return nn.LayerNorm(dtype=self.dtype, name="final_ln")(x)
+        with jax.named_scope("final_norm"):
+            return nn.LayerNorm(dtype=self.dtype, name="final_ln")(x)

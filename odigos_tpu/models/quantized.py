@@ -168,44 +168,54 @@ class QuantizedTraceScorer:
         c = self.cfg
         dt = c.dtype
         H, hd = c.n_heads, c.d_model // c.n_heads
-        h = _layernorm(x, blk["ln1"]["scale"], blk["ln1"]["bias"], dt)
-        R, L, _ = h.shape
+        # the float path's part names (layers.PARTS), so that a trace of
+        # either scorer folds the same way
+        with jax.named_scope("attn"):
+            h = _layernorm(x, blk["ln1"]["scale"], blk["ln1"]["bias"], dt)
+            R, L, _ = h.shape
 
-        def heads(proj):
-            y = _qdense(h, proj["w"], proj["s"], proj["b"], dt)
-            return y.reshape(R, L, H, hd)
+            def heads(proj):
+                y = _qdense(h, proj["w"], proj["s"], proj["b"], dt)
+                return y.reshape(R, L, H, hd)
 
-        q, k, v = heads(blk["q"]), heads(blk["k"]), heads(blk["v"])
-        # attention internals stay bf16 (few % of FLOPs, most sensitivity)
-        scores = jnp.einsum("rlhd,rmhd->rhlm", q, k) / jnp.sqrt(
-            jnp.asarray(hd, jnp.float32)).astype(dt)
-        scores = jnp.where(attn_mask, scores.astype(jnp.float32),
-                           -1e9)
-        attn = jax.nn.softmax(scores, axis=-1).astype(dt)
-        ctx = jnp.einsum("rhlm,rmhd->rlhd", attn, v).reshape(R, L, -1)
-        x = x + _qdense(ctx, blk["o"]["w"], blk["o"]["s"],
-                        blk["o"]["b"], dt)
-        h = _layernorm(x, blk["ln2"]["scale"], blk["ln2"]["bias"], dt)
-        h = _qdense(h, blk["ffn1"]["w"], blk["ffn1"]["s"],
-                    blk["ffn1"]["b"], dt)
-        h = jax.nn.gelu(h)
-        return x + _qdense(h, blk["ffn2"]["w"], blk["ffn2"]["s"],
-                           blk["ffn2"]["b"], dt)
+            q, k, v = heads(blk["q"]), heads(blk["k"]), heads(blk["v"])
+            # attention internals stay bf16 (few % of FLOPs, most
+            # sensitivity)
+            scores = jnp.einsum("rlhd,rmhd->rhlm", q, k) / jnp.sqrt(
+                jnp.asarray(hd, jnp.float32)).astype(dt)
+            scores = jnp.where(attn_mask, scores.astype(jnp.float32),
+                               -1e9)
+            attn = jax.nn.softmax(scores, axis=-1).astype(dt)
+            ctx = jnp.einsum("rhlm,rmhd->rlhd", attn, v).reshape(R, L, -1)
+            x = x + _qdense(ctx, blk["o"]["w"], blk["o"]["s"],
+                            blk["o"]["b"], dt)
+        with jax.named_scope("mlp"):
+            h = _layernorm(x, blk["ln2"]["scale"], blk["ln2"]["bias"], dt)
+            h = _qdense(h, blk["ffn1"]["w"], blk["ffn1"]["s"],
+                        blk["ffn1"]["b"], dt)
+            h = jax.nn.gelu(h)
+            return x + _qdense(h, blk["ffn2"]["w"], blk["ffn2"]["s"],
+                               blk["ffn2"]["b"], dt)
 
     def _score_packed_impl(self, cat, cont, segments, positions):
         c, p = self.cfg, self.params
         dt = c.dtype
         mask = segments > 0
-        x = self._embed(cat, cont)
-        x = x + p["pos"].astype(dt)[positions]
-        x = x * mask[..., None].astype(dt)
-        attn_mask = ((segments[..., None] == segments[..., None, :])
-                     & mask[..., None] & mask[..., None, :])[:, None]
+        with jax.named_scope("embed"):
+            x = self._embed(cat, cont)
+            x = x + p["pos"].astype(dt)[positions]
+            x = x * mask[..., None].astype(dt)
+        with jax.named_scope("attn_mask"):
+            attn_mask = ((segments[..., None] == segments[..., None, :])
+                         & mask[..., None] & mask[..., None, :])[:, None]
         for blk in p["blocks"]:
             x = self._block(blk, x, attn_mask)
-        x = _layernorm(x, p["final_ln"]["scale"], p["final_ln"]["bias"],
-                       dt)
-        head = p["span_head"]
-        logit = (x.astype(jnp.float32) @ head["kernel"].astype(jnp.float32)
-                 + head["bias"].astype(jnp.float32))[..., 0]
-        return jax.nn.sigmoid(logit)
+        with jax.named_scope("final_norm"):
+            x = _layernorm(x, p["final_ln"]["scale"],
+                           p["final_ln"]["bias"], dt)
+        with jax.named_scope("head"):
+            head = p["span_head"]
+            logit = (x.astype(jnp.float32)
+                     @ head["kernel"].astype(jnp.float32)
+                     + head["bias"].astype(jnp.float32))[..., 0]
+            return jax.nn.sigmoid(logit)

@@ -63,12 +63,13 @@ class _TraceTransformerModule(nn.Module):
                     name="encoder")(categorical, continuous, mask,
                                     deterministic, positions=positions,
                                     segments=segments)
-        span_logit = nn.Dense(1, dtype=jnp.float32,
-                              name="span_head")(h)[..., 0]
-        denom = jnp.maximum(mask.sum(-1, keepdims=True), 1)
-        pooled = (h * mask[..., None].astype(h.dtype)).sum(-2) / denom.astype(h.dtype)
-        trace_logit = nn.Dense(1, dtype=jnp.float32,
-                               name="trace_head")(pooled)[..., 0]
+        with jax.named_scope("head"):
+            span_logit = nn.Dense(1, dtype=jnp.float32,
+                                  name="span_head")(h)[..., 0]
+            denom = jnp.maximum(mask.sum(-1, keepdims=True), 1)
+            pooled = (h * mask[..., None].astype(h.dtype)).sum(-2) / denom.astype(h.dtype)
+            trace_logit = nn.Dense(1, dtype=jnp.float32,
+                                   name="trace_head")(pooled)[..., 0]
         return span_logit, trace_logit
 
 
@@ -115,7 +116,8 @@ class TraceTransformer:
         """(T, L) per-span anomaly probability + (T,) per-trace probability."""
         span_logit, trace_logit = self.apply(
             variables, categorical, continuous, mask)
-        return jax.nn.sigmoid(span_logit), jax.nn.sigmoid(trace_logit)
+        with jax.named_scope("head"):
+            return jax.nn.sigmoid(span_logit), jax.nn.sigmoid(trace_logit)
 
     def _score_packed_impl(self, variables, categorical, continuous,
                            segments, positions):
@@ -125,7 +127,8 @@ class TraceTransformer:
         span_logit, _ = self.module.apply(
             variables, categorical, continuous, mask,
             positions=positions, segments=segments)
-        return jax.nn.sigmoid(span_logit)
+        with jax.named_scope("head"):
+            return jax.nn.sigmoid(span_logit)
 
     def loss_fn(self, variables, categorical, continuous, mask,
                 span_labels, trace_labels, rngs=None):

@@ -61,6 +61,7 @@ from __future__ import annotations
 import contextvars
 import enum
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -138,6 +139,20 @@ STAGES = tuple(s.value for s in Stage if s is not Stage.FUSED)
 # deadline-driven loss, realized or predicted, is countable in one place.
 PREDICTED_BLAME = "predicted"
 
+# the working stages of the scored path as the profiler's trace shows
+# them (``annotate`` below), one site each; the pure waits (submit,
+# queue, device, wait) have none, because nobody works in them. Closed
+# like Stage, and held equal to the sites by the package-hygiene lint
+# (``TestAnnotationHygiene``). The benchmark's trace reduction
+# (benchmark/hosttrace.py) reads the ``engine/*`` names.
+ANNOTATIONS = (
+    "wire/admission", "wire/decode",            # receiver thread
+    "fastpath/featurize", "fastpath/enqueue",   # submit lane
+    "engine/collect", "engine/pack", "engine/enqueue",
+    "engine/harvest", "engine/scatter",         # engine worker
+    "lane/tag", "lane/forward",                 # retirement lane
+)
+
 # bounded ring of recent frame clocks per recorder: the latencyz
 # waterfall witnesses AND the window the predictive gate's stage means
 # are computed over (consumers clamping thresholds key off this)
@@ -153,7 +168,7 @@ class StageClock:
     synchronization, the clock itself is never shared concurrently."""
 
     __slots__ = ("t0", "_mark", "stages", "ctx", "overlap_ms",
-                 "device_attrib", "fused_bucket")
+                 "device_attrib", "fused_bucket", "call")
 
     def __init__(self, ctx: Optional[tuple[int, int]] = None):
         self.t0 = self._mark = time.monotonic_ns()
@@ -166,6 +181,9 @@ class StageClock:
         # and the fused shape bucket ("r{rows}x{len}") the frame ran in
         self.device_attrib: Optional[dict] = None
         self.fused_bucket: Optional[str] = None
+        # serial of the engine call the frame rode (``call`` on the
+        # engine/* annotations, ``call.serial`` on its tpu/score span)
+        self.call: Optional[int] = None
 
     def stamp(self, stage: Stage) -> None:
         now = time.monotonic_ns()
@@ -199,6 +217,7 @@ class StageClock:
         self.overlap_ms = float(info.get("overlap_ms") or 0.0)
         self.device_attrib = info.get("device_attrib")
         self.fused_bucket = info.get("fused_bucket")
+        self.call = info.get("call")
 
     def wall_ms(self) -> float:
         return (self._mark - self.t0) / 1e6
@@ -210,7 +229,8 @@ class StageClock:
         return {"stages": [{"stage": s, "ms": round(d, 4)}
                            for s, d in self.stages],
                 "wall_ms": round(self.wall_ms(), 4),
-                "overlap_ms": round(self.overlap_ms, 4)}
+                "overlap_ms": round(self.overlap_ms, 4),
+                "call": self.call}
 
 
 class _NullClock:
@@ -223,6 +243,7 @@ class _NullClock:
     stages: list = []
     device_attrib = None
     fused_bucket = None
+    call = None
 
     def stamp(self, stage: Stage) -> None:
         pass
@@ -240,10 +261,87 @@ class _NullClock:
         return 0.0
 
     def to_dict(self) -> dict[str, Any]:
-        return {"stages": [], "wall_ms": 0.0, "overlap_ms": 0.0}
+        return {"stages": [], "wall_ms": 0.0, "overlap_ms": 0.0,
+                "call": None}
 
 
 NULL_CLOCK = _NullClock()
+
+_trace_me: Any = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+class annotate:
+    """Bracket one working stage on the profiler's clock, where the work
+    happens: ``with annotate("lane/tag", clock, Stage.TAG): ...`` opens
+    a ``jax.profiler.TraceAnnotation`` named as ``ANNOTATIONS`` has it
+    and, where the stage is one of ``Stage``, stamps the frame's clock
+    on the way out, so that stamp and annotation cannot drift apart. An
+    exception passing through closes the annotation and leaves the
+    clock unstamped, as the bare stamps did. ``args`` become the trace
+    event's arguments; ``set`` adds what is known only at the end.
+
+    With no profiler session live this costs an inactive TraceMe: one
+    flag read each way, no lock, nothing formatted (the name and
+    arguments are rendered by the TraceMe only when a session records).
+    A process that has not imported jax has no profiler to show up in
+    and opens nothing."""
+
+    __slots__ = ("_tm", "_clock", "_stage")
+
+    def __init__(self, name: str, clock: Any = None,
+                 stage: Optional[Stage] = None, **args: Any):
+        global _trace_me
+        cls = _trace_me
+        if cls is None:
+            # looked up, never imported: a worker thread importing jax
+            # while the main thread is still inside ``import jax``
+            # deadlocks on the module lock
+            cls = _trace_me = getattr(sys.modules.get("jax.profiler"),
+                                      "TraceAnnotation", None)
+        self._tm = cls(name, **args) if cls is not None else None
+        self._clock = clock
+        self._stage = stage
+
+    def __enter__(self) -> "annotate":
+        if self._tm is not None:
+            self._tm.__enter__()
+        return self
+
+    def set(self, **args: Any) -> None:
+        if self._tm is not None:
+            self._tm.set_metadata(**args)
+
+    def close(self) -> None:
+        """End the annotation before the block does (the next stage
+        starts inside it); the block's own exit is then a no-op."""
+        tm, self._tm = self._tm, None
+        if tm is not None:
+            tm.__exit__(None, None, None)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._clock is not None and exc_type is None:
+            self._clock.stamp(self._stage)
+        self.close()
+
+
+_prctl: Any = None
+
+
+def name_thread(role: str) -> None:
+    """Give the calling OS thread the name ``role`` (Linux keeps 15
+    bytes), so that the profiler's host plane tells the receiver, the
+    submit lanes, the engine worker and the retirement lanes apart:
+    Python 3.12 does not pass ``threading.Thread(name=)`` to the OS.
+    Best effort: a platform without prctl keeps its unnamed lines."""
+    global _prctl
+    if _prctl is None:
+        try:
+            import ctypes
+            _prctl = ctypes.CDLL(None).prctl
+        except (OSError, AttributeError):
+            _prctl = False
+    if _prctl:
+        _prctl(15, role.encode()[:15], 0, 0, 0)  # PR_SET_NAME
 
 # hands the receiver-started clock to the fast path across the consume
 # seam (same thread, synchronous call chain — the receiver cannot pass
@@ -393,7 +491,8 @@ class _Recorder:
             # rides along so worst_frames() can name the slowest
             # frame's self-trace without a per-frame allocation.
             self.recent.append(
-                (clock.stages, wall, clock.overlap_ms, scored, ex))
+                (clock.stages, wall, clock.overlap_ms, scored, ex,
+                 clock.call))
 
     def record_expiry(self, blame: str, n_spans: int,
                       clock=None) -> None:
@@ -414,7 +513,7 @@ class _Recorder:
         out: list[dict[str, Any]] = []
         with self._lock:
             worst = None
-            for stages, wall, _ov, scored, ex in self.recent:
+            for stages, wall, _ov, scored, ex, _call in self.recent:
                 if ex is None:
                     continue
                 if worst is None or wall > worst[0]:
@@ -519,7 +618,7 @@ class _Recorder:
             sums: dict[str, float] = {}
             counts: dict[str, int] = {}
             n = 0
-            for stages, _wall, _ov, scored, _ex in self.recent:
+            for stages, _wall, _ov, scored, _ex, _call in self.recent:
                 if not scored:
                     continue
                 n += 1
@@ -590,8 +689,8 @@ class _Recorder:
                 {"stages": [{"stage": s, "ms": round(d, 4)}
                             for s, d in stages],
                  "wall_ms": round(wall, 4),
-                 "overlap_ms": round(ov, 4), "scored": sc}
-                for stages, wall, ov, sc, _ex in recent],
+                 "overlap_ms": round(ov, 4), "scored": sc, "call": call}
+                for stages, wall, ov, sc, _ex, call in recent],
             "worst_frames": self.worst_frames(),
         }
 

@@ -38,6 +38,7 @@ from __future__ import annotations
 import functools
 import os
 import sys
+import tempfile
 import threading
 import time
 import weakref
@@ -623,6 +624,46 @@ def stop_started(started: list[str]) -> None:
         profiler.stop()
     if "device_runtime" in started:
         device_runtime.stop()
+
+
+_capture_lock = threading.Lock()
+
+
+def capture_trace(seconds: float) -> tuple[int, dict[str, Any]]:
+    """One ``jax.profiler`` trace of ``seconds`` (1 to 30) of this
+    process, for ``/debug/xlaz?trace_s=``: the operator's way to see the
+    program's annotations (``latency.ANNOTATIONS``) and the model's
+    parts on the device's clock. Answers (HTTP status, body): 200 with
+    the directory the trace was written under; 409 while another
+    capture runs, here or in anyone's own ``start_trace`` (one profiler
+    session a process: it cannot cross a benchmark's); 503 where this
+    process holds no JAX backend (with ``model: remote`` the sidecar
+    holds the chip, and only the process that holds it can trace it)."""
+    if not 1.0 <= seconds <= 30.0:
+        return 400, {"error": "trace_s must be between 1 and 30"}
+    if not backend_initialized():
+        return 503, {"error": "this process has initialised no JAX "
+                              "backend; trace the process that holds "
+                              "the chip"}
+    if not _capture_lock.acquire(blocking=False):
+        return 409, {"error": "a trace capture is already running"}
+    try:
+        import jax
+
+        trace_dir = tempfile.mkdtemp(prefix="odigos-xlaz-")
+        try:
+            jax.profiler.start_trace(trace_dir)
+        except RuntimeError as e:
+            # jax allows one session: someone else's is live
+            os.rmdir(trace_dir)
+            return 409, {"error": f"a profiler session is live: {e}"}
+        try:
+            time.sleep(seconds)
+        finally:
+            jax.profiler.stop_trace()
+        return 200, {"trace_dir": trace_dir, "seconds": seconds}
+    finally:
+        _capture_lock.release()
 
 
 def device_snapshot() -> dict[str, Any]:

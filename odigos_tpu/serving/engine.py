@@ -59,7 +59,7 @@ from ..features.featurizer import (
     pack_sequences)
 from ..pdata.spans import SpanBatch
 from ..selftelemetry.flow import FlowContext
-from ..selftelemetry.latency import latency_enabled
+from ..selftelemetry.latency import annotate, latency_enabled, name_thread
 from ..selftelemetry.profiler import engines as _engine_registry
 from ..selftelemetry.tracer import (
     NULL_SPAN, is_selftelemetry_batch, tracer)
@@ -500,11 +500,9 @@ class SequenceBackend:
             jnp.asarray(packed.segments),
             jnp.asarray(packed.positions))
 
-    def dispatch(self, batch: SpanBatch, features: SpanFeatures) -> Any:
-        """Pack stage: host featurize/pack/pad + non-blocking device
-        enqueue. Returns an opaque handle for ``harvest``."""
-        import jax.numpy as jnp
-
+    def pack(self, batch: SpanBatch, features: SpanFeatures) -> Any:
+        """Pack stage, the host's half: featurize/pack/pad into the
+        arrays of one device call. Returns what ``enqueue`` takes."""
         if self.cfg.model == "transformer":
             # packed rows: block-diagonal attention, ~6x the MXU density of
             # naive per-trace padding (bench.py measures this path)
@@ -515,9 +513,7 @@ class SequenceBackend:
             self.last_shape = list(packed.categorical.shape[:2])
             self.last_padding_waste = round(1.0 - float(packed.density()), 4)
             self.last_bucket_hit = self.ladder.observe(packed.n_rows)
-            dev = self._device_call(packed)
-            return ("packed", dev, packed.span_index, packed.mask,
-                    len(batch))
+            return ("packed", packed, len(batch))
 
         seqs = assemble_sequences(
             batch, features, max_len=self.max_len,
@@ -526,9 +522,27 @@ class SequenceBackend:
         self.last_padding_waste = round(1.0 - float(seqs.mask.mean()), 4) \
             if seqs.mask.size else 0.0
         self.last_bucket_hit = self.ladder.observe(seqs.n_traces)
-        dev, _ = self._seq_call(seqs.categorical, seqs.continuous,
-                                seqs.mask)
-        return ("seq", dev, seqs.span_index, seqs.mask, len(batch))
+        return ("seq", seqs, len(batch))
+
+    def enqueue(self, staged: Any, call: int = -1) -> Any:
+        """Pack stage, the device's half: host-to-device copies and the
+        jitted call's enqueue, non-blocking. ``call`` is the engine's
+        serial of the coalesced call (-1: nobody's, a direct score).
+        Returns an opaque handle for ``fetch``."""
+        kind, host, n = staged
+        with annotate("engine/enqueue", call=call,
+                      rows=host.categorical.shape[0], spans=n):
+            if kind == "packed":
+                dev = self._device_call(host)
+            else:
+                dev, _ = self._seq_call(host.categorical, host.continuous,
+                                        host.mask)
+        return (kind, dev, host.span_index, host.mask, n)
+
+    def dispatch(self, batch: SpanBatch, features: SpanFeatures) -> Any:
+        """Pack stage: ``pack`` then ``enqueue``. Returns an opaque
+        handle for ``harvest``."""
+        return self.enqueue(self.pack(batch, features))
 
     def _seq_call(self, cat, cont, mask) -> Any:
         """Sequence-route device call (autoencoder): through the mesh
@@ -541,9 +555,18 @@ class SequenceBackend:
             self.variables, jnp.asarray(cat), jnp.asarray(cont),
             jnp.asarray(mask))
 
+    def fetch(self, handle: Any, call: int = -1) -> Any:
+        """Harvest stage, the wait: block on the device result (the only
+        blocking host<->device interaction) and nothing else. Returns
+        the handle with the host array in the device array's place,
+        for ``harvest``."""
+        with annotate("engine/harvest", call=call):
+            host = np.asarray(handle[1], dtype=np.float32)
+        return (handle[0], host) + tuple(handle[2:])
+
     def harvest(self, handle: Any) -> np.ndarray:
-        """Harvest stage: block on the device result (the only blocking
-        host<->device interaction), scatter scores back to span rows."""
+        """Harvest stage: block on the device result, unless ``fetch``
+        already has, and scatter scores back to span rows."""
         kind, dev, span_index, mask, n = handle
         span_scores = np.asarray(dev, dtype=np.float32)
         if kind == "seq":
@@ -792,6 +815,10 @@ class _InflightGroup:
     attrib: Optional[dict] = None
     span_bucket: Optional[int] = None
     cold_dispatch_s: float = 0.0
+    # the engine's serial of this coalesced call: ``call`` on the
+    # engine/* trace annotations, ``call.serial`` on the tpu/score span,
+    # ``call`` in the frames' stage_ns
+    call: int = 0
 
 
 class ScoringEngine:
@@ -902,11 +929,10 @@ class ScoringEngine:
         # never serializes dispatch against harvest across calls, so the
         # host/device overlap is untouched.
         self._backend_lock = threading.Lock()
-        # first-call latency split: call 0 pays jit compilation on top of
-        # execution; the estimated compile share is (first - second) call
-        # duration, surfaced as a gauge + span attribute
+        # calls retired (the device_calls gauge) and calls dispatched:
+        # the latter is the next call's serial, the worker's alone
         self._device_calls = 0
-        self._first_call_ms = 0.0
+        self._call_serial = 0
         # pipeline observability: per-call stage timings (bounded ring) and
         # a union accumulator of device in-flight intervals for the
         # device_busy_frac the bench reports
@@ -1212,6 +1238,7 @@ class ScoringEngine:
         to overlap with); on stop the queue and window drain losslessly.
         ``stop`` is THIS run's event (see start()): a zombie run never
         consults the replacement's."""
+        name_thread("odigos-engine")
         inflight: deque[_InflightGroup] = deque()
         while True:
             stopping = stop.is_set()
@@ -1223,7 +1250,10 @@ class ScoringEngine:
             # into a silent full-deadline pass-through
             try:
                 if len(inflight) < self._depth:
-                    reqs = self._collect(block=not inflight and not stopping)
+                    with annotate("engine/collect") as collecting:
+                        reqs = self._collect(
+                            block=not inflight and not stopping)
+                        collecting.set(queued=len(reqs) if reqs else 0)
                     if reqs is not None:
                         grp = self._dispatch_group(reqs,
                                                    overlapped=bool(inflight))
@@ -1328,6 +1358,8 @@ class ScoringEngine:
         t0 = time.monotonic_ns()
         if self._t_run0 is None:
             self._t_run0 = t0
+        call = self._call_serial
+        self._call_serial = call + 1
         # failover (ISSUE 13): the breaker picks the backend PER GROUP —
         # primary while closed, the fallback while tripped, and one
         # half-open probe group per interval while recovering
@@ -1351,7 +1383,8 @@ class ScoringEngine:
         span_bucket = None
         cold_dispatch_s = 0.0
         try:
-            with lease_scope(lease):
+            with lease_scope(lease), \
+                    annotate("engine/pack", call=call) as packing:
                 if self._device_fault is not None \
                         and backend is self.backend:
                     # injected device loss (chaos hook): only the
@@ -1424,10 +1457,13 @@ class ScoringEngine:
 
                             merged = concat_batches(
                                 [r.batch for r in reqs])
-                    dispatch = getattr(backend, "dispatch", None)
+                    pack = getattr(backend, "pack", None)
                     with self._backend_lock:
-                        if dispatch is not None:
-                            handle = dispatch(merged, feats)
+                        if pack is not None:
+                            staged = pack(merged, feats)
+                            # the device call is engine/enqueue's
+                            packing.close()
+                            handle = backend.enqueue(staged, call)
                         else:
                             # depth-1 backend: the whole call happens
                             # here, eagerly — identical to the serial
@@ -1480,7 +1516,7 @@ class ScoringEngine:
             bucket_hit=bucket_hit, shape=shape, padding_waste=waste,
             lease=lease, backend=backend, probe=probe, fused=fused,
             attrib=attrib, span_bucket=span_bucket,
-            cold_dispatch_s=cold_dispatch_s)
+            cold_dispatch_s=cold_dispatch_s, call=call)
 
     def _retire(self, grp: _InflightGroup) -> None:
         """Harvest stage: block on the oldest in-flight device call, split
@@ -1496,74 +1532,96 @@ class ScoringEngine:
             if grp.lease is not None:
                 grp.lease.release()
 
+    def _harvest_failed(self, grp: _InflightGroup, backend: Any,
+                        exc: Exception) -> None:
+        """The in-flight call's result could not be had: its frames
+        forward unscored, the breaker hears of it."""
+        self._note_error("harvest", exc)
+        if self.failover is not None:
+            self.failover.observe(backend, ok=False,
+                                  n_spans=grp.n_spans,
+                                  error=f"{type(exc).__name__}: {exc}",
+                                  probe=grp.probe)
+        for r in grp.reqs:
+            r.scores = None
+            r.signal_done()
+        grp.span.set_attr("error", True)
+        grp.span.finish(error=True)
+
     def _retire_inner(self, grp: _InflightGroup) -> None:
         t_h0 = time.monotonic_ns()
         # harvest against the backend that DISPATCHED this group (see
         # _InflightGroup.backend): a failover trip between dispatch and
         # harvest must not hand a primary handle to the fallback
         backend = grp.backend if grp.backend is not None else self.backend
-        try:
-            harvest = getattr(backend, "harvest", None)
-            with self._backend_lock:
-                scores = harvest(grp.handle) if harvest is not None \
-                    else grp.handle
-        except Exception as e:
-            self._note_error("harvest", e)
+        # the wait apart from the work: ``fetch`` blocks on the device
+        # result (engine/harvest), ``harvest`` then finds it fetched
+        handle = grp.handle
+        fetch = getattr(backend, "fetch", None)
+        if fetch is not None:
+            try:
+                with self._backend_lock:
+                    handle = fetch(handle, grp.call)
+            except Exception as e:
+                self._harvest_failed(grp, backend, e)
+                return
+        with annotate("engine/scatter", call=grp.call):
+            try:
+                harvest = getattr(backend, "harvest", None)
+                with self._backend_lock:
+                    scores = harvest(handle) if harvest is not None \
+                        else handle
+            except Exception as e:
+                self._harvest_failed(grp, backend, e)
+                return
             if self.failover is not None:
-                self.failover.observe(backend, ok=False,
+                # the group's FINAL success: harvest landed (or the
+                # eager fallback call already had) — breaker evidence,
+                # and the fallback's scored-span volume when it served
+                self.failover.observe(backend, ok=True,
                                       n_spans=grp.n_spans,
-                                      error=f"{type(e).__name__}: {e}",
                                       probe=grp.probe)
-            for r in grp.reqs:
-                r.scores = None
-                r.signal_done()
-            grp.span.set_attr("error", True)
-            grp.span.finish(error=True)
-            return
-        if self.failover is not None:
-            # the group's FINAL success: harvest landed (or the eager
-            # fallback call already had) — breaker evidence, and the
-            # fallback's scored-span volume when it served
-            self.failover.observe(backend, ok=True, n_spans=grp.n_spans,
-                                  probe=grp.probe)
-        if latency_enabled():
-            # one boundary dict per group, attached to every request
-            # BEFORE its done event fires: the fast-path forwarder reads
-            # stage_ns the instant the wait returns, and the frame's
-            # queue/pack/device/harvest stages are exactly these
-            # boundaries diffed (selftelemetry/latency.StageClock)
-            stage_ns = {"pack0": grp.t_pack0, "dispatch": grp.t_dispatch,
-                        "harvest0": t_h0, "end": time.monotonic_ns(),
-                        "overlap_ms": grp.overlap_ms,
-                        "fused": grp.fused}
-            if grp.fused and grp.shape is not None:
-                # bucket label for the latency ledger's exemplar join
-                # (worst fused frame -> this bucket's compile event +
-                # cost-ledger row)
-                stage_ns["fused_bucket"] = "r{}x{}".format(*grp.shape)
-            if grp.attrib is not None:
-                # the sampled intra-fused waterfall rides the same
-                # boundary dict into StageClock.merge_engine
-                stage_ns["device_attrib"] = grp.attrib
-            for r in grp.reqs:
-                r.stage_ns = stage_ns
-        try:
-            if len(grp.reqs) == 1:
-                grp.reqs[0].scores = scores
-                grp.reqs[0].signal_done()
-            else:
-                off = 0
+            if latency_enabled():
+                # one boundary dict per group, attached to every request
+                # BEFORE its done event fires: the fast-path forwarder
+                # reads stage_ns the instant the wait returns, and the
+                # frame's queue/pack/device/harvest stages are exactly
+                # these boundaries diffed
+                # (selftelemetry/latency.StageClock)
+                stage_ns = {"pack0": grp.t_pack0,
+                            "dispatch": grp.t_dispatch,
+                            "harvest0": t_h0, "end": time.monotonic_ns(),
+                            "overlap_ms": grp.overlap_ms,
+                            "fused": grp.fused, "call": grp.call}
+                if grp.fused and grp.shape is not None:
+                    # bucket label for the latency ledger's exemplar
+                    # join (worst fused frame -> this bucket's compile
+                    # event + cost-ledger row)
+                    stage_ns["fused_bucket"] = "r{}x{}".format(*grp.shape)
+                if grp.attrib is not None:
+                    # the sampled intra-fused waterfall rides the same
+                    # boundary dict into StageClock.merge_engine
+                    stage_ns["device_attrib"] = grp.attrib
                 for r in grp.reqs:
-                    n_r = len(r.batch)
-                    r.scores = scores[off:off + n_r]
-                    off += n_r
+                    r.stage_ns = stage_ns
+            try:
+                if len(grp.reqs) == 1:
+                    grp.reqs[0].scores = scores
+                    grp.reqs[0].signal_done()
+                else:
+                    off = 0
+                    for r in grp.reqs:
+                        n_r = len(r.batch)
+                        r.scores = scores[off:off + n_r]
+                        off += n_r
+                        r.signal_done()
+            finally:
+                # no request may hang on a half-failed split: unset
+                # events fire with scores=None (caller passes through,
+                # counter fires); signal_done is a no-op on requests
+                # already signaled above
+                for r in grp.reqs:
                     r.signal_done()
-        finally:
-            # no request may hang on a half-failed split: unset events fire
-            # with scores=None (caller passes through, counter fires);
-            # signal_done is a no-op on requests already signaled above
-            for r in grp.reqs:
-                r.signal_done()
         t_end = time.monotonic_ns()
         # device-occupancy accounting: the union of [dispatch, harvest-end]
         # intervals is an upper bound on device busy time (it includes
@@ -1648,8 +1706,9 @@ class ScoringEngine:
                              harvest_ms: float) -> None:
         """TPU-stage span attributes: device, coalesced batch shape,
         padding waste, queue wait, per-stage split, pipeline overlap, and
-        the compile-vs-execute first-call split (jit compilation dominates
-        call 0; the difference to call 1 is the estimated compile share)."""
+        the call's serial (``call`` on the engine/* trace annotations and
+        in the frames' stage_ns: which call, of which rung, a frame
+        rode)."""
         sp = grp.span
         sp.set_attr("model", self.cfg.model)
         sp.set_attr("device",
@@ -1669,29 +1728,5 @@ class ScoringEngine:
             sp.set_attr("padding.waste", grp.padding_waste)
         if grp.bucket_hit is not None:
             sp.set_attr("bucket.hit", grp.bucket_hit)
-        if self._device_calls == 0:
-            self._first_call_ms = dt_ms
-            sp.set_attr("jit.first_call", True)
-        elif self._device_calls == 1:
-            est = max(self._first_call_ms - dt_ms, 0.0)
-            sp.set_attr("jit.compile_est_ms", round(est, 3))
-            meter.set_gauge("odigos_anomaly_jit_compile_est_ms",
-                            round(est, 3))
-            # attribute to the backend's real jit site (matches the
-            # track_jit registration); zscore's kernels register as
-            # zscore.score/zscore.update — score is what first-call pays.
-            # Skip on warm-started engines (the ladder already recorded
-            # the real compiles — call 0 is warm, est is pure jitter)
-            # and below 1 ms (scheduler noise must not read as a
-            # post-warmup recompile in the per-site ledger).
-            site = getattr(self.backend, "jit_site", None) or (
-                "zscore.score" if self.cfg.model == "zscore" else None)
-            if site is not None and not self.cfg.warm_ladder \
-                    and est >= 1.0:
-                tid = getattr(sp, "trace_id", None)
-                _record_compile_event(
-                    site, est / 1e3,
-                    shape="x".join(map(str, grp.shape))
-                    if grp.shape else None,
-                    trace_id=f"{tid:032x}" if tid is not None else None)
+        sp.set_attr("call.serial", grp.call)
         self._device_calls += 1

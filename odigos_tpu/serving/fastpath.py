@@ -91,7 +91,8 @@ from ..hooks.tracecontext import _active
 from ..pdata.spans import SpanBatch
 from ..selftelemetry.flow import FlowContext
 from ..selftelemetry.latency import (
-    PREDICTED_BLAME, RECENT_WINDOW, Stage, claim_clock, latency_ledger)
+    PREDICTED_BLAME, RECENT_WINDOW, Stage, annotate, claim_clock,
+    latency_ledger, name_thread)
 from ..utils.telemetry import labeled_key, meter
 from .engine import PASSTHROUGH_METRIC, ScoringEngine
 from .fused import FALLBACK_REASONS, extract_columns, fused_enabled
@@ -571,6 +572,7 @@ class IngestFastPath:
         ``self._stop``): a lane surviving a shutdown→start cycle must
         keep seeing its epoch's SET flag, not run on as an extra
         uncounted lane the operator never sized for."""
+        name_thread(f"odigos-submit-{lane}")
         pool = self._pools[lane] if self._pools is not None else None
         while True:
             with self._lock:
@@ -626,13 +628,15 @@ class IngestFastPath:
             try:
                 feats = None
                 if cols is None:
-                    if self._needs_features:
-                        # lease_scope(None) is an explicit plain-numpy
-                        # scope, so one call site covers pooled and not
-                        with lease_scope(lease):
-                            feats = featurize(frame.batch,
-                                              self._feat_cfg)
-                    clock.stamp(Stage.FEATURIZE)
+                    with annotate("fastpath/featurize", clock,
+                                  Stage.FEATURIZE):
+                        if self._needs_features:
+                            # lease_scope(None) is an explicit plain-
+                            # numpy scope, so one call site covers
+                            # pooled and not
+                            with lease_scope(lease):
+                                feats = featurize(frame.batch,
+                                                  self._feat_cfg)
                     if lease is not None:
                         # the engine's reference, taken BEFORE submit:
                         # the worker can consume the request (and fire
@@ -645,18 +649,18 @@ class IngestFastPath:
                 # tpuanomaly contract). The on_done callback is the
                 # completion queue — fired by the engine the instant
                 # scores land, replacing the old done.wait() poll.
-                req = self.engine.submit(
-                    frame.batch, feats, deadline_ns=deadline,
-                    on_done=lambda r, f=frame: self._completed(f, r),
-                    on_features_consumed=lease.release
-                    if lease is not None else None,
-                    columns=cols)
-                if req is None and lease is not None:
-                    # no request was enqueued: the engine will never
-                    # fire the features-consumed hook
-                    lease.release()
-                    retained = False
-                clock.stamp(Stage.ENQUEUE)
+                with annotate("fastpath/enqueue", clock, Stage.ENQUEUE):
+                    req = self.engine.submit(
+                        frame.batch, feats, deadline_ns=deadline,
+                        on_done=lambda r, f=frame: self._completed(f, r),
+                        on_features_consumed=lease.release
+                        if lease is not None else None,
+                        columns=cols)
+                    if req is None and lease is not None:
+                        # no request was enqueued: the engine will
+                        # never fire the features-consumed hook
+                        lease.release()
+                        retained = False
             except Exception:  # noqa: BLE001 — a frame must never kill the lane
                 # featurize/submit failure: lossless unscored
                 # pass-through (the frame was already accepted on the
@@ -799,34 +803,35 @@ class IngestFastPath:
         gate = self._gate
         stop = self._stop
         if not frame.tagged:
-            try:
-                scores = None
-                if req is not None and not frame.expired:
-                    scores = req.scores  # final: assigned before done
-                if scores is not None and req.stage_ns is not None:
-                    # fold the engine call's queue/pack/device/harvest
-                    # boundaries into this frame's timeline (same
-                    # monotonic clock domain); WAIT then measures
-                    # score-landing → lane-pickup — the completion-queue
-                    # handoff, no longer the old forwarder's
-                    # head-of-line wait
-                    clock.merge_engine(req.stage_ns)
-                clock.stamp(Stage.WAIT)
-                frame.out = frame.batch if scores is None else \
-                    tag_anomalies(frame.batch, scores, self.threshold)
-                # only after tag succeeds: a frame whose tagging raised
-                # never forwards, and observing it scored=True would
-                # keep the scored_fraction SLO green during exactly the
-                # failure it exists to burn on
-                frame.scored = scores is not None
-            except Exception:  # noqa: BLE001 — a frame never kills a lane
-                # tag failure: the frame cannot forward, but it still
-                # passes the gate and releases its reservation below —
-                # wedging the ordered sequence on one bad frame would
-                # park every later frame forever
-                meter.add(self._errors_key)
-                frame.out = None
-            clock.stamp(Stage.TAG)
+            with annotate("lane/tag", clock, Stage.TAG):
+                try:
+                    scores = None
+                    if req is not None and not frame.expired:
+                        scores = req.scores  # final: assigned before done
+                    if scores is not None and req.stage_ns is not None:
+                        # fold the engine call's queue/pack/device/
+                        # harvest boundaries into this frame's timeline
+                        # (same monotonic clock domain); WAIT then
+                        # measures score-landing → lane-pickup — the
+                        # completion-queue handoff, no longer the old
+                        # forwarder's head-of-line wait
+                        clock.merge_engine(req.stage_ns)
+                    clock.stamp(Stage.WAIT)
+                    frame.out = frame.batch if scores is None else \
+                        tag_anomalies(frame.batch, scores, self.threshold)
+                    # only after tag succeeds: a frame whose tagging
+                    # raised never forwards, and observing it
+                    # scored=True would keep the scored_fraction SLO
+                    # green during exactly the failure it exists to
+                    # burn on
+                    frame.scored = scores is not None
+                except Exception:  # noqa: BLE001 — a frame never kills a lane
+                    # tag failure: the frame cannot forward, but it
+                    # still passes the gate and releases its reservation
+                    # below — wedging the ordered sequence on one bad
+                    # frame would park every later frame forever
+                    meter.add(self._errors_key)
+                    frame.out = None
             frame.tagged = True
         offered = False
         if gate is not None and not stop.is_set():
@@ -846,17 +851,18 @@ class IngestFastPath:
             frame.retiring = True
             offered = True
         try:
-            if frame.out is not None:
-                self.downstream.consume(frame.out)
-        except Exception:  # noqa: BLE001 — edge-accounted; keep serving
-            meter.add(self._errors_key)
+            with annotate("lane/forward", clock, Stage.FORWARD):
+                try:
+                    if frame.out is not None:
+                        self.downstream.consume(frame.out)
+                except Exception:  # noqa: BLE001 — edge-accounted; keep serving
+                    meter.add(self._errors_key)
         finally:
             try:
                 # observed even when consume raises: a downstream
                 # outage is exactly when the SLO tracker must keep
                 # seeing frames (an unfed tracker reads burn 0.0
                 # during the incident it exists to page on)
-                clock.stamp(Stage.FORWARD)
                 latency_ledger.observe(self.pipeline, clock,
                                        scored=frame.scored,
                                        n_spans=len(frame.batch))

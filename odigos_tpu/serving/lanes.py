@@ -40,6 +40,7 @@ import threading
 from collections import deque
 from typing import Any, Callable, Optional
 
+from ..selftelemetry.latency import name_thread
 from ..utils.telemetry import labeled_key, meter
 
 LANE_RETIRED_METRIC = "odigos_fastpath_lane_retired_frames_total"
@@ -187,6 +188,7 @@ class RetirementLanes:
 
     # -------------------------------------------------------------- lane
     def _run(self, idx: int, stop: threading.Event) -> None:
+        name_thread(f"odigos-lane-{idx}")
         retired_key = self._retired_keys[idx]
         while True:
             with self._ready:

@@ -24,7 +24,8 @@ from ..components.api import ComponentKind, Factory, Receiver, Signal, register
 from ..pdata.spans import SpanKind
 from ..selftelemetry.flow import FlowContext, flow_ledger
 from ..selftelemetry.latency import (
-    Stage, publish_clock, start_clock, unpublish_clock)
+    Stage, annotate, name_thread, publish_clock, start_clock,
+    unpublish_clock)
 from ..selftelemetry.tracer import is_selftelemetry_batch, tracer
 from ..utils.framing import recv_exact as _recv_exact
 from ..utils.telemetry import labeled_key, meter
@@ -264,6 +265,9 @@ class WireReceiver(Receiver):
 
         class Handler(socketserver.BaseRequestHandler):
             def setup(self):
+                # one thread per connection: the trace's host plane
+                # shows each as a receiver's line
+                name_thread("odigos-receiver")
                 with receiver._conns_lock:
                     receiver._conns.add(self.request)
 
@@ -288,8 +292,9 @@ class WireReceiver(Receiver):
                         # fast path adopts it across the consume seam
                         # (no-op object when ODIGOS_LATENCY=0)
                         clock = start_clock()
-                        verdict = receiver.admission.admit(payload_len)
-                        clock.stamp(Stage.ADMISSION)
+                        with annotate("wire/admission", clock,
+                                      Stage.ADMISSION):
+                            verdict = receiver.admission.admit(payload_len)
                         if verdict is not None:
                             # pre-decode rejection: drain the socket bytes,
                             # never allocate/decode, tell client to back off
@@ -306,7 +311,9 @@ class WireReceiver(Receiver):
                             if payload is None:
                                 return
                             try:
-                                batch, tp = decode_frame(payload)
+                                with annotate("wire/decode", clock,
+                                              Stage.DECODE):
+                                    batch, tp = decode_frame(payload)
                             except Exception:
                                 # corrupt payload is permanent: MALFORMED
                                 # tells the client to drop, not retry
@@ -322,7 +329,6 @@ class WireReceiver(Receiver):
                                     signal="frames")
                                 sock.sendall(MALFORMED)
                                 continue
-                            clock.stamp(Stage.DECODE)
                             token = publish_clock(clock)
                             try:
                                 if is_selftelemetry_batch(batch):

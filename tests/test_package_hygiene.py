@@ -535,9 +535,14 @@ class TestLatencyStageHygiene:
                 with open(path) as f:
                     tree = ast.parse(f.read(), path)
                 for node in ast.walk(tree):
-                    if not (isinstance(node, ast.Call)
-                            and isinstance(node.func, ast.Attribute)
-                            and node.func.attr == "stamp"):
+                    # ``<clock>.stamp(Stage.X)``, or the helper that
+                    # stamps on its way out:
+                    # ``annotate("<name>", clock, Stage.X)``
+                    if not (isinstance(node, ast.Call) and (
+                            (isinstance(node.func, ast.Attribute)
+                             and node.func.attr == "stamp")
+                            or (isinstance(node.func, ast.Name)
+                                and node.func.id == "annotate"))):
                         continue
                     for arg in node.args:
                         if (isinstance(arg, ast.Attribute)
@@ -585,6 +590,71 @@ class TestLatencyStageHygiene:
         assert set(ALL_STAGES) - set(STAGES) == {Stage.FUSED.value}
         for v in ALL_STAGES:
             assert re.fullmatch(r"[a-z_]+", v), v
+
+
+class TestAnnotationHygiene:
+    """Trace-annotation lint (ISSUE 25): ``latency.ANNOTATIONS`` and the
+    ``annotate("<name>", ...)`` sites hold each other, in both
+    directions. A name with no site is a stage the trace never shows; a
+    name at two sites is two different stretches of work under one
+    label; a site whose name is not in the tuple is an annotation no
+    reader knows of. Names are literals, so that the trace reduction
+    (benchmark/hosttrace.py) and this lint can find them."""
+
+    def _sites(self) -> dict[str, list[str]]:
+        sites: dict[str, list[str]] = {}
+        for dirpath, _dirs, names in os.walk(PKG_ROOT):
+            for n in names:
+                if not n.endswith(".py"):
+                    continue
+                path = os.path.join(dirpath, n)
+                rel = os.path.relpath(path, PKG_ROOT)
+                with open(path) as f:
+                    tree = ast.parse(f.read(), path)
+                for node in ast.walk(tree):
+                    if not (isinstance(node, ast.Call)
+                            and isinstance(node.func, ast.Name)
+                            and node.func.id == "annotate"):
+                        continue
+                    first = node.args[0] if node.args else None
+                    name = first.value if isinstance(first, ast.Constant) \
+                        else f"<not a literal: {ast.dump(first)[:40]}>"
+                    sites.setdefault(name, []).append(
+                        f"{rel}:{node.lineno}")
+        return sites
+
+    def test_tuple_and_sites_hold_each_other(self):
+        from odigos_tpu.selftelemetry.latency import ANNOTATIONS
+
+        assert len(ANNOTATIONS) == len(set(ANNOTATIONS))
+        sites = self._sites()
+        problems = []
+        for name in ANNOTATIONS:
+            where = sites.pop(name, [])
+            if len(where) != 1:
+                problems.append(f"{name}: {len(where)} sites {where} "
+                                f"(must be exactly 1)")
+        for name, where in sites.items():
+            problems.append(f"annotate({name!r}) at {where} is not in "
+                            f"latency.ANNOTATIONS")
+        assert not problems, "\n  ".join(problems)
+
+    def test_a_stamping_annotation_is_named_for_its_stage(self):
+        """``annotate("<layer>/<stage>", clock, Stage.X)``: the name's
+        last word is the stage's label, so the trace and the waterfall
+        speak of the same thing."""
+        from odigos_tpu.selftelemetry.latency import ANNOTATIONS, Stage
+
+        labels = {s.value for s in Stage}
+        assert {"pack", "harvest", "featurize", "enqueue", "admission",
+                "decode", "tag", "forward"} <= labels
+        for name in ANNOTATIONS:
+            layer, _, stage = name.partition("/")
+            assert layer in ("wire", "fastpath", "engine", "lane"), name
+            assert re.fullmatch(r"[a-z]+", stage), name
+        # the waits have no annotation: nobody works in them
+        for wait in ("submit", "queue", "device", "wait"):
+            assert not any(n.endswith("/" + wait) for n in ANNOTATIONS)
 
 
 class TestFleetRuleHygiene:
