@@ -12,7 +12,9 @@ Design — a two-stage software pipeline over one worker thread:
 * the worker's **pack stage** drains the queue, **coalesces** pending requests
   into a single device call (big batches feed the MXU), featurizes/packs on
   the host, and *dispatches* the device call without blocking on its result
-  (JAX async dispatch);
+  (JAX async dispatch). A call is closed on the ladder rung it will fill:
+  its budget is packed rows on a rung, and the request that would spill
+  past it is held to lead the next call (``_collect``);
 * up to ``pipeline_depth`` device calls ride **in flight** at once: while
   call N executes on the device, the worker packs and dispatches call N+1 —
   the host/device overlap that closes the serial featurize→execute→fetch
@@ -106,12 +108,25 @@ STAGE_PACK_METRIC = "odigos_anomaly_stage_pack_ms"
 STAGE_DEVICE_METRIC = "odigos_anomaly_stage_device_ms"
 STAGE_HARVEST_METRIC = "odigos_anomaly_stage_harvest_ms"
 ADAPTIVE_CAP_GAUGE = "odigos_engine_adaptive_cap_spans"
+COALESCE_CLOSED_METRIC = "odigos_anomaly_coalesce_closed_total"
+RUNG_SPILL_METRIC = "odigos_anomaly_rung_spill_total"
 MESH_UNAVAILABLE_METRIC = "odigos_engine_mesh_unavailable_total"
 
 # EWMA smoothing of the per-span device-step cost estimate; 0.2 follows
 # load shifts within ~5 calls without letting one outlier call resize
 # the next batch
 _ADAPT_ALPHA = 0.2
+# spans a packed row is counted on to hold: the mean less this many mean
+# deviations (the retransmit timer's srtt + 4 rttvar, turned round). A
+# call closed for a rung whose pack spills past it runs the next rung,
+# twice the time, so the estimate leans towards the row that holds less
+_ROW_MARGIN_DEVS = 4.0
+
+
+def _ewma(old: Optional[float], new: float) -> float:
+    """One step of the adaptive estimators' smoothing (None: first)."""
+    return new if old is None else \
+        (1 - _ADAPT_ALPHA) * old + _ADAPT_ALPHA * new
 
 
 def _mesh_label(mesh_spec) -> str:
@@ -242,8 +257,8 @@ class BucketLadder:
 
     ``round_rows`` maps a real packed row/trace count to the smallest ladder
     bucket that holds it (base, 2·base, 4·base, ...); counts beyond the top
-    bucket round up to a multiple of it (rare — max_batch_spans bounds the
-    coalesced call). ``observe`` tracks which shapes have already been
+    bucket round up to a multiple of it (rare — the coalescer closes a call
+    on a rung, so only a single request can pack past the top one). ``observe`` tracks which shapes have already been
     compiled this process (LRU-bounded so an adversarial shape storm cannot
     grow the table), feeding the bench's hit-rate and the zero-recompile
     assertion; ``mark_warm`` pre-seeds it from ``warm()`` compilations.
@@ -446,6 +461,9 @@ class SequenceBackend:
                          if cfg.model == "transformer"
                          else "autoencoder.score_spans")
         self.last_shape: Optional[list[int]] = None
+        # rows (traces, on the sequence route) the packer filled before
+        # the ladder padded them up to last_shape[0]
+        self.last_real_rows: Optional[int] = None
         self.last_padding_waste: Optional[float] = None
         self.last_bucket_hit: Optional[bool] = None
         self.variables = variables if variables is not None else \
@@ -500,6 +518,12 @@ class SequenceBackend:
             jnp.asarray(packed.segments),
             jnp.asarray(packed.positions))
 
+    def _round_rows(self, real: int) -> int:
+        """The ladder's rounding, remembering what it rounded: the
+        engine learns spans per row from the rows that were filled."""
+        self.last_real_rows = int(real)
+        return self.ladder.round_rows(real)
+
     def pack(self, batch: SpanBatch, features: SpanFeatures) -> Any:
         """Pack stage, the host's half: featurize/pack/pad into the
         arrays of one device call. Returns what ``enqueue`` takes."""
@@ -507,7 +531,7 @@ class SequenceBackend:
             # packed rows: block-diagonal attention, ~6x the MXU density of
             # naive per-trace padding (bench.py measures this path)
             packed = pack_sequences(batch, features, max_len=self.max_len,
-                                    pad_rows_to=self.ladder.round_rows)
+                                    pad_rows_to=self._round_rows)
             # scoring-span attributes: device shape + padding waste (the
             # MXU-density evidence the bench trajectory reads offline)
             self.last_shape = list(packed.categorical.shape[:2])
@@ -517,7 +541,7 @@ class SequenceBackend:
 
         seqs = assemble_sequences(
             batch, features, max_len=self.max_len,
-            pad_traces_to=self.ladder.round_rows)
+            pad_traces_to=self._round_rows)
         self.last_shape = list(seqs.categorical.shape[:2])
         self.last_padding_waste = round(1.0 - float(seqs.mask.mean()), 4) \
             if seqs.mask.size else 0.0
@@ -819,6 +843,13 @@ class _InflightGroup:
     # engine/* trace annotations, ``call.serial`` on the tpu/score span,
     # ``call`` in the frames' stage_ns
     call: int = 0
+    # rows the packer filled before the ladder padded them to shape[0]
+    # (None: the fused route and ladderless backends report none)
+    real_rows: Optional[int] = None
+    # why the coalescer closed this call (drained, rung, cap), and
+    # whether it was closed for one rung and packed past it
+    closed: str = "drained"
+    spilled: bool = False
 
 
 class ScoringEngine:
@@ -919,6 +950,11 @@ class ScoringEngine:
         self._depth = max(1, self.cfg.pipeline_depth) \
             if callable(getattr(self.backend, "dispatch", None)) else 1
         self._queue: queue.Queue[ScoreRequest] = queue.Queue(self.cfg.max_queue)
+        # the request taken off the queue that the last call had no room
+        # for (queue.Queue cannot be peeked): at most one, it leads the
+        # next call. A deque for its atomic append/popleft: shutdown()
+        # takes it from another thread when the worker is gone.
+        self._held: deque[ScoreRequest] = deque()
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         # serializes backend access between the worker and warmup(): a
@@ -955,9 +991,23 @@ class ScoringEngine:
         # the first call retires — no estimate means no adaptive cap.
         self._ewma_call_ms: Optional[float] = None
         self._ewma_call_spans: Optional[float] = None
+        # spans per REAL packed row (the rows the packer filled, before
+        # the ladder padded them) and its mean deviation: what converts
+        # a span budget to rows on a rung (_spans_per_row)
         self._ewma_spans_per_row: Optional[float] = None
+        self._ewma_spans_per_row_dev = 0.0
         self._ewma_harvest_ms = 0.0
         self._last_adaptive_cap: Optional[int] = None
+        # what a call of each rung costs the device, by padded rows: the
+        # time a retired call had the device to itself (_retire_inner)
+        self._rung_ms: dict[int, float] = {}
+        # why the call just collected was closed (drained, rung, cap)
+        # and the rung it was closed for (None: no rung to fill): the
+        # pack that comes out past that rung is a spill (worker-owned)
+        self._closed: tuple[str, Optional[int]] = ("drained", None)
+        self._closed_keys = {
+            reason: labeled_key(COALESCE_CLOSED_METRIC, reason=reason)
+            for reason in ("drained", "rung", "cap")}
         # per-mesh step-cost learning (ISSUE 7 tentpole d): the estimate
         # is keyed by (model, mesh) so deadline-sized coalescing scales
         # with device count instead of assuming one chip — an 8-device
@@ -982,7 +1032,8 @@ class ScoringEngine:
             prior = ScoringEngine._ADAPT_PRIORS.get(self._adapt_key)
             if prior is not None:
                 (self._ewma_call_ms, self._ewma_call_spans,
-                 self._ewma_spans_per_row, self._ewma_harvest_ms) = prior
+                 self._ewma_spans_per_row, self._ewma_spans_per_row_dev,
+                 self._ewma_harvest_ms) = prior
         if self.mesh is not None:
             self._adaptive_gauge_key = labeled_key(
                 ADAPTIVE_CAP_GAUGE, model=self.cfg.model,
@@ -1036,11 +1087,11 @@ class ScoringEngine:
         # fail-fast any request that raced past submit()'s stop check after
         # the worker's final queue-empty observation (TOCTOU): its done
         # event must still fire or a score_sync caller eats the full
-        # deadline for a request nothing will ever score
+        # deadline for a request nothing will ever score. The request a
+        # dead (or wedged) worker left held goes the same way, first
         while True:
-            try:
-                req = self._queue.get_nowait()
-            except queue.Empty:
+            req = self._take(block=False)
+            if req is None:
                 break
             req.release_features()  # never dispatched: nothing read them
             req.scores = None
@@ -1105,7 +1156,7 @@ class ScoringEngine:
                              else None)
             return None
         FlowContext.watermark(f"engine/{self.cfg.model}", "queue_depth",
-                              self._queue.qsize())
+                              self._queued())
         return req
 
     def score_sync(self, batch: SpanBatch,
@@ -1168,7 +1219,7 @@ class ScoringEngine:
         wall = (now - self._t_run0) if self._t_run0 else 0
         out: dict[str, Any] = {
             "model": self.cfg.model,
-            "queue_depth": self._queue.qsize(),
+            "queue_depth": self._queued(),
             "inflight": inflight,
             "window_occupancy": round(inflight / self._depth, 4),
             "pipeline_depth": self._depth,
@@ -1218,6 +1269,9 @@ class ScoringEngine:
         out["adaptive"] = {
             "ms_per_span": self._ms_per_span(),
             "spans_per_row": self._ewma_spans_per_row,
+            "spans_per_row_dev": round(self._ewma_spans_per_row_dev, 4),
+            "rung_ms": {r: round(ms, 3)
+                        for r, ms in sorted(self._rung_ms.items())},
             "harvest_ms": round(self._ewma_harvest_ms, 4),
             "last_cap_spans": self._last_adaptive_cap,
             "mesh": self._mesh_label,
@@ -1242,7 +1296,7 @@ class ScoringEngine:
         inflight: deque[_InflightGroup] = deque()
         while True:
             stopping = stop.is_set()
-            if stopping and not inflight and self._queue.empty():
+            if stopping and not inflight and not self._queued():
                 return
             # keep-serving backstop: _dispatch_group/_retire fail their own
             # requests on error, but nothing outside those narrow trys may
@@ -1281,48 +1335,154 @@ class ScoringEngine:
             _log.error("engine/%s %s (frames forward unscored)",
                        self.cfg.model, text)
 
-    def _collect(self, block: bool) -> Optional[list[ScoreRequest]]:
-        """Pack-stage intake: one request (blocking briefly only when the
-        pipeline is idle) plus whatever else is already waiting (bounded
-        coalescing). Deadline-carrying requests size the coalesced call
-        adaptively (``_adaptive_cap``): batches grow under load while the
-        oldest deadline affords it and shrink back when it does not, so
-        harvest latency — not queue wait — bounds the request's p99."""
+    def _queued(self) -> int:
+        """Requests waiting for a call: the queue and the held one."""
+        return self._queue.qsize() + len(self._held)
+
+    def _take(self, block: bool) -> Optional[ScoreRequest]:
+        """The next request in arrival order: the held one, else the
+        queue's (waiting briefly when ``block``), else None."""
+        try:
+            return self._held.popleft()
+        except IndexError:
+            pass
         try:
             if block:
-                first = self._queue.get(timeout=0.05)
-            else:
-                first = self._queue.get_nowait()
+                return self._queue.get(timeout=0.05)
+            return self._queue.get_nowait()
         except queue.Empty:
+            return None
+
+    def _collect(self, block: bool) -> Optional[list[ScoreRequest]]:
+        """Pack-stage intake: one request (blocking briefly only when the
+        pipeline is idle) plus whatever else is already waiting, up to
+        the call's budget (``_budget``). Where the backend has a ladder
+        the budget is rows on a rung and is never overshot: the request
+        whose rows would spill past it is held and leads the next call,
+        and a call grows from one rung into the next only where that
+        scores more spans per device millisecond (``_climb_pays``).
+        Backends without a ladder have no rung to fill: their budget is
+        in spans and the request that reaches it closes the call."""
+        first = self._take(block)
+        if first is None:
             return None
         reqs = [first]
         total = len(first.batch)
-        cap = self.cfg.max_batch_spans
+        cap, row_cap = self._budget(first.deadline_ns)
         if first.deadline_ns is not None:
-            cap = min(cap, self._adaptive_cap(first.deadline_ns))
             self._last_adaptive_cap = cap
             meter.set_gauge(self._adaptive_gauge_key, cap)
-        while total < cap:
-            try:
-                nxt = self._queue.get_nowait()
-            except queue.Empty:
+        reason, rung = "drained", None
+        if row_cap is None:
+            while total < cap:
+                nxt = self._take(block=False)
+                if nxt is None:
+                    break
+                reqs.append(nxt)
+                total += len(nxt.batch)
+            else:
+                reason = "cap"
+        else:
+            ladder = self.backend.ladder
+            per_row = self._spans_per_row()
+            rung = ladder.round_rows(math.ceil(total / per_row))
+            while True:
+                nxt = self._take(block=False)
+                if nxt is None:
+                    break
+                grown = total + len(nxt.batch)
+                rows = math.ceil(grown / per_row)
+                if grown > self.cfg.max_batch_spans:
+                    reason = "cap"
+                elif rows > row_cap or (
+                        rows > rung and not self._climb_pays(
+                            rung, row_cap, total, grown, len(reqs) + 1)):
+                    reason = "rung"
+                else:
+                    reqs.append(nxt)
+                    total, rung = grown, ladder.round_rows(rows)
+                    continue
+                self._held.append(nxt)
                 break
-            reqs.append(nxt)
-            total += len(nxt.batch)
+        self._closed = (reason, rung)
+        meter.add(self._closed_keys[reason])
         # re-report the drained depth: watermark consumers (the wire
         # receiver's admission gate) read the CURRENT value — leaving the
         # submit-time high reading in place would keep shedding traffic
         # long after the queue emptied
         FlowContext.watermark(f"engine/{self.cfg.model}", "queue_depth",
-                              self._queue.qsize())
+                              self._queued())
         return reqs
+
+    def _climb_pays(self, rung: int, row_cap: int, total: int,
+                    grown: int, taken: int) -> bool:
+        """Whether a call that fills ``rung`` with ``total`` spans should
+        grow into a higher one: yes where some rung the budget allows,
+        filled with what is waiting, scores more spans per device
+        millisecond than closing here does. What waits is ``grown`` (the
+        call and the request in hand, ``taken`` requests) plus the queue
+        counted in requests of that mean size."""
+        ladder = self.backend.ladder
+        per_row = self._spans_per_row()
+        waiting = min(grown + self._queue.qsize() * grown / taken,
+                      self.cfg.max_batch_spans)
+        stay = total / self._rung_cost(rung)
+        return any(
+            min(waiting, b * per_row) / self._rung_cost(b) >= stay
+            for b in ladder.buckets if rung < b <= row_cap)
+
+    def _rung_cost(self, rows: int) -> float:
+        """Device milliseconds of a call of ``rows`` padded rows, as the
+        engine observed that rung; a rung not dispatched yet costs by its
+        rows from the nearest one that was (no rung seen: rows)."""
+        seen = self._rung_ms
+        if rows in seen:
+            return seen[rows]
+        if not seen:
+            return float(rows)
+        near = min(seen, key=lambda r: abs(r - rows))
+        return seen[near] * rows / near
+
+    def _spans_per_row(self) -> Optional[float]:
+        """Spans a packed row is counted on to hold (None until a call
+        with real rows retired): the mean less ``_ROW_MARGIN_DEVS`` mean
+        deviations, and never under the one span a real row has."""
+        mean = self._ewma_spans_per_row
+        if not mean:
+            return None
+        return max(1.0, mean
+                   - _ROW_MARGIN_DEVS * self._ewma_spans_per_row_dev)
+
+    def _budget(self, deadline_ns: Optional[int]
+                ) -> tuple[int, Optional[int]]:
+        """What one coalesced call may hold, as (spans, rows). Spans: the
+        smaller of ``max_batch_spans`` and, under a deadline, what its
+        headroom affords at the observed per-span device-step cost. Rows:
+        that span budget over the spans a real packed row holds, snapped
+        DOWN onto a shape the ladder serves (never up into a recompile),
+        and the spans are then that rung's; None where the backend has no
+        ladder or no call with real rows has retired yet."""
+        spans = self.cfg.max_batch_spans
+        if deadline_ns is not None:
+            spans = min(spans, self._afford(deadline_ns))
+        ladder = getattr(self.backend, "ladder", None)
+        per_row = self._spans_per_row()
+        if ladder is None or per_row is None:
+            return max(1, spans), None
+        rows = ladder.floor_rows(spans / per_row)
+        return max(1, min(int(rows * per_row),
+                          self.cfg.max_batch_spans)), rows
 
     def _adaptive_cap(self, deadline_ns: int) -> int:
         """Span budget for one coalesced call such that its harvest is
-        expected inside ``deadline_ns``: remaining headroom divided by the
-        observed per-span device-step cost, snapped DOWN onto the bucket
-        ladder's precompiled row shapes (never up into a recompile). With
-        no estimate yet (cold engine) the fixed cap applies."""
+        expected inside ``deadline_ns`` (``_budget``'s spans)."""
+        return self._budget(deadline_ns)[0]
+
+    def _afford(self, deadline_ns: int) -> int:
+        """Spans whose call is expected to be harvested inside
+        ``deadline_ns``: remaining headroom over the observed per-span
+        device-step cost. With no estimate yet (cold engine) the fixed
+        cap applies."""
         per_span = self._ms_per_span()
         if per_span is None or per_span <= 0:
             return self.cfg.max_batch_spans
@@ -1335,13 +1495,7 @@ class ScoringEngine:
             # shipping minimal calls here would shrink batches exactly
             # when load demands growth and collapse throughput
             return self.cfg.max_batch_spans
-        afford = int(headroom_ms / per_span)
-        ladder = getattr(self.backend, "ladder", None)
-        spans_per_row = self._ewma_spans_per_row
-        if ladder is not None and spans_per_row and spans_per_row > 0:
-            rows = afford / spans_per_row
-            afford = int(ladder.floor_rows(rows) * spans_per_row)
-        return max(1, min(afford, self.cfg.max_batch_spans))
+        return int(headroom_ms / per_span)
 
     def _ms_per_span(self) -> Optional[float]:
         """Volume-weighted device-step cost per span (see __init__)."""
@@ -1382,6 +1536,7 @@ class ScoringEngine:
         attrib = None
         span_bucket = None
         cold_dispatch_s = 0.0
+        real_rows = None
         try:
             with lease_scope(lease), \
                     annotate("engine/pack", call=call) as packing:
@@ -1480,6 +1635,8 @@ class ScoringEngine:
                         shape = getattr(backend, "last_shape", None)
                         waste = getattr(backend, "last_padding_waste",
                                         None)
+                        real_rows = getattr(backend, "last_real_rows",
+                                            None)
         except Exception as e:
             self._note_error("dispatch", e)
             if self.failover is not None:
@@ -1503,6 +1660,13 @@ class ScoringEngine:
         # as the pool's residual steady-state misses (depth jitter)
         for r in reqs:
             r.release_features()
+        closed, rung = self._closed
+        spilled = bool(real_rows is not None and rung is not None
+                       and shape and shape[0] > rung)
+        if spilled:
+            # closed for one rung, packed past it: the call runs the
+            # next rung up, mostly padding
+            meter.add(RUNG_SPILL_METRIC)
         t1 = time.monotonic_ns()
         for r in reqs:
             # expiry blame marker (ISSUE 8): a deadline that dies after
@@ -1516,7 +1680,8 @@ class ScoringEngine:
             bucket_hit=bucket_hit, shape=shape, padding_waste=waste,
             lease=lease, backend=backend, probe=probe, fused=fused,
             attrib=attrib, span_bucket=span_bucket,
-            cold_dispatch_s=cold_dispatch_s, call=call)
+            cold_dispatch_s=cold_dispatch_s, call=call,
+            real_rows=real_rows, closed=closed, spilled=spilled)
 
     def _retire(self, grp: _InflightGroup) -> None:
         """Harvest stage: block on the oldest in-flight device call, split
@@ -1627,7 +1792,8 @@ class ScoringEngine:
         # intervals is an upper bound on device busy time (it includes
         # transfers); intervals overlap under depth>1, so clip to the
         # high-water mark instead of double counting
-        self._busy_ns += t_end - max(grp.t_dispatch, self._busy_until)
+        alone_ns = t_end - max(grp.t_dispatch, self._busy_until)
+        self._busy_ns += alone_ns
         self._busy_until = t_end
         wall = max(t_end - self._t_run0, 1)
         busy_frac = min(self._busy_ns / wall, 1.0)
@@ -1638,33 +1804,40 @@ class ScoringEngine:
         # adaptive-batching estimators: device-step cost (pack + device,
         # the wall the next group's deadline must absorb) and span volume
         # as SEPARATE EWMAs (ratio of averages — see __init__), spans per
-        # packed row (converts span budgets to ladder rows), and the
+        # real packed row with its mean deviation (converts span budgets
+        # to ladder rows), the rung's cost (the time this call had the
+        # device to itself: from its dispatch, or from the retirement of
+        # the call ahead where that came later, to its own), and the
         # harvest allowance subtracted from headroom
         if grp.n_spans > 0:
-            call_ms = pack_ms + device_ms
-            self._ewma_call_ms = call_ms \
-                if self._ewma_call_ms is None else \
-                (1 - _ADAPT_ALPHA) * self._ewma_call_ms \
-                + _ADAPT_ALPHA * call_ms
-            self._ewma_call_spans = float(grp.n_spans) \
-                if self._ewma_call_spans is None else \
-                (1 - _ADAPT_ALPHA) * self._ewma_call_spans \
-                + _ADAPT_ALPHA * grp.n_spans
-            if grp.shape and grp.shape[0] > 0:
-                spr = grp.n_spans / grp.shape[0]
-                self._ewma_spans_per_row = spr \
-                    if self._ewma_spans_per_row is None else \
-                    (1 - _ADAPT_ALPHA) * self._ewma_spans_per_row \
-                    + _ADAPT_ALPHA * spr
-        self._ewma_harvest_ms = (1 - _ADAPT_ALPHA) * self._ewma_harvest_ms \
-            + _ADAPT_ALPHA * harvest_ms
+            self._ewma_call_ms = _ewma(self._ewma_call_ms,
+                                       pack_ms + device_ms)
+            self._ewma_call_spans = _ewma(self._ewma_call_spans,
+                                          float(grp.n_spans))
+            if grp.real_rows and grp.shape:
+                spr = grp.n_spans / grp.real_rows
+                mean = self._ewma_spans_per_row
+                if mean is not None:
+                    self._ewma_spans_per_row_dev = _ewma(
+                        self._ewma_spans_per_row_dev, abs(spr - mean))
+                self._ewma_spans_per_row = _ewma(mean, spr)
+                rung = grp.shape[0]
+                ladder = getattr(backend, "ladder", None)
+                if grp.bucket_hit is not False and ladder is not None \
+                        and rung in ladder.buckets:
+                    # (a shape's first sight is its compile, not its
+                    # cost; a shape past the ladder is nobody's choice)
+                    self._rung_ms[rung] = _ewma(self._rung_ms.get(rung),
+                                                alone_ns / 1e6)
+        self._ewma_harvest_ms = _ewma(self._ewma_harvest_ms, harvest_ms)
         if self.mesh is not None and self._adapt_key is not None:
             # publish the learned per-mesh cost so the next engine on
             # this (model geometry, mesh) starts informed (dict store is
             # atomic; the worker is the only writer for this key)
             ScoringEngine._ADAPT_PRIORS[self._adapt_key] = (
                 self._ewma_call_ms, self._ewma_call_spans,
-                self._ewma_spans_per_row, self._ewma_harvest_ms)
+                self._ewma_spans_per_row, self._ewma_spans_per_row_dev,
+                self._ewma_harvest_ms)
         if grp.fused and grp.shape is not None:
             # device-plane ledger joins (ISSUE 20): the measured stamp
             # against XLA's expectation, and the cold-key compile as a
@@ -1724,6 +1897,11 @@ class ScoringEngine:
         sp.set_attr("harvest_ms", round(harvest_ms, 3))
         if grp.shape is not None:
             sp.set_attr("device.shape", "x".join(map(str, grp.shape)))
+        if grp.real_rows is not None:
+            sp.set_attr("rows.real", grp.real_rows)
+        sp.set_attr("coalesce.closed", grp.closed)
+        if grp.spilled:
+            sp.set_attr("rung.spill", True)
         if grp.padding_waste is not None:
             sp.set_attr("padding.waste", grp.padding_waste)
         if grp.bucket_hit is not None:

@@ -14,6 +14,7 @@ in-flight window. These tests pin the correctness contract of that overlap:
   the tpu/score spans carry the pipeline annotations.
 """
 
+import math
 import threading
 
 import numpy as np
@@ -231,7 +232,7 @@ def test_adaptive_cap_sizes_from_deadline_and_ladder():
     assert eng._adaptive_cap(_time.monotonic_ns() + 10_000_000) \
         == eng.cfg.max_batch_spans
     # seed observed step cost: 0.01 ms/span (ratio of averages:
-    # 100 ms over 10k spans), 4 spans/row, ladder {8, 16}
+    # 100 ms over 10k spans), 4 spans a REAL packed row, ladder {8, 16}
     eng._ewma_call_ms = 100.0
     eng._ewma_call_spans = 10_000.0
     eng._ewma_spans_per_row = 4.0
@@ -240,6 +241,16 @@ def test_adaptive_cap_sizes_from_deadline_and_ladder():
     # -> 64 spans: the cap lands on a precompiled shape
     cap = eng._adaptive_cap(_time.monotonic_ns() + 1_000_000)
     assert cap == 64
+    # the fixed cap goes through the same estimator onto the same rungs:
+    # (spans, rows) with no deadline at all
+    assert eng._budget(None) == (eng.cfg.max_batch_spans,
+                                 eng.cfg.max_batch_spans // 4)
+    # rows that vary in what they hold are counted on for less: four
+    # mean deviations off the mean (3.5 spans a row): 28 rows -> the
+    # bucket of 16 still, now worth 56 spans
+    eng._ewma_spans_per_row_dev = 0.125
+    assert eng._budget(_time.monotonic_ns() + 1_000_000) == (56, 16)
+    eng._ewma_spans_per_row_dev = 0.0
     # generous headroom still clamps to max_batch_spans
     cap = eng._adaptive_cap(_time.monotonic_ns() + int(1e12))
     assert cap == eng.cfg.max_batch_spans
@@ -277,9 +288,15 @@ def test_deadline_requests_update_estimators_and_score():
         np.testing.assert_array_equal(req.scores, want)
         assert eng._ms_per_span() is not None \
             and eng._ms_per_span() > 0
-        assert eng._ewma_spans_per_row is not None
+        # spans per row is learned from the rows the packer FILLED, not
+        # from the rung that padded them
+        real = eng.backend.last_real_rows
+        assert 0 < real < eng.backend.last_shape[0]
+        assert eng._ewma_spans_per_row == len(b) / real
         stats = eng.pipeline_stats()
         assert stats["adaptive"]["ms_per_span"] > 0
+        assert stats["adaptive"]["spans_per_row"] == len(b) / real
+        assert list(stats["adaptive"]["rung_ms"]) == []  # a first sight
     finally:
         eng.shutdown()
 
@@ -361,3 +378,289 @@ def test_failed_dispatch_is_logged_with_its_exception_text(caplog):
         assert "still no" in caplog.records[-1].getMessage()
     finally:
         eng.shutdown()
+
+
+# ----------------------------- closing a coalesced call on the rung it fills
+
+FRAME = 2620        # spans a wire frame carries (the benchmark's pool)
+PER_ROW = 57.5      # spans a real packed row holds on that traffic
+RUNG_MS = {256: 217.0, 512: 334.0, 1024: 637.0}
+
+
+class _Sized:
+    """A batch the coalescer only ever asks for its length."""
+
+    def __init__(self, n):
+        self.n = n
+
+    def __len__(self):
+        return self.n
+
+
+class _RungBackend:
+    """Ladder of 256/512/1,024 rows and a fixed spans-per-real-row; a
+    call reports the rows it filled and the rung that padded them."""
+
+    coalesce_columns = ()
+
+    def __init__(self, per_row=PER_ROW):
+        self.ladder = BucketLadder(256, 3)
+        self.per_row = per_row
+        self.calls = []
+
+    def score(self, batch, features):
+        n = len(batch)
+        self.last_real_rows = math.ceil(n / self.per_row)
+        self.last_shape = [self.ladder.round_rows(self.last_real_rows), 64]
+        self.last_bucket_hit = True
+        self.calls.append(n)
+        return np.arange(n, dtype=np.float32)
+
+
+def rung_engine(per_row=PER_ROW):
+    """An engine that has learned the benchmark cell's numbers: the cap
+    of 32768 spans, 57.5 spans a real row, the three rungs' costs."""
+    eng = ScoringEngine(EngineConfig(model="mock", max_batch_spans=32768))
+    eng.backend = _RungBackend(per_row)
+    eng._ewma_spans_per_row = PER_ROW
+    eng._rung_ms = dict(RUNG_MS)
+    return eng
+
+
+def enqueue(eng, sizes):
+    from odigos_tpu.features.featurizer import SpanFeatures
+    from odigos_tpu.serving.engine import ScoreRequest
+
+    reqs = []
+    for n in sizes:
+        feats = SpanFeatures(np.zeros((n, 1), np.int32),
+                             np.zeros((n, 1), np.float32))
+        reqs.append(ScoreRequest(batch=_Sized(n), features=feats))
+        eng._queue.put_nowait(reqs[-1])
+    return reqs
+
+
+def closed_counts():
+    from odigos_tpu.serving.engine import COALESCE_CLOSED_METRIC
+
+    return {r: meter.counter(f"{COALESCE_CLOSED_METRIC}{{reason={r}}}")
+            for r in ("drained", "rung", "cap")}
+
+
+def same(got, want):
+    return len(got) == len(want) and all(a is b for a, b in zip(got, want))
+
+
+@pytest.mark.parametrize("queued, takes, rung, reason", [
+    # 3-4 frames, the paced cell's every call: same rung, all of them
+    (4, 4, 256, "drained"),
+    # 6 frames: 5 in 217 ms beat 6 in 334 ms, the sixth leads the next
+    (6, 5, 256, "rung"),
+    # 9 frames: 9 in 334 ms beat 5 in 217 ms
+    (9, 9, 512, "drained"),
+    # a backlog: the cap is 568 rows, the rung under it holds 11 frames
+    (20, 11, 512, "rung"),
+])
+def test_call_closes_on_the_rung_it_fills(queued, takes, rung, reason):
+    eng = rung_engine()
+    reqs = enqueue(eng, [FRAME] * queued)
+    before = closed_counts()
+    call = eng._collect(block=False)
+    assert same(call, reqs[:takes])
+    assert eng._closed == (reason, rung)
+    after = closed_counts()
+    assert {r: after[r] - before[r] for r in after} == \
+        {r: float(r == reason) for r in after}
+    if takes < queued:
+        # the request that would have spilled was taken and not used:
+        # held, and the next call starts with it
+        assert same(list(eng._held), [reqs[takes]])
+        assert eng._collect(block=False)[0] is reqs[takes]
+    else:
+        assert not eng._held
+    eng.shutdown()
+
+
+def test_held_request_keeps_order_counts_as_queued_and_is_never_lost():
+    from odigos_tpu.selftelemetry.flow import flow_ledger
+
+    # FIFO across calls, and the held request is part of the queue depth
+    eng = rung_engine()
+    reqs = enqueue(eng, [FRAME] * 30)
+    first = eng._collect(block=False)
+    assert len(first) == 11 and eng._held[0] is reqs[11]
+    assert eng._queue.qsize() == 18 and eng._queued() == 19
+    assert eng.runtime_gauges()["queue_depth"] == 19
+    assert flow_ledger.watermark_current("engine/mock", "queue_depth") == 19
+    order = list(first)
+    while True:
+        call = eng._collect(block=False)
+        if call is None:
+            break
+        order += call
+    assert same(order, reqs)
+
+    # scored on shutdown(): the worker drains the queue AND the held one
+    eng = rung_engine()
+    reqs = enqueue(eng, [FRAME] * 13)
+    eng.start()
+    eng.shutdown()
+    assert eng.backend.calls == [11 * FRAME, 2 * FRAME]
+    assert all(r.done.is_set() and r.scores is not None
+               and len(r.scores) == FRAME for r in reqs)
+    assert not eng._held and eng._queued() == 0
+
+    # a worker that died holding a request: shutdown() fails it like the
+    # queue's (done fires, no scores, the callback runs once), in order
+    eng = rung_engine()
+    reqs = enqueue(eng, [FRAME] * 13)
+    failed = []
+    for r in reqs:
+        r.on_done = failed.append
+    taken = eng._collect(block=False)   # ... and the worker is gone
+    assert len(taken) == 11 and eng._held[0] is reqs[11]
+    eng.shutdown()
+    assert same(failed, reqs[11:])
+    assert all(r.done.is_set() and r.scores is None for r in reqs[11:])
+    assert not eng._held and eng._queued() == 0
+
+
+@pytest.mark.parametrize("first, second, reason", [
+    (40_000, FRAME, "cap"),    # over max_batch_spans by itself
+    (30_000, 100, "rung"),     # 522 rows: under the span cap, over 512 rows
+])
+def test_first_request_over_the_budget_goes_out_alone(first, second,
+                                                      reason):
+    eng = rung_engine()
+    reqs = enqueue(eng, [first, second])
+    before = closed_counts()
+    assert same(eng._collect(block=False), reqs[:1])
+    assert closed_counts()[reason] - before[reason] == 1
+    assert same(eng._collect(block=False), reqs[1:])
+    eng.shutdown()
+
+
+def test_rows_are_learned_from_real_rows_and_a_spill_is_counted():
+    """The pack says how many rows it filled: the estimator follows the
+    real rows (not the rung that padded them), the rung's cost is what
+    the call had of the device, and a call closed for one rung whose
+    pack came out past it counts as a spill."""
+    from odigos_tpu.selftelemetry.tracer import tracer
+    from odigos_tpu.serving.engine import RUNG_SPILL_METRIC
+
+    eng = rung_engine(per_row=50.0)   # packs thinner than was learned
+    enqueue(eng, [FRAME] * 13)
+    spills0 = meter.counter(RUNG_SPILL_METRIC)
+    tracer.ring.drain()
+    call = eng._collect(block=False)
+    assert len(call) == 11 and eng._closed == ("rung", 512)
+    grp = eng._dispatch_group(call, overlapped=False)
+    assert grp.real_rows == 577 and grp.shape == [1024, 64]
+    assert meter.counter(RUNG_SPILL_METRIC) - spills0 == 1
+    eng._retire(grp)
+    # one step of the EWMA from 57.5 towards 28,820 spans / 577 rows
+    spr = 11 * FRAME / 577
+    assert eng._ewma_spans_per_row == pytest.approx(
+        0.8 * PER_ROW + 0.2 * spr)
+    assert eng._ewma_spans_per_row_dev == pytest.approx(
+        0.2 * (PER_ROW - spr))
+    # ... and the margin it now carries: four mean deviations
+    assert eng._spans_per_row() == pytest.approx(
+        eng._ewma_spans_per_row - 4 * eng._ewma_spans_per_row_dev)
+    assert 0 < eng._rung_ms[1024] < RUNG_MS[1024]
+    span = [s for s in tracer.ring.snapshot() if s.name == "tpu/score"][-1]
+    assert span.attrs["rows.real"] == 577
+    assert span.attrs["device.shape"] == "1024x64"
+    assert span.attrs["coalesce.closed"] == "rung"
+    assert span.attrs["rung.spill"] is True
+    # the next call is closed with the margin: 9 frames, which is what
+    # 512 rows of 50 spans hold (10 would be 524 rows), not 11 again
+    enqueue(eng, [FRAME] * 11)
+    assert len(eng._collect(block=False)) == 9
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("seen, rows, cost", [
+    ({}, 512, 512.0),                          # nothing seen: by rows
+    ({256: 217.0}, 512, 434.0),                # by rows from the nearest
+    ({256: 217.0, 1024: 637.0}, 512, 434.0),   # (ties: the first seen)
+    ({256: 217.0, 512: 334.0}, 512, 334.0),    # seen: as observed
+])
+def test_rung_cost_is_the_observed_one_or_by_rows_from_the_nearest(
+        seen, rows, cost):
+    eng = rung_engine()
+    eng._rung_ms = dict(seen)
+    assert eng._rung_cost(rows) == pytest.approx(cost)
+
+
+def test_ladderless_backends_close_on_the_span_cap_as_before():
+    """zscore/mock/remote have no rung to fill: the budget stays in
+    spans, the request that reaches it closes the call, nothing is
+    held."""
+    eng = ScoringEngine(EngineConfig(model="mock", max_batch_spans=6000))
+    reqs = enqueue(eng, [FRAME] * 5)
+    before = closed_counts()
+    assert same(eng._collect(block=False), reqs[:3])   # 7,860 >= 6,000
+    assert not eng._held and eng._closed == ("cap", None)
+    assert same(eng._collect(block=False), reqs[3:])
+    after = closed_counts()
+    assert after["cap"] - before["cap"] == 1
+    assert after["drained"] - before["drained"] == 1
+    eng.shutdown()
+
+
+def test_no_request_is_lost_between_submitters_worker_and_shutdown():
+    """The held request is shared state (the worker holds and takes it,
+    shutdown() takes it when the worker is gone): under many submitters
+    and a shutdown in mid-stream every accepted request is signalled
+    exactly once, scored or failed, and those scored come back in the
+    order they were accepted."""
+    import sys
+    import time as _time
+
+    eng = rung_engine()
+    eng._queue.maxsize = 0          # admission is not what is tested
+    signalled = []
+    accepted = [[] for _ in range(16)]
+    go = threading.Event()
+
+    def submitter(mine):
+        from odigos_tpu.features.featurizer import SpanFeatures
+
+        go.wait(5.0)
+        for n in (FRAME, 3 * FRAME, 700, FRAME, 9000) * 6:
+            feats = SpanFeatures(np.zeros((n, 1), np.int32),
+                                 np.zeros((n, 1), np.float32))
+            req = eng.submit(_Sized(n), feats, on_done=signalled.append)
+            if req is not None:
+                mine.append(req)
+
+    threads = [threading.Thread(target=submitter, args=(mine,))
+               for mine in accepted]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        eng.start()
+        for t in threads:
+            t.start()
+        go.set()
+        _time.sleep(0.05)
+        eng.shutdown()              # submitters still running
+        for t in threads:
+            t.join(30.0)
+        assert not any(t.is_alive() for t in threads)
+        eng.shutdown()              # whatever raced in after the first
+    finally:
+        sys.setswitchinterval(interval)
+    reqs = [r for mine in accepted for r in mine]
+    assert reqs and all(r.done.is_set() for r in reqs)
+    assert len(signalled) == len(reqs)
+    assert len({id(r) for r in signalled}) == len(reqs)
+    assert not eng._held and eng._queued() == 0
+    scored = [r for r in reqs if r.scores is not None]
+    assert sum(len(r.batch) for r in scored) == sum(eng.backend.calls)
+    for mine in accepted:           # per submitter, order kept
+        ids = {id(r) for r in mine}
+        assert same([r for r in signalled
+                     if id(r) in ids and r.scores is not None],
+                    [r for r in mine if r.scores is not None])

@@ -180,11 +180,18 @@ def test_adaptive_cost_learned_per_mesh_and_seeds_new_engines():
         assert r is not None and r.done.wait(120.0)
         assert eng._ms_per_span() is not None
         assert eng.pipeline_stats()["adaptive"]["mesh"] == "data2"
+        # rows are learned from the rows the packer filled, not from
+        # the dp-aligned rung that padded them
+        real = eng.backend.last_real_rows
+        assert 0 < real < eng.backend.last_shape[0]
+        assert eng._ewma_spans_per_row == len(b) / real
     finally:
         eng.shutdown()
     # a fresh engine on the SAME mesh shape starts from the learned cost
+    # and the learned spans per real row
     eng2 = ScoringEngine(cfg_for(mesh=mesh))
     assert eng2._ms_per_span() is not None
+    assert eng2._spans_per_row() == eng._spans_per_row() == len(b) / real
     # ... while single-device engines keep their exact cold start
     eng3 = ScoringEngine(cfg_for())
     assert eng3._ms_per_span() is None

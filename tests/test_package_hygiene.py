@@ -400,22 +400,29 @@ class TestFastPathHygiene:
             + "\n  ".join(problems))
 
     def test_adaptive_batching_snaps_to_ladder_rungs(self):
-        """AST-level: ``_adaptive_cap`` must consult the backend ladder's
-        ``floor_rows`` — the declaration that deadline-sized batches land
-        on SHAPE_BUCKETING'd precompiled shapes."""
+        """AST-level: ``_budget`` (the one place a coalesced call is
+        sized; ``_adaptive_cap`` and ``_collect`` both go through it)
+        must consult the backend ladder's ``floor_rows`` — the
+        declaration that deadline-sized AND cap-sized batches land on
+        SHAPE_BUCKETING'd precompiled shapes."""
         path = os.path.join(PKG_ROOT, "serving", "engine.py")
         with open(path) as f:
             tree = ast.parse(f.read(), path)
-        cap_fns = [n for n in ast.walk(tree)
-                   if isinstance(n, ast.FunctionDef)
-                   and n.name == "_adaptive_cap"]
-        assert cap_fns, "engine lost its _adaptive_cap stage"
-        calls = {n.func.attr for n in ast.walk(cap_fns[0])
-                 if isinstance(n, ast.Call)
-                 and isinstance(n.func, ast.Attribute)}
-        assert "floor_rows" in calls, (
-            "_adaptive_cap no longer snaps span budgets onto "
-            "BucketLadder rungs — adaptive batches would pay recompiles")
+
+        def attr_calls(name):
+            fns = [n for n in ast.walk(tree)
+                   if isinstance(n, ast.FunctionDef) and n.name == name]
+            assert fns, f"engine lost its {name} stage"
+            return {n.func.attr for n in ast.walk(fns[0])
+                    if isinstance(n, ast.Call)
+                    and isinstance(n.func, ast.Attribute)}
+
+        assert "floor_rows" in attr_calls("_budget"), (
+            "_budget no longer snaps span budgets onto BucketLadder "
+            "rungs — coalesced batches would pay recompiles")
+        for caller in ("_adaptive_cap", "_collect"):
+            assert "_budget" in attr_calls(caller), (
+                f"{caller} sizes a call without _budget's rung snapping")
 
 
 class TestSteadyStateAllocHygiene:
