@@ -48,8 +48,8 @@ def main() -> int:
     for name in args.configs:
         with open(os.path.join(HERE, "configs", name + ".json")) as f:
             stanza = json.load(f)["tpuanomaly"]
-        model = TraceTransformer(make_model_config("transformer",
-                                                   stanza["model_config"]))
+        model = TraceTransformer(make_model_config(
+            stanza.get("model", "transformer"), stanza["model_config"]))
         L = model.cfg.max_len
         rows = args.rung or (int(stanza["trace_bucket"]) * n
                              << (int(stanza["bucket_ladder"]) - 1))

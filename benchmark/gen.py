@@ -6,13 +6,20 @@ mesh, about ten spans a trace, four fault kinds), rebuilt here so that
 the yardstick owns its traffic. Two generators draw a pool:
 
 * the **shape** generator (``shape_seed``, from the traffic file) decides
-  what sizes exist: each trace's root service (which fixes its tree),
-  which traces carry which fault, and which subtree a ``missing_subtree``
-  fault removes. Every ``--seed`` therefore offers the same multiset of
-  trace sizes, so the seed does not change the work;
-* the **value** generator (``--seed``) decides everything else: the order
-  of the traces over the pool, every latency, gap and error flag, and the
-  size of each fault.
+  every size: each trace's root service (which fixes its tree), which
+  traces carry which fault, which subtree a ``missing_subtree`` fault
+  removes, the order of the traces over the pool and each trace's id
+  (the program packs a call's traces into rows in the order of their
+  ids). Every ``--seed`` therefore offers the same frames of the same
+  trace sizes in the same places, and they pack to the same rows, so
+  the seed does not change the work (while the seed ordered the traces
+  and drew the ids, a frame held 2,305 to 3,002 spans by the seed,
+  eleven of them packed to 490 to 513 rows of a 512-row call, and a
+  seed read up to 3% apart from another and the same twice: PERF.md,
+  PR 27);
+* the **value** generator (``--seed``) decides everything else: every
+  latency, gap and error flag, the size of each fault, and (in
+  ``loadgen``) the pool frame the sending starts at.
 
 A pool frame is plain numpy columns plus a string table (``PlainFrame``):
 the reference featurizes from those and never sees a program object. The
@@ -226,8 +233,13 @@ def make_pool(traffic: dict[str, Any], seed: int) -> list[PlainFrame]:
             victim = ok[int(rs.integers(len(ok)))]
         fault_kind[t], fault_victim[t] = kind, victim
 
-    # ---- values: the seed orders the traces and draws every number
-    order = rv.permutation(total)
+    # the shapes' own order over the pool, and each trace's id: a frame
+    # holds the same trees in the same places under the same ids on every
+    # seed (the program packs a call's traces in the order of their ids)
+    order = rs.permutation(total)
+    trace_lo = rs.integers(1, 2**63, size=total)
+
+    # ---- values: the seed draws every number
     frames = []
     for f in range(n_frames):
         strings: list[str] = []
@@ -255,7 +267,7 @@ def make_pool(traffic: dict[str, Any], seed: int) -> list[PlainFrame]:
                 keep = _apply_fault(rv, FAULT_KINDS[fault_kind[t]],
                                     int(fault_victim[t]), tree, start, end,
                                     status)
-            lo = int(rv.integers(1, 2**63))
+            lo = int(trace_lo[t])
             ids = np.arange(next_id, next_id + len(tree))
             next_id += len(tree)
             for i, (svc, op, kind, parent, callee) in enumerate(tree):

@@ -19,15 +19,15 @@ keeps three things more of the same ``.xplane.pb``:
   (``op_metadata``), the events through ``ProfileData`` as ``tracered``
   reads them.
 
+Which scopes there are and the part each folds into is the architecture's
+to say (``architectures/<name>.py`` ``PARTS``); an operation under no
+scope it names folds into ``rest``.
+
 Works on plain records, so that it is checked on hand-made ones
 (``tests/test_hosttrace.py``). Nothing here raises on a trace of a
 program without the annotations: ``reduce`` then returns ``None``.
-
-``python3 benchmark/hosttrace.py --workload <cell> --seed <n> --seconds
-<s>`` runs one traced run of a cell through ``run.run_cell`` and prints
-its result line with the joined metrics added, and on stderr the table
-of seconds by part and operation family. It stands in until ``run.py``
-hands its trace to this module itself (PERF.md section 7).
+``run.py``'s traced branch calls ``load`` and ``reduce`` before it deletes
+the trace, and hands the result to the readers as ``obs.host``.
 """
 
 from __future__ import annotations
@@ -35,19 +35,12 @@ from __future__ import annotations
 import bisect
 import glob
 import os
-import sys
 from collections import Counter, defaultdict
 from dataclasses import dataclass, field
-from typing import Any, Iterable, Iterator, Optional
+from typing import Any, Iterable, Iterator, Mapping, Optional
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark import opcount, tracered  # noqa: E402
-from benchmark.tracered import (  # noqa: E402
-    DEVICE_PREFIX, MODULES_LINE, OPS_LINE)
+from benchmark import tracered
+from benchmark.tracered import DEVICE_PREFIX, MODULES_LINE, OPS_LINE
 
 # the engine worker's annotations: the call's working stages, and the
 # one in which it has nothing to work on
@@ -56,11 +49,10 @@ COLLECT = "engine/collect"
 # the runtime's host event that hands a program to the chip; it carries
 # the run_id of the XLA Modules event it starts
 ENQUEUE_PROGRAM = "DoEnqueueProgram"
-# the model's parts, as odigos_tpu/models/layers.py PARTS scopes them
-PARTS = ("embed", "attn_mask", "attn", "mlp", "final_norm", "head")
+# an operation under no scope the architecture names, and the part it
+# folds into
 UNSCOPED = "unscoped"
-# what step_rest_ms folds: every part but the two of the blocks
-REST = tuple(p for p in PARTS if p not in ("attn", "mlp")) + (UNSCOPED,)
+REST = "rest"
 JOIN_FLOOR = 0.95
 # an executable may end this long after the host saw its result: the
 # two clocks are aligned by the profiler, not identical
@@ -330,22 +322,23 @@ def run_id_agreement(planes: list[Plane], joined: list[Call],
 # --------------------------------------------------------------- the folds
 
 
-def part_of(path: Optional[str]) -> str:
-    """The model's part an operation belongs to: the first component of
-    its scope path that names one."""
+def scope_of(path: Optional[str], scopes: Iterable[str]) -> str:
+    """The scope an operation belongs to: the first component of its
+    scope path that is one of ``scopes``."""
     for piece in (path or "").split("/"):
-        if piece in PARTS:
+        if piece in scopes:
             return piece
     return UNSCOPED
 
 
 def fold_parts(planes: list[Plane], rows_of_run: dict[int, int],
-               ) -> dict[tuple[int, str, str], float]:
-    """Seconds of ``XLA Ops`` by (rows of the call's rung, part,
+               scopes: Iterable[str]) -> dict[tuple[int, str, str], float]:
+    """Seconds of ``XLA Ops`` by (rows of the call's rung, scope,
     operation family). An operation belongs to the executable run it
     started in; ``rows_of_run`` maps ``id()`` of a joined run to its
     call's rows, and an operation of a run nobody joined reads rows 0."""
     out: dict[tuple[int, str, str], float] = defaultdict(float)
+    scopes = frozenset(scopes)
     for plane, modules, ops in _device(planes):
         runs = sorted(modules.events, key=lambda e: e.start)
         starts = [r.start for r in runs]
@@ -354,7 +347,7 @@ def fold_parts(planes: list[Plane], rows_of_run: dict[int, int],
             rows = 0
             if j >= 0 and e.start < runs[j].end:
                 rows = rows_of_run.get(id(runs[j]), 0)
-            out[(rows, part_of(plane.op_names.get(e.name)),
+            out[(rows, scope_of(plane.op_names.get(e.name), scopes),
                  tracered.op_family(e.name))] += e.dur
     return dict(out)
 
@@ -400,18 +393,26 @@ class HostTrace:
     idle_collect_s: float         # ... the worker waits for requests
     idle_s: float                 # no executable runs, first to last run
     window_s: float
-    # seconds by (rows, part, family); rows 0: a run nobody joined
+    # seconds by (rows, scope, family); rows 0: a run nobody joined
     parts: dict[tuple[int, str, str], float] = field(default_factory=dict)
     runs_by_rows: dict[int, int] = field(default_factory=dict)
+    # the architecture's PARTS: scope -> the part it folds into
+    fold: Mapping[str, str] = field(default_factory=dict)
+    n_dev: int = 1                # chips whose operations ``parts`` sums
 
     @property
     def joined_share(self) -> float:
         return self.n_joined / self.n_calls if self.n_calls else 0.0
 
-    def part_ms(self, *parts: str) -> float:
-        """Mean per executable run of the operations under ``parts``."""
-        total = sum(s for (_, p, _), s in self.parts.items() if p in parts)
-        return 1e3 * total / self.n_runs
+    def part_s(self, part: str) -> float:
+        """Summed seconds, over every chip, of the operations whose scope
+        folds into ``part``; those under no scope fold into ``rest``."""
+        return sum(s for (_, scope, _), s in self.parts.items()
+                   if self.fold.get(scope, REST) == part)
+
+    def part_ms(self, part: str) -> float:
+        """``part_s`` as a mean per executable run."""
+        return 1e3 * self.part_s(part) / (self.n_runs * self.n_dev)
 
     @property
     def scoped_share(self) -> float:
@@ -421,12 +422,13 @@ class HostTrace:
         return named / total if total else 0.0
 
 
-def reduce(planes: list[Plane], window_s: Optional[float] = None,
-           ) -> Optional[HostTrace]:
+def reduce(planes: list[Plane], window_s: Optional[float],
+           parts: Mapping[str, str]) -> Optional[HostTrace]:
     """The joined numbers of one traced window; None where the trace
     holds no device run or no ``engine/enqueue`` (a program without the
     annotations). ``window_s`` is the length the idle shares are taken
-    of (the harness's traced window); first to last run by default."""
+    of (the harness's traced window; None: first to last run), ``parts``
+    the architecture's scopes and the part each folds into."""
     device = _device(planes)
     all_calls = calls(planes)
     if not device or not all_calls:
@@ -461,8 +463,9 @@ def reduce(planes: list[Plane], window_s: Optional[float] = None,
         idle_collect_s=overlap_s(idle, collect),
         idle_s=sum(b - a for a, b in idle),
         window_s=window_s if window_s else hi - lo,
-        parts=fold_parts(planes, rows_of_run),
-        runs_by_rows={k: v for k, v in by_rows.items() if v})
+        parts=fold_parts(planes, rows_of_run, parts),
+        runs_by_rows={k: v for k, v in by_rows.items() if v},
+        fold=dict(parts), n_dev=n_dev)
 
 
 def table(ht: HostTrace, top: int = 6) -> list[str]:
@@ -477,11 +480,12 @@ def table(ht: HostTrace, top: int = 6) -> list[str]:
         for (r, p, f), s in ht.parts.items():
             if rows is None or r == rows:
                 sel[(p, f)] += s
-        n = ht.n_runs if rows is None else ht.runs_by_rows[rows]
+        runs = ht.n_runs if rows is None else ht.runs_by_rows[rows]
+        n = runs * ht.n_dev          # the seconds are summed over the chips
         total = sum(sel.values())
         title = "all runs" if rows is None else (
             f"{rows}-row runs" if rows else "runs no call joined")
-        out.append(f"-- {title}: {n} runs, {total:.3f} s of operations, "
+        out.append(f"-- {title}: {runs} runs, {total:.3f} s of operations, "
                    f"{1e3 * total / n:.2f} ms a run")
         by_part: dict[str, float] = defaultdict(float)
         for (p, _), s in sel.items():
@@ -501,127 +505,9 @@ def table(ht: HostTrace, top: int = 6) -> list[str]:
     return out
 
 
-# ---------------------------------------------- operations needed, by part
-
-
-def flops_by_part(model: dict[str, Any], piece_lengths: Iterable[int],
-                  ) -> dict[str, float]:
-    """``opcount.flops_needed`` split by the model's parts: ``attn`` is
-    the four d x d projections a span and layer and the attention core
-    over each piece's own length, ``mlp`` the two d x d_ff products,
-    ``rest`` the embedder's continuous projection and the span head.
-    The three sum to ``opcount.flops_needed``."""
-    d, ff, n = model["d_model"], model["d_ff"], model["n_layers"]
-    pieces = list(piece_lengths)
-    spans = sum(pieces)
-    return {
-        "attn": spans * 2.0 * n * 4 * d * d
-        + sum(opcount.attention_flops(model, p) for p in pieces),
-        "mlp": spans * 2.0 * n * 2 * d * ff,
-        "rest": spans * 2.0 * (3 * d + d)}
-
-
-# ------------------------------------------------------------------ the run
-
-QUANTITIES = ("device_step_ms", "device_queue_ms", "fetch_ms",
-              "device_idle_host", "step_attn_ms", "step_mlp_ms",
-              "step_rest_ms")
-
-
-def main(argv: Optional[list[str]] = None) -> int:
-    import argparse
-    import json
-    import types
-
-    from benchmark import observe, run
-
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--rehearse", default=None, metavar="FILE")
-    args = ap.parse_args(argv)
-
-    # run.py reduces its trace and deletes it before a reader runs, and
-    # an Observation has no field for what is read here: keep both as
-    # they pass, until run.py hands them over itself
-    kept: dict[str, Any] = {}
-    load_planes, observation = tracered.load, observe.Observation
-
-    def load_and_keep(trace_dir: str):
-        kept["planes"] = load(trace_dir)
-        return load_planes(trace_dir)
-
-    def observation_kept(**kw: Any):
-        kept["obs"] = observation(**kw)
-        return kept["obs"]
-
-    tracered.load, observe.Observation = load_and_keep, observation_kept
-    # jax keys its persistent compile cache on a program without its
-    # metadata, so a cached executable carries the scope names of the
-    # tree that compiled it first, which may have none. Key this run on
-    # the metadata too: its trace then names this tree's parts, for one
-    # compile of its own (set-up, not the window)
-    import jax
-
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    try:
-        line = run.run_cell(
-            args.workload, args.seed, args.seconds, True,
-            run.load_json(args.rehearse) if args.rehearse else None)
-    except run.Refused as e:
-        run.say(f"benchmark/hosttrace.py: {e}")
-        return 2
-    finally:
-        tracered.load, observe.Observation = load_planes, observation
-    obs = kept.get("obs")
-    ht = None
-    if obs is not None and obs.device is not None and "planes" in kept:
-        ht = reduce(kept["planes"], obs.device.window_s)
-    if ht is None:
-        run.say("hosttrace: the trace holds no engine/enqueue annotation "
-                "or no device run; nothing is added to the line")
-    else:
-        split = args.workload.rpartition(".")[2]
-        host = types.SimpleNamespace(host=ht)
-        for quantity in QUANTITIES:
-            value = observe.load_reader(f"{quantity}.{split}")(host)
-            if value is not None:
-                line["metrics"][f"{quantity}.{split}"] = {
-                    "value": value,
-                    "unit": "%" if quantity == "device_idle_host" else "ms"}
-        run.say("\n".join(table(ht)))
-        needed = flops_by_part(obs.model, obs.piece_lengths)
-        slots = [length for _, rows, length in obs.score_calls
-                 for _ in range(rows)]
-        offered = flops_by_part(obs.model, slots)
-        peak = obs.peak_flops() * obs.chips
-        line["hosttrace"] = {
-            "calls": ht.n_calls, "joined": ht.n_joined, "runs": ht.n_runs,
-            "run_id_agree": ht.run_id_agree,
-            "scoped_share": ht.scoped_share,
-            "idle_s": ht.idle_s, "idle_host_s": ht.idle_host_s,
-            "idle_collect_s": ht.idle_collect_s,
-            "runs_by_rows": {str(k): v for k, v in ht.runs_by_rows.items()},
-            "parts": {}}
-        for part, names in (("attn", ("attn",)), ("mlp", ("mlp",)),
-                            ("rest", REST)):
-            part_s = ht.part_ms(*names) * 1e-3 * ht.n_runs
-            if part_s > 0:
-                line["hosttrace"]["parts"][part] = {
-                    "ms_a_run": ht.part_ms(*names),
-                    "flops_needed": needed[part],
-                    "flops_dispatched": offered[part],
-                    "peak_share_needed":
-                    100.0 * needed[part] / (part_s * peak),
-                    "peak_share_dispatched":
-                    100.0 * offered[part] / (part_s * peak)}
-    print(json.dumps(line), flush=True)
-    return 0
-
-
 if __name__ == "__main__":
-    code = main()
-    sys.stdout.flush()
-    sys.stderr.flush()
-    os._exit(code)
+    # a library since PR 27; said aloud, since older notes name this file
+    # as the traced run and a silent exit 0 would pass for one
+    raise SystemExit("benchmark/hosttrace.py runs nothing: the traced run "
+                     "is python3 benchmark/run.py --workload <cell> --seed "
+                     "<n> --seconds <run_seconds> --trace 1")

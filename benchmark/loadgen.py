@@ -78,7 +78,10 @@ class LoadGenerator:
         self.spy = spy
         self.chips = chips
         self.give_up_s = give_up_s
-        self.order = np.random.default_rng(seed).permutation(len(pool))
+        # the pool's frames in the pool's own cyclic order on every seed
+        # (which frames fall into one call decides how full it packs);
+        # the seed picks where the cycle starts
+        self.order = np.roll(np.arange(len(pool)), -(int(seed) % len(pool)))
         self._next_serial = 1
         self._lock = threading.Lock()
         self._exporters: list = []

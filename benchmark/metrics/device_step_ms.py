@@ -1,7 +1,7 @@
 """Model step on the device's own clock: mean duration of the window's
 executable runs (XLA Modules events), in ms. Read by hosttrace.py from
-the traced window; nothing to read until the harness hands it the trace
-(obs.host)."""
+the traced window (obs.host); nothing to read in a trace without the
+program's engine/* annotations."""
 
 
 def read(obs):

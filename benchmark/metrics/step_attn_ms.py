@@ -1,6 +1,7 @@
-"""Model step, attention: XLA Ops time under the scope ``attn`` (LayerNorm,
-projections, attention core, residual of every block), mean per
-executable run of the window, in ms."""
+"""Model step, attention: XLA Ops time under the scopes the architecture
+folds into ``attn`` (for ``encoder_preln``: LayerNorm, projections,
+attention core and residual of every block), mean per executable run of
+the window, in ms."""
 
 
 def read(obs):

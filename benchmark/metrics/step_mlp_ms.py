@@ -1,6 +1,7 @@
-"""Model step, feed-forward: XLA Ops time under the scope ``mlp``
-(LayerNorm, both Dense, GELU, residual of every block), mean per
-executable run of the window, in ms."""
+"""Model step, feed-forward: XLA Ops time under the scopes the
+architecture folds into ``mlp`` (for ``encoder_preln``: LayerNorm, both
+Dense, GELU and residual of every block), mean per executable run of the
+window, in ms."""
 
 
 def read(obs):

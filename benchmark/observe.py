@@ -22,7 +22,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 @dataclass
 class Observation:
-    model: dict[str, Any]            # d_model, n_heads, n_layers, d_ff, max_len
+    model: dict[str, Any]            # the configuration's whole model_config
     chips: int
     device_kind: str
     deadline_ms: float
@@ -37,6 +37,8 @@ class Observation:
     score_calls: list[tuple[int, int, int]] = field(default_factory=list)
     piece_lengths: list[int] = field(default_factory=list)  # scored traces
     device: Any = None               # tracered.DeviceTime of the traced run
+    host: Any = None                 # hosttrace.HostTrace of the traced run
+    arch: Any = None                 # the configuration's architecture module
 
     def stage_mean_ms(self, *names: str) -> Optional[float]:
         """Mean per frame of the summed stages; None if none was stamped."""
@@ -47,7 +49,8 @@ class Observation:
         return float(sum(s / c for s, c in got))
 
     def flops_needed(self) -> float:
-        return opcount.flops_needed(self.model, self.piece_lengths)
+        return opcount.flops_needed(self.arch, self.model,
+                                    self.piece_lengths)
 
     def peak_flops(self) -> float:
         return opcount.peaks(self.device_kind)["bf16_flops_per_s"]

@@ -1,30 +1,24 @@
-"""The plain reference: raw span columns and a seed in, span scores out.
+"""What every architecture's plain reference shares: raw span columns and
+a seed in, and the steps that are this system's and not the backbone's.
 
 Straightforward ``jax.numpy`` in float32 with every matrix product at
 ``highest`` precision, no kernels, no ladder, no shared rows beyond a
 plain greedy fill. It imports nothing of the program and takes nothing
-the program made: it featurizes the generator's plain columns itself,
-derives the weights from ``--seed`` itself (the same numbers flax's
-``Module.init(PRNGKey(seed))`` gives the program: same key derivation,
-same initializers) and runs the encoder layer by layer, generating each
-layer's weights inside the jitted layer step, so that ViT-H's 2.5 GB of
-float32 parameters never sit on the device at once.
+the program made: it featurizes the generator's plain columns itself
+(``featurize``), lays them out in rows (``lay_out``), derives the weights
+from ``--seed`` itself (``_param_key``, ``_inits``: the same numbers
+flax's ``Module.init(PRNGKey(seed))`` gives the program, same key
+derivation, same initializers) and holds this system's span embedder and
+span head (``outer_weights``, ``span_embedding``, ``span_head``), which
+every backbone sits between. ``score_rows`` drives an architecture's
+forward pass over the laid-out rows a block at a time.
 
-The equations, after arXiv:2010.11929 section 3.1 (pre-LN encoder), with
-this system's embedder and head in place of patches and class token::
+The backbone's own equations, its weights and its operation count are in
+``benchmark/architectures/<name>.py``, which imports from here.
 
-    x0 = E_service[svc] + E_name[name] + E_kind[kind] + E_status[status]
-         + E_service[parent_svc] + cont @ W_c + b_c + E_pos[position]
-    h  = LN(x);  q, k, v = h W_q + b_q, h W_k + b_k, h W_v + b_v
-    a  = softmax(q k^T / sqrt(d_head), over the spans of the same trace)
-    x  = x + (a v) W_o + b_o
-    x  = x + gelu_tanh(LN(x) W_1 + b_1) W_2 + b_2          (each layer)
-    score = sigmoid(LN(x) w_s + b_s)
-
-``precision="fp8"`` is the control: the same reference with the six
-matrix products of every layer computed from inputs cast to float8
+``_matmul("fp8")`` is the control's product: inputs cast to float8
 (e4m3; activations scaled per row, weights per output column), the
-precision next below the configuration's bfloat16.
+precision next below bfloat16.
 """
 
 from __future__ import annotations
@@ -38,7 +32,6 @@ import numpy as np
 CAT_WIDTH = 5     # service, name, kind, status, parent service
 CONT_WIDTH = 3    # log1p(duration us), is root, depth hint
 VOCAB = {"service": 512, "name": 2048, "kind": 8, "status": 4}
-LN_EPS = 1e-6
 
 
 # ------------------------------------------------------------- featurize
@@ -149,25 +142,6 @@ def _inits():
             init.variance_scaling(1.0, "fan_in", "normal", out_axis=0))
 
 
-def layer_keys(seed: int, n_layers: int):
-    """(n_layers, 6, 2) uint32: the keys of each block's six kernels
-    (query, key, value, out, feed-forward in, feed-forward out)."""
-    import jax
-    import jax.numpy as jnp
-
-    root = jax.random.PRNGKey(seed)
-    out = []
-    for i in range(n_layers):
-        blk = ("encoder", f"block_{i}")
-        mha = blk + ("MultiHeadDotProductAttention_0",)
-        out.append(jnp.stack(
-            [_param_key(root, mha + (nm,), 1)
-             for nm in ("query", "key", "value", "out")]
-            + [_param_key(root, blk + (nm,), 1)
-               for nm in ("Dense_0", "Dense_1")]))
-    return jnp.stack(out)
-
-
 def outer_weights(seed: int, d_model: int, max_len: int) -> dict[str, Any]:
     """Embedding tables, continuous projection, position table and span
     head (float32), keyed by flax's own parameter paths."""
@@ -198,36 +172,13 @@ def outer_weights(seed: int, d_model: int, max_len: int) -> dict[str, Any]:
     }
 
 
-def block_weights(keys, d_model: int, d_ff: int) -> dict[str, Any]:
-    """One block's parameters from its six kernel keys: lecun-normal
-    kernels, zero biases, unit LayerNorm scales (what flax makes)."""
-    import jax.numpy as jnp
-
-    lecun, _ = _inits()
-    f32 = jnp.float32
-    d = d_model
-    return {
-        "wq": lecun(keys[0], (d, d), f32), "wk": lecun(keys[1], (d, d), f32),
-        "wv": lecun(keys[2], (d, d), f32), "wo": lecun(keys[3], (d, d), f32),
-        "w1": lecun(keys[4], (d, d_ff), f32),
-        "w2": lecun(keys[5], (d_ff, d), f32),
-        "bq": jnp.zeros((d,), f32), "bk": jnp.zeros((d,), f32),
-        "bv": jnp.zeros((d,), f32), "bo": jnp.zeros((d,), f32),
-        "b1": jnp.zeros((d_ff,), f32), "b2": jnp.zeros((d,), f32),
-        "ln1_s": jnp.ones((d,), f32), "ln1_b": jnp.zeros((d,), f32),
-        "ln2_s": jnp.ones((d,), f32), "ln2_b": jnp.zeros((d,), f32),
-    }
-
-
 # ---------------------------------------------------------------- forward
 
 
-def _layer_norm(x, scale, bias):
-    import jax.numpy as jnp
-
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + LN_EPS) * scale + bias
+# the precisions ``_matmul`` computes: the reference's own, and those an
+# architecture's ``CONTROL`` may name. An architecture whose control is
+# another brings the product itself, in its own file.
+PRECISIONS = ("float32", "fp8")
 
 
 def _matmul(precision: str):
@@ -249,75 +200,45 @@ def _matmul(precision: str):
     raise ValueError(f"unknown precision {precision!r}")
 
 
-def block_step(x, allowed, keys, *, n_heads: int, d_ff: int,
-               precision: str):
-    """One pre-LN encoder block over (rows, L, d) with its weights made
-    here from ``keys``; ``allowed`` is (rows, L, L) bool."""
+def span_embedding(outer: dict[str, Any], cat, cont):
+    """The span embedder's sum, before any position term: the five
+    categorical lookups and the continuous projection."""
     import jax
     import jax.numpy as jnp
 
-    hi = jax.lax.Precision.HIGHEST
-    mm = _matmul(precision)
-    rows, L, d = x.shape
-    hd = d // n_heads
-    w = block_weights(keys, d, d_ff)
-    h = _layer_norm(x, w["ln1_s"], w["ln1_b"])
-    q = (mm(h, w["wq"]) + w["bq"]).reshape(rows, L, n_heads, hd)
-    k = (mm(h, w["wk"]) + w["bk"]).reshape(rows, L, n_heads, hd)
-    v = (mm(h, w["wv"]) + w["bv"]).reshape(rows, L, n_heads, hd)
-    s = jnp.einsum("rqhd,rkhd->rhqk", q, k, precision=hi) / np.sqrt(hd)
-    s = jnp.where(allowed[:, None], s, jnp.finfo(jnp.float32).min)
-    a = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("rhqk,rkhd->rqhd", a, v, precision=hi).reshape(rows, L, d)
-    x = x + mm(o, w["wo"]) + w["bo"]
-    h = _layer_norm(x, w["ln2_s"], w["ln2_b"])
-    h = jax.nn.gelu(mm(h, w["w1"]) + w["b1"], approximate=True)
-    return x + mm(h, w["w2"]) + w["b2"]
+    return (outer["service"][cat[..., 0]] + outer["name"][cat[..., 1]]
+            + outer["kind"][cat[..., 2]] + outer["status"][cat[..., 3]]
+            + outer["service"][cat[..., 4]]
+            + jnp.matmul(cont, outer["cont_w"],
+                         precision=jax.lax.Precision.HIGHEST)
+            + outer["cont_b"])
 
 
-def scores(frames, seed: int, model: dict[str, Any],
-           precision: str = "float32", block_rows: int = 256
-           ) -> list[np.ndarray]:
-    """The reference's score of every span of every frame, as one float32
-    array per frame in the frame's own span order. ``model`` holds
-    d_model, n_heads, n_layers, d_ff and max_len."""
+def span_head(outer: dict[str, Any], h):
+    """The span head over the backbone's normed output: one logit a
+    span, through the sigmoid."""
     import jax
     import jax.numpy as jnp
 
-    d, L = int(model["d_model"]), int(model["max_len"])
-    n_layers, d_ff = int(model["n_layers"]), int(model["d_ff"])
-    cat, cont, seg, pos, slot = lay_out(frames, L, row_multiple=block_rows)
-    hi = jax.lax.Precision.HIGHEST
-    outer = outer_weights(seed, d, L)
+    logit = jnp.matmul(h, outer["head_w"],
+                       precision=jax.lax.Precision.HIGHEST)[..., 0]
+    return jax.nn.sigmoid(logit + outer["head_b"][0])
 
-    @jax.jit
-    def embed(cat, cont, seg, pos):
-        x = (outer["service"][cat[..., 0]] + outer["name"][cat[..., 1]]
-             + outer["kind"][cat[..., 2]] + outer["status"][cat[..., 3]]
-             + outer["service"][cat[..., 4]]
-             + jnp.matmul(cont, outer["cont_w"], precision=hi)
-             + outer["cont_b"] + outer["pos"][pos])
-        return x * (seg > 0)[..., None]
 
-    step = jax.jit(partial(block_step, n_heads=int(model["n_heads"]),
-                           d_ff=d_ff, precision=precision))
+def score_rows(frames, max_len: int, block_rows: int, forward,
+               ) -> list[np.ndarray]:
+    """``forward(cat, cont, segments, positions) -> (rows, max_len)``
+    scores, run over the laid-out rows of ``frames`` a block of
+    ``block_rows`` at a time; returns one float32 array per frame in the
+    frame's own span order."""
+    import jax.numpy as jnp
 
-    @jax.jit
-    def head(x):
-        h = _layer_norm(x, jnp.ones((d,)), jnp.zeros((d,)))
-        logit = jnp.matmul(h, outer["head_w"], precision=hi)[..., 0]
-        return jax.nn.sigmoid(logit + outer["head_b"][0])
-
-    keys = layer_keys(seed, n_layers)
+    cat, cont, seg, pos, slot = lay_out(frames, max_len,
+                                        row_multiple=block_rows)
     out = np.zeros(seg.shape, np.float32)
     for r0 in range(0, seg.shape[0], block_rows):
         sl = slice(r0, r0 + block_rows)
-        s = jnp.asarray(seg[sl])
-        allowed = (s[:, :, None] == s[:, None, :]) & (s > 0)[:, :, None] \
-            & (s > 0)[:, None, :]
-        x = embed(jnp.asarray(cat[sl]), jnp.asarray(cont[sl]), s,
-                  jnp.asarray(pos[sl]))
-        for i in range(n_layers):
-            x = step(x, allowed, keys[i])
-        out[sl] = np.asarray(head(x))
+        out[sl] = np.asarray(forward(
+            jnp.asarray(cat[sl]), jnp.asarray(cont[sl]),
+            jnp.asarray(seg[sl]), jnp.asarray(pos[sl])))
     return [out[sl_[:, 0], sl_[:, 1]] for sl_ in slot]

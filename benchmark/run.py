@@ -3,14 +3,19 @@
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Reads the cell from ``BENCHMARK.json`` at the root of the checkout, its
-configuration from ``benchmark/configs/``, its traffic mix from
-``benchmark/traffic/`` and each metric's reader from
+configuration from ``benchmark/configs/``, the architecture the
+configuration names from ``benchmark/architectures/`` (the plain
+reference, the operation count and the trace's parts), its traffic mix
+from ``benchmark/traffic/`` and each metric's reader from
 ``benchmark/metrics/``. Starts the product's gateway collector in this
 process (the rendered gateway config with the configuration's
 ``tpuanomaly`` stanza laid over it), drives the collector's wire receiver
 with ``WireExporter`` clients for ``--seconds`` seconds, waits for the
 window's frames at the terminal exporter, decides ``correct`` against the
-plain reference, and prints one JSON line last. It exits non-zero and
+architecture's plain reference, and prints one JSON line last. With
+``--trace 1`` the window runs under the profiler, and the readers get the
+trace's reductions (``tracered``: device time; ``hosttrace``: each engine
+call joined to its executable run, step time by part). It exits non-zero and
 prints no result unless JAX's devices are TPUs and cover the cell's
 ``chips``.
 
@@ -20,8 +25,9 @@ One rule turns ``chips`` n > 1 into a deployment, for every cell alike:
 each chip sees the rungs it sees alone.
 
 ``--rehearse FILE`` is the harness's own rehearsal: FILE's ``tpuanomaly``,
-``traffic`` and ``correct`` mappings are laid over the cell's, the
-platform gate is dropped, and the line is marked ``"rehearsal": true``.
+``traffic`` and ``correct`` mappings and its ``architecture`` (a name, or a
+file's path from the root) are laid over the cell's, the platform gate is
+dropped, and the line is marked ``"rehearsal": true``.
 A rehearsal's numbers are never results.
 """
 
@@ -88,6 +94,23 @@ def load_cell(workload: str) -> tuple[dict, dict, dict, dict]:
     return bench, cell, config, traffic
 
 
+def load_architecture(config: dict, rehearse: Optional[dict] = None):
+    """The module of the architecture the configuration names (or the
+    rehearsal lays over it)."""
+    from benchmark import architectures
+
+    name = (rehearse or {}).get("architecture", config.get("architecture"))
+    if name is None:
+        raise Refused(
+            f"configuration {config.get('name')!r} names no architecture: "
+            f"it needs \"architecture\": \"<name>\", a file "
+            f"{os.path.join(HERE, 'architectures', '<name>.py')}")
+    try:
+        return architectures.load(name, rehearsal=rehearse is not None)
+    except architectures.NotFound as e:
+        raise Refused(str(e))
+
+
 def cell_metrics(bench: dict, cell: dict, group: str) -> list[dict]:
     """The metrics of ``group`` this cell reports. An end-to-end metric
     with no ``workloads`` is every cell's; a per-layer metric with none
@@ -144,9 +167,10 @@ def memory_peak(chips: int) -> Optional[int]:
 def render_config(stanza: dict, chips: int, seed: int) -> dict:
     """The gateway config the product renders (two trace-db destinations,
     every span to ``all`` through the default stream, flagged traces
-    again to ``flagged``; transformer scoring on the ingest fast path;
-    threshold 0 so that every span carries its score), with the
-    configuration's stanza and the chips rule laid over it."""
+    again to ``flagged``; the stanza's ``model`` (``transformer`` where it
+    names none) scoring on the ingest fast path; threshold 0 so that
+    every span carries its score), with the configuration's stanza and
+    the chips rule laid over it."""
     from odigos_tpu.components.api import Signal
     from odigos_tpu.config.model import AnomalyStageConfiguration
     from odigos_tpu.destinations import Destination
@@ -159,7 +183,8 @@ def render_config(stanza: dict, chips: int, seed: int) -> dict:
     streams = [DataStream("default", (DataStreamDestination("all"),)),
                DataStream("anomalies", (DataStreamDestination("flagged"),))]
     anomaly = AnomalyStageConfiguration(
-        enabled=True, model="transformer", fast_path=True,
+        enabled=True, model=stanza.get("model", "transformer"),
+        fast_path=True,
         timeout_ms=float(stanza["timeout_ms"]), threshold=0.0, devices=chips)
     config, statuses, _ = build_gateway_config(
         dests, data_streams=streams, options=GatewayOptions(anomaly=anomaly))
@@ -259,12 +284,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              control: bool = False) -> dict:
     """The whole run; returns the result line's object. ``control`` is
     for the readings ``correct``'s limits are set from, never for a
-    benchmark run: the reference computed in float8 is put in the
-    program's place, span for span of the window, and judged as the
-    served scores are (reported under ``control`` in the line)."""
+    benchmark run: the reference computed in the architecture's
+    ``CONTROL`` precision is put in the program's place, span for span of
+    the window, and judged as the served scores are (reported under
+    ``control`` in the line)."""
     import numpy as np
 
-    from benchmark import gen, judge, loadgen, observe, reference, tracered
+    from benchmark import gen, hosttrace, judge, loadgen, observe, tracered
 
     bench, cell, config, traffic = load_cell(workload)
     chips = int(cell["chips"])
@@ -275,8 +301,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         stanza.update(rehearse.get("tpuanomaly", {}))
         traffic = {**traffic, **rehearse.get("traffic", {})}
         limits = {**limits, **rehearse.get("correct", {})}
-    model = {k: stanza["model_config"][k]
-             for k in ("d_model", "n_heads", "n_layers", "d_ff", "max_len")}
+    arch = load_architecture(config, rehearse)
+    model = copy.deepcopy(stanza["model_config"])
     deadline_ms = float(stanza["timeout_ms"])
 
     facts = device_gate(chips, rehearsal)
@@ -291,6 +317,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     cache_dir = configure_compile_cache()
     say(f"compile cache: {cache_dir}")
+    # jax keys its persistent cache on a program without its metadata, so
+    # a cached executable carries the scope names of whichever tree
+    # compiled it first, which may have none, and a trace of it names no
+    # part. Every run keys on the metadata too, traced or not: one set of
+    # executables a checkout, so that a traced run finds what an untraced
+    # one compiled (keyed apart, each kind evicted the other's from a
+    # cache that holds one set: PERF.md section 6, PR 27)
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
     # ---- set-up: pool, collector (weights from the seed, warm ladder),
     # untimed warm-up frames
@@ -333,8 +369,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         score_spans = ScoreSpans() if trace else None
         trace_dir = os.path.join(OUT_DIR, "trace")
         if trace:
-            import jax
-
             shutil.rmtree(trace_dir, ignore_errors=True)
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
@@ -349,6 +383,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             jax.profiler.stop_trace()
         snap1, stages1 = watched(meter.snapshot()), stage_sums(PIPELINE)
         calls = score_spans.finish() if score_spans else []
+        missed = score_spans.missed if score_spans else 0
+        if missed:
+            # the tracer's ring lapped the reader: the calls collected are
+            # a part of the window's, and a share read off them is not
+            # the window's padded share
+            calls = []
         records = list(spy.records)
     finally:
         lg.stop()
@@ -380,9 +420,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         {k: v for k, v in moved.items() if v},
         "stage_mean_ms": {s: round(a / n, 3) for s, (a, n) in stages.items()
                           if n}, "score_calls": len(calls),
-        "device": facts, "rehearsal": rehearsal}))
+        "score_spans_missed": missed, "setup_s": setup_s, "device": facts,
+        "rehearsal": rehearsal}))
 
-    device = None
+    device = host = None
     breakdown = None
     if trace:
         planes = tracered.load(trace_dir)
@@ -405,6 +446,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                               + [[f"{what}@+{a - device.t0:.3f}s", b - a]
                                  for what, (a, b)
                                  in zip(doing, long_gaps)])[:10]}
+            host = hosttrace.reduce(hosttrace.load(trace_dir),
+                                    device.window_s, arch.PARTS)
+            if host is None:
+                say("hosttrace: the trace holds no engine/enqueue "
+                    "annotation; the joined metrics are left out")
+            else:
+                say("\n".join(hosttrace.table(host)))
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     pieces = []
@@ -420,7 +468,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         deadline_ms=deadline_ms, window_s=window_s,
         scored_spans=int(tl.scored.sum()), latency_ms=lat, late_ms=late_ms,
         stages=stages, counters=moved, score_calls=calls,
-        piece_lengths=pieces, device=device)
+        piece_lengths=pieces, device=device, host=host, arch=arch)
 
     metrics: dict[str, dict] = {}
     group = "per_layer" if trace else "end_to_end"
@@ -437,7 +485,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     del collector, spy, records, lg
     free_program_state()
     t_ref = time.perf_counter()
-    ref = reference.scores(pool, seed, model)
+    ref = arch.scores(pool, seed, model)
     correct, compared = judge.compare(tl, pool, ref, limits)
     ref_s = time.perf_counter() - t_ref
     line: dict[str, Any] = {
@@ -448,19 +496,52 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         line["device"]["busy_s"] = device.busy_mean_s
         line["device"]["window_s"] = device.window_s
         line["breakdown"] = breakdown
+    if host is not None:
+        line["hosttrace"] = joined_summary(obs)
+        say("hosttrace: " + json.dumps(line["hosttrace"]))
     if rehearsal:
         line["rehearsal"] = True
     if control:
-        ref8 = reference.scores(pool, seed, model, precision="fp8")
-        good, read = judge.compare(judge.served_by(tl, pool, ref8), pool,
+        low = arch.scores(pool, seed, model, precision=arch.CONTROL)
+        good, read = judge.compare(judge.served_by(tl, pool, low), pool,
                                    ref, limits)
-        line["control"] = {"precision": "fp8", "correct": bool(good),
+        line["control"] = {"precision": arch.CONTROL, "correct": bool(good),
                            **{k: v["value"] for k, v in read.items()}}
     line["reference_s"] = ref_s
     line["compared"] = compared
     for name, row in compared.items():
         say(f"compared {name}: {row['value']!r} limit {row['limit']!r}")
     return line
+
+
+def joined_summary(obs: Any) -> dict:
+    """What the joined trace says beside the metrics: how many calls
+    joined a run, and by part of the architecture the device time a run,
+    the operations needed (real spans) and dispatched (every slot of
+    every call) and each over the part's device time at the chip's
+    peak, in percent."""
+    ht = obs.host
+    needed = obs.arch.flops_by_part(obs.model, obs.piece_lengths)
+    offered = obs.arch.flops_by_part(
+        obs.model, [length for _, rows, length in obs.score_calls
+                    for _ in range(rows)])
+    parts = {}
+    for part in needed:
+        at_peak = ht.part_s(part) * obs.peak_flops()   # chip-seconds
+        if at_peak > 0:
+            parts[part] = {
+                "ms_a_run": ht.part_ms(part),
+                "flops_needed": needed[part],
+                "flops_dispatched": offered[part],
+                "peak_share_needed": 100.0 * needed[part] / at_peak,
+                "peak_share_dispatched": 100.0 * offered[part] / at_peak}
+    return {"calls": ht.n_calls, "joined": ht.n_joined, "runs": ht.n_runs,
+            "run_id_agree": ht.run_id_agree,
+            "scoped_share": ht.scoped_share, "idle_s": ht.idle_s,
+            "idle_host_s": ht.idle_host_s,
+            "idle_collect_s": ht.idle_collect_s,
+            "runs_by_rows": {str(k): v for k, v in ht.runs_by_rows.items()},
+            "parts": parts}
 
 
 def free_program_state() -> None:
@@ -485,8 +566,9 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", default=None, metavar="FILE")
     ap.add_argument("--control", action="store_true",
-                    help="also judge the reference in float8 in the "
-                         "program's place (for setting limits)")
+                    help="also judge the reference in the architecture's "
+                         "CONTROL precision in the program's place (for "
+                         "setting limits)")
     args = ap.parse_args(argv)
     try:
         line = run_cell(args.workload, args.seed, args.seconds,

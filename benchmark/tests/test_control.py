@@ -5,21 +5,13 @@ the rehearsal's own limits (set, like the cells', between the two
 readings: see rehearsal.json and PERF.md). The benchmark's own runs do
 not run the control."""
 
-import json
-import os
-
 import pytest
 
 from benchmark import run
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-
 
 @pytest.mark.parametrize("seed", [21, 2**31 + 22, 23])
-def test_float8_control_fails_where_the_sound_run_passes(seed):
-    with open(os.path.join(HERE, "rehearsal.json")) as f:
-        rehearsal = json.load(f)
-    rehearsal["settle_s"] = 4.0
+def test_float8_control_fails_where_the_sound_run_passes(seed, rehearsal):
     line = run.run_cell("vit-h14.backlog", seed, 1.0, False,
                         rehearse=rehearsal, control=True)
     assert line["correct"] is True

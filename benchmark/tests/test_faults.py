@@ -3,23 +3,9 @@ correct, once for each fault this kind of cell can have. The run is the
 harness's own (``run.run_cell``) past its look for a chip, at the
 rehearsal's tiny size; the sound run beside them comes out correct."""
 
-import json
-import os
-
-import numpy as np
 import pytest
 
 from benchmark import run
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-@pytest.fixture()
-def rehearsal():
-    with open(os.path.join(HERE, "rehearsal.json")) as f:
-        r = json.load(f)
-    r["settle_s"] = 4.0
-    return r
 
 
 def drive(rehearsal, seed=11, workload="vit-h14.backlog", **kw):

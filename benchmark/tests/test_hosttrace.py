@@ -1,14 +1,23 @@
-"""The joined reduction on hand-made planes, and the pin that what was
-there reads as it did."""
+"""The joined reduction on hand-made planes, alone and fed through
+``run.py``'s traced branch, and the pin that what was there reads as it
+did."""
 
-import json
+import copy
 import os
 import types
 
 import pytest
 
-from benchmark import hosttrace, observe, run, tracered
+from benchmark import architectures, hosttrace, observe, run, tracered
 from benchmark.hosttrace import Event, Line, Plane
+
+# an architecture's PARTS, made by hand: scope -> the part it folds into
+FOLD = {"embed": "rest", "attn_mask": "rest", "attn": "attn", "mlp": "mlp",
+        "final_norm": "rest", "head": "rest"}
+# the quantities that read the joined trace, each in both splits
+QUANTITIES = ("device_step_ms", "device_queue_ms", "fetch_ms",
+              "device_idle_host", "step_attn_ms", "step_mlp_ms",
+              "step_rest_ms")
 
 OPS = {  # event name -> scope path, as the profiler's metadata has it
     "%fusion.1 = f(x)": "jit(f)/M/encoder/block_0/attn/LayerNorm_0/mul",
@@ -70,7 +79,7 @@ def test_calls_join_their_runs_in_order():
 
 
 def test_reduce_reads_the_joined_times():
-    ht = hosttrace.reduce(planes(), window_s=10.0)
+    ht = hosttrace.reduce(planes(), 10.0, FOLD)
     assert (ht.n_calls, ht.n_joined, ht.n_runs) == (2, 2, 2)
     assert ht.joined_share == 1.0 and ht.run_id_agree == 1.0
     assert ht.step_ms == pytest.approx(2500.0)
@@ -85,7 +94,7 @@ def test_idle_with_the_worker_working_and_waiting():
     """The one gap between the runs, 3.5-3.6: the worker is in
     engine/harvest until 3.52 and engine/scatter until 3.57 (0.07 s of
     work), then in engine/collect until 3.62 (0.03 s of the gap)."""
-    ht = hosttrace.reduce(planes(), window_s=10.0)
+    ht = hosttrace.reduce(planes(), 10.0, FOLD)
     assert ht.idle_s == pytest.approx(0.1)
     assert ht.idle_host_s == pytest.approx(0.07)
     assert ht.idle_collect_s == pytest.approx(0.03)
@@ -99,7 +108,7 @@ def test_a_call_that_never_joins():
     """Call 1 is never harvested inside the trace: it joins no run, one
     of two calls joined is under the floor, and the joined metrics are
     not reported while the others are."""
-    ht = hosttrace.reduce(planes(second_harvest=False), window_s=10.0)
+    ht = hosttrace.reduce(planes(second_harvest=False), 10.0, FOLD)
     assert (ht.n_calls, ht.n_joined) == (2, 1)
     assert ht.joined_share == 0.5 < hosttrace.JOIN_FLOOR
     host = types.SimpleNamespace(host=ht)
@@ -120,7 +129,7 @@ def test_a_run_that_ends_after_the_harvest_is_not_the_calls():
 
 
 def test_parts_fold_by_scope_and_family():
-    ht = hosttrace.reduce(planes(), window_s=10.0)
+    ht = hosttrace.reduce(planes(), 10.0, FOLD)
     assert ht.parts[(256, "attn", "fusion")] == pytest.approx(0.5)
     assert ht.parts[(512, "mlp", "fusion")] == pytest.approx(1.5)
     assert ht.parts[(256, "unscoped", "copy")] == pytest.approx(0.3)
@@ -138,8 +147,39 @@ def test_parts_fold_by_scope_and_family():
     assert "256-row runs: 1 runs" in text and "512-row runs" in text
 
 
+def test_two_chips_read_a_run_as_one_chip_does():
+    """The operations' seconds are summed over the chips and the runs
+    counted per chip: a millisecond a run is a chip's."""
+    ps = planes()
+    second = copy.deepcopy(ps[0])
+    second.name = "/device:TPU:1"
+    one = hosttrace.reduce(ps, 10.0, FOLD)
+    two = hosttrace.reduce([ps[0], second, ps[1]], 10.0, FOLD)
+    assert (two.n_dev, two.n_runs, two.n_joined) == (2, 2, 2)
+    assert two.step_ms == pytest.approx(one.step_ms)
+    assert two.part_s("mlp") == pytest.approx(2 * one.part_s("mlp"))
+    for part in ("attn", "mlp", "rest"):
+        assert two.part_ms(part) == pytest.approx(one.part_ms(part))
+    assert "all runs: 2 runs, 10.000 s of operations, 2500.00 ms a run" \
+        in hosttrace.table(two)[0]
+
+
+def test_a_scope_folds_into_the_part_the_architecture_says():
+    """Another architecture, other scopes: what it folds into ``attn``
+    reads under step_attn_ms, and a scope it does not name is unscoped
+    and reads under ``rest``."""
+    fold = {"attn": "attn", "embed": "attn"}
+    ht = hosttrace.reduce(planes(), 10.0, fold)
+    assert ht.parts[(512, "unscoped", "fusion")] == pytest.approx(1.5)
+    assert ht.part_s("attn") == pytest.approx(0.5 + 1.0 + 0.5)
+    assert ht.part_s("rest") == pytest.approx(1.2 + 0.3 + 1.5)
+    assert ht.part_s("mlp") == 0.0
+
+
 def test_part_is_the_first_scope_on_the_path_that_names_one():
-    part = hosttrace.part_of
+    def part(path):
+        return hosttrace.scope_of(path, FOLD)
+
     assert part("jit(f)/M/encoder/block_3/mlp/Dense_1/dot_general") == "mlp"
     assert part("jit(f)/M/encoder/embed/embed/pos_embed/take") == "embed"
     # an attention projection's own module is called "out"/"key": the
@@ -153,9 +193,9 @@ def test_part_is_the_first_scope_on_the_path_that_names_one():
 
 def test_a_program_without_the_annotations_reads_nothing():
     dev = planes()[0]
-    assert hosttrace.reduce([dev]) is None
+    assert hosttrace.reduce([dev], None, FOLD) is None
     bare = types.SimpleNamespace(host=None)
-    for q in hosttrace.QUANTITIES:
+    for q in QUANTITIES:
         assert observe.load_reader(q + ".backlog")(bare) is None
         assert observe.load_reader(q + ".steady")(object()) is None
 
@@ -200,20 +240,61 @@ def test_the_wire_reader_finds_an_operations_scope():
         "%fusion.2 = f(x)": "jit(f)/M/encoder/block_1/attn/add"}}
 
 
-def test_flops_by_part_sum_to_the_whole_count():
-    from benchmark import opcount
+def test_the_traced_branch_hands_the_trace_to_the_readers(
+        rehearsal, stood_in_trace):
+    """``run.run_cell`` with ``--trace 1`` at the rehearsal's size, the
+    profiler's file stood in for by the hand-made records: the fourteen
+    metrics' quantities read what ``reduce`` reads off them, through the
+    configuration's own architecture's PARTS, and the line says how the
+    calls joined."""
+    for cell, split in (("vit-h14.backlog", "backlog"),
+                        ("vit-h14.steady", "steady")):
+        line = run.run_cell(cell, 31, 1.0, True, rehearse=rehearsal)
+        assert line["correct"] is True
+        got = {k: v["value"] for k, v in line["metrics"].items()}
+        assert {f"{q}.{split}" for q in QUANTITIES} <= set(got)
+        assert got[f"device_step_ms.{split}"] == pytest.approx(2500.0)
+        assert got[f"device_queue_ms.{split}"] == pytest.approx(900.0)
+        assert got[f"fetch_ms.{split}"] == pytest.approx(85.0)
+        assert got[f"step_attn_ms.{split}"] == pytest.approx(750.0)
+        assert got[f"step_mlp_ms.{split}"] == pytest.approx(1350.0)
+        assert got[f"step_rest_ms.{split}"] == pytest.approx(400.0)
+        # 0.07 s of the traced window, whose length is the run's own
+        window_s = line["device"]["window_s"]
+        assert got[f"device_idle_host.{split}"] \
+            == pytest.approx(100.0 * 0.07 / window_s)
+        assert f"padded_share.{split}" in got
+        assert {m: line["metrics"][m]["unit"] for m in got} \
+            == {m["name"]: m["unit"] for m in run.cell_metrics(
+                run.load_json(os.path.join(run.ROOT, "BENCHMARK.json")),
+                run.load_cell(cell)[1], "per_layer") if m["name"] in got}
+        ht = line["hosttrace"]
+        assert (ht["calls"], ht["joined"], ht["runs"]) == (2, 2, 2)
+        assert ht["run_id_agree"] == 1.0
+        assert set(ht["parts"]) == {"attn", "mlp", "rest"}
+        assert ht["parts"]["mlp"]["flops_needed"] > 0
+        assert ht["parts"]["mlp"]["flops_dispatched"] \
+            >= ht["parts"]["mlp"]["flops_needed"]
+        assert list(line)[-1] == "compared"
 
-    vit_h = {"d_model": 1280, "n_heads": 16, "n_layers": 32, "d_ff": 5120,
-             "max_len": 64}
-    pieces = [64, 64, 17, 3, 1]
-    by = hosttrace.flops_by_part(vit_h, pieces)
-    assert sum(by.values()) == pytest.approx(
-        opcount.flops_needed(vit_h, pieces))
-    # a span and layer: projections 13.1 MFLOP, feed-forward 26.2 MFLOP
-    assert by["mlp"] / sum(pieces) / 32 == pytest.approx(26.2e6, rel=1e-2)
-    one = hosttrace.flops_by_part(vit_h, [1])
-    assert (one["attn"] - 2 * 2 * 32 * 1280) / 32 \
-        == pytest.approx(13.1e6, rel=1e-2)
+
+def test_a_ring_that_lapped_the_reader_leaves_the_padded_share_out(
+        rehearsal, monkeypatch):
+    """Spans the tracer's ring dropped before they were read make the
+    collected calls a part of the window's: ``padded_share`` is left out
+    of the line rather than read off them."""
+    sound = run.ScoreSpans.finish
+
+    def lapped(self):
+        calls = sound(self)
+        self.missed += 3
+        return calls
+
+    monkeypatch.setattr(run.ScoreSpans, "finish", lapped)
+    line = run.run_cell("vit-h14.backlog", 32, 1.0, True, rehearse=rehearsal)
+    assert line["correct"] is True
+    assert "padded_share.backlog" not in line["metrics"]
+    assert "ingest_ms.backlog" in line["metrics"]
 
 
 # ------------------------------------------------- what was there, pinned
@@ -235,6 +316,7 @@ def test_the_readers_that_were_there_read_as_they_did():
     import numpy as np
 
     obs = observe.Observation(
+        arch=architectures.load("encoder_preln"),
         model={"d_model": 8, "n_heads": 2, "n_layers": 3, "d_ff": 16,
                "max_len": 4},
         chips=1, device_kind="TPU v5 lite", deadline_ms=8000.0,
@@ -267,16 +349,3 @@ def test_the_readers_that_were_there_read_as_they_did():
     assert len(want) == 13
     for name, value in want.items():
         assert observe.load_reader(name)(obs) == pytest.approx(value), name
-
-
-def test_benchmark_json_only_names_what_has_a_reader():
-    """Every per-layer entry has a reader (test_schema.py walks them per
-    cell); the seven quantities of this file have readers too, for the
-    entries a ``benchmark`` PR adds with the harness's hand-over."""
-    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    for m in bench["per_layer"]:
-        assert callable(observe.load_reader(m["name"]))
-    for q in hosttrace.QUANTITIES:
-        for split in ("backlog", "steady"):
-            assert callable(observe.load_reader(f"{q}.{split}"))

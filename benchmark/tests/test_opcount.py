@@ -1,35 +1,52 @@
-"""The operation count against a hand count for one tiny configuration."""
+"""The operation count of every architecture a configuration names
+against the hand count its case file states (``arch_cases/<name>.py``),
+and the whole against the parts."""
 
 import pytest
 
 from benchmark import opcount
-
-TINY = {"d_model": 8, "n_heads": 2, "n_layers": 3, "d_ff": 16, "max_len": 4}
-
-
-def test_flops_per_span_hand_count():
-    # per layer: q, k, v, out = 4 * 8*8 = 256 MACs; ffn 2 * 8*16 = 256 MACs
-    # 3 layers * 512 MACs * 2 = 3072; embedder cont 3*8 + head 8 = 32 MACs
-    assert opcount.flops_per_span(TINY) == 3072 + 64
+from benchmark.tests.conftest import ARCHITECTURES, arch_case
 
 
-def test_attention_hand_count():
-    # a piece of 3 spans: q k^T 3*3*8 MACs, a v 3*3*8 MACs, 3 layers, 2/MAC
-    assert opcount.attention_flops(TINY, 3) == 2 * 3 * 2 * 9 * 8
+@pytest.fixture(params=ARCHITECTURES)
+def arch_and_case(request):
+    return arch_case(request.param)
 
 
-def test_needed_counts_real_spans_only():
-    got = opcount.flops_needed(TINY, [3, 1])
-    want = 4 * opcount.flops_per_span(TINY) \
-        + opcount.attention_flops(TINY, 3) + opcount.attention_flops(TINY, 1)
-    assert got == want
+def test_hand_count_by_part(arch_and_case):
+    arch, case = arch_and_case
+    got = arch.flops_by_part(case.TINY, case.HAND["pieces"])
+    assert got == case.HAND["by_part"]
 
 
-def test_published_sizes():
-    vit_l = {"d_model": 1024, "n_layers": 24, "d_ff": 4096}
-    assert opcount.flops_per_span(vit_l) == pytest.approx(0.604e9, rel=1e-2)
-    vit_h = {"d_model": 1280, "n_layers": 32, "d_ff": 5120}
-    assert opcount.flops_per_span(vit_h) == pytest.approx(1.258e9, rel=1e-2)
+def test_the_parts_sum_to_the_whole_and_fold_the_scopes(arch_and_case):
+    arch, case = arch_and_case
+    pieces = [64, 64, 17, 3, 1]
+    for model, _ in case.PUBLISHED:
+        by = arch.flops_by_part(model, pieces)
+        assert opcount.flops_needed(arch, model, pieces) \
+            == pytest.approx(sum(by.values()))
+        assert all(v > 0 for v in by.values())
+        # every scope folds into a part that is counted, and operations
+        # under no scope have a part to fall into
+        assert set(arch.PARTS.values()) <= set(by) and "rest" in by
+
+
+def test_needed_counts_real_spans_only(arch_and_case):
+    """Additive over pieces, and more than additive in a piece's length
+    only through attention: two pieces of 3 and 1 need less than one of 4."""
+    arch, case = arch_and_case
+    three, one = (opcount.flops_needed(arch, case.TINY, [n]) for n in (3, 1))
+    assert opcount.flops_needed(arch, case.TINY, [3, 1]) == three + one
+    assert opcount.flops_needed(arch, case.TINY, [4]) > three + one
+    assert opcount.flops_needed(arch, case.TINY, []) == 0
+
+
+def test_published_sizes(arch_and_case):
+    arch, case = arch_and_case
+    for model, per_span in case.PUBLISHED:
+        assert opcount.flops_needed(arch, model, [1]) \
+            == pytest.approx(per_span, rel=1e-2)
 
 
 def test_unknown_device_kind_is_an_error():
