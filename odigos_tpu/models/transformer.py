@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from . import jitstats
-from .layers import Encoder
+from .layers import BLOCK_PARTS, Encoder, LoopedDecoder
 
 # Shape-bucketing strategy per jitted scoring entry point (the package
 # hygiene test asserts every jit path in models/ and parallel/ declares
@@ -49,6 +49,30 @@ class TransformerConfig:
     d_ff: int = 1024
     max_len: int = 64
     dtype: Any = jnp.bfloat16
+    # the block kind (layers.BLOCK_PARTS). "encoder": pre-LN, bidirectional,
+    # a learned table of max_len positions. "decoder": sandwich RMS norms,
+    # rotary positions (no table: max_len bounds the row, not the model),
+    # causal within a trace, SwiGLU, no biases, the stack of n_layers run
+    # ``passes`` times over the same parameters with the final norm
+    # closing every pass. The three keys below are the decoder block's.
+    block: str = "encoder"
+    passes: int = 1
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+
+    def __post_init__(self) -> None:
+        if self.block not in BLOCK_PARTS:
+            raise ValueError(f"unknown block kind {self.block!r} "
+                             f"(known: {sorted(BLOCK_PARTS)})")
+        if self.passes < 1 or (self.block == "encoder" and self.passes != 1):
+            raise ValueError(f"passes {self.passes!r} with block "
+                             f"{self.block!r}: only the decoder block's "
+                             f"stack is looped, and at least once")
+
+    @property
+    def layer_applications(self) -> int:
+        """Blocks a span passes through in one scoring call."""
+        return self.passes * self.n_layers
 
 
 class _TraceTransformerModule(nn.Module):
@@ -58,11 +82,17 @@ class _TraceTransformerModule(nn.Module):
     def __call__(self, categorical, continuous, mask, deterministic=True,
                  positions=None, segments=None):
         c = self.cfg
-        h = Encoder(c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
-                    c.n_heads, c.n_layers, c.d_ff, c.max_len, c.dtype,
-                    name="encoder")(categorical, continuous, mask,
-                                    deterministic, positions=positions,
-                                    segments=segments)
+        if c.block == "encoder":
+            backbone = Encoder(c.service_vocab, c.name_vocab, c.attr_vocab,
+                               c.d_model, c.n_heads, c.n_layers, c.d_ff,
+                               c.max_len, c.dtype, name="encoder")
+        else:
+            backbone = LoopedDecoder(
+                c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
+                c.n_heads, c.n_layers, c.d_ff, c.passes, c.rope_theta,
+                c.norm_eps, c.dtype, name="encoder")
+        h = backbone(categorical, continuous, mask, deterministic,
+                     positions=positions, segments=segments)
         with jax.named_scope("head"):
             span_logit = nn.Dense(1, dtype=jnp.float32,
                                   name="span_head")(h)[..., 0]

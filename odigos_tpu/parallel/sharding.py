@@ -7,6 +7,9 @@ over the mesh from parallel.mesh:
 * attention q/k/v kernels (d_model, n_heads, head_dim): heads on "model"
 * attention out kernel (n_heads, head_dim, d_model): heads on "model"
 * encoder mlp up kernel (d_model, d_ff): d_ff on "model"; down transposed
+* decoder block: q/k/v (d_model, heads x head_dim) and gate/up (d_model,
+  d_ff) columns on "model"; out and down rows on "model"; RMS norms
+  replicated
 * autoencoder decoder ffn + wide vocab heads: d_ff / vocab on "model"
 * embedding tables + layernorms + small heads: replicated
 * batch (packed-row / trace) axis of inputs: "data"
@@ -98,6 +101,10 @@ PARTITION_RULES: tuple[tuple[str, P], ...] = (
     (r"Attention_\d+/out/kernel$", P("model", None, None)),
     (r"block_\d+/Dense_0/kernel$", P(None, "model")),  # mlp up: d_ff cols
     (r"block_\d+/Dense_1/kernel$", P("model", None)),  # mlp down: d_ff rows
+    # the decoder block's seven kernels, all 2D: q/k/v columns are heads,
+    # gate/up columns d_ff; out and down contract over them
+    (r"block_\d+/(q|k|v|gate|up)_proj/kernel$", P(None, "model")),
+    (r"block_\d+/(o|down)_proj/kernel$", P("model", None)),
     (r"dec_ff1/kernel$", P(None, "model")),            # autoencoder decoder
     (r"dec_ff2/kernel$", P("model", None)),
     (r"(service|name)_head/kernel$", P(None, "model")),  # wide vocab heads

@@ -109,6 +109,9 @@ STAGE_DEVICE_METRIC = "odigos_anomaly_stage_device_ms"
 STAGE_HARVEST_METRIC = "odigos_anomaly_stage_harvest_ms"
 ADAPTIVE_CAP_GAUGE = "odigos_engine_adaptive_cap_spans"
 COALESCE_CLOSED_METRIC = "odigos_anomaly_coalesce_closed_total"
+# blocks applied, calls x passes x layers: a build that runs fewer passes
+# than its configuration states shows in the program's own telemetry
+LAYER_APPLICATIONS_METRIC = "odigos_anomaly_layer_applications_total"
 RUNG_SPILL_METRIC = "odigos_anomaly_rung_spill_total"
 MESH_UNAVAILABLE_METRIC = "odigos_engine_mesh_unavailable_total"
 
@@ -440,6 +443,12 @@ class SequenceBackend:
 
             self.model = TraceTransformer(model_config or TransformerConfig(
                 attr_slots=cfg.featurizer.attr_slots))
+            if cfg.quantized and self.model.cfg.block != "encoder":
+                # the int8 scorer mirrors the encoder block's parameter
+                # tree; refuse before any weight is made
+                raise ValueError(
+                    f"quantized serving is only implemented for the "
+                    f"encoder block, not block {self.model.cfg.block!r}")
         else:
             from ..models.autoencoder import AutoencoderConfig, SpanAutoencoder
 
@@ -468,6 +477,12 @@ class SequenceBackend:
         self.last_bucket_hit: Optional[bool] = None
         self.variables = variables if variables is not None else \
             self.model.init(jax.random.PRNGKey(cfg.seed))
+        # what every tpu/score span says of the model behind the call
+        mc = self.model.cfg
+        self.score_attrs: dict[str, Any] = {
+            "model.block": mc.block, "model.passes": mc.passes,
+            "model.layer_applications": mc.layer_applications,
+        } if cfg.model == "transformer" else {}
         self._plan = None
         self._quantized = None
         if cfg.quantized and cfg.model == "transformer":
@@ -1362,7 +1377,9 @@ class ScoringEngine:
         and a call grows from one rung into the next only where that
         scores more spans per device millisecond (``_climb_pays``).
         Backends without a ladder have no rung to fill: their budget is
-        in spans and the request that reaches it closes the call."""
+        in spans and the request that reaches it closes the call. A
+        laddered backend that has reported no real rows yet is budgeted
+        in spans too, and holds the request that would pass the cap."""
         first = self._take(block)
         if first is None:
             return None
@@ -1374,9 +1391,18 @@ class ScoringEngine:
             meter.set_gauge(self._adaptive_gauge_key, cap)
         reason, rung = "drained", None
         if row_cap is None:
+            laddered = getattr(self.backend, "ladder", None) is not None
             while total < cap:
                 nxt = self._take(block=False)
                 if nxt is None:
+                    break
+                if laddered and total + len(nxt.batch) > cap:
+                    # a ladder, and no rows learned to budget by (a cold
+                    # engine's first calls): the span cap is all that
+                    # keeps the call on a rung that was warmed, so it is
+                    # not overshot either
+                    self._held.append(nxt)
+                    reason = "cap"
                     break
                 reqs.append(nxt)
                 total += len(nxt.batch)
@@ -1907,4 +1933,11 @@ class ScoringEngine:
         if grp.bucket_hit is not None:
             sp.set_attr("bucket.hit", grp.bucket_hit)
         sp.set_attr("call.serial", grp.call)
+        attrs = getattr(grp.backend if grp.backend is not None
+                        else self.backend, "score_attrs", {})
+        for key, value in attrs.items():
+            sp.set_attr(key, value)
+        if attrs:
+            meter.add(LAYER_APPLICATIONS_METRIC,
+                      attrs["model.layer_applications"])
         self._device_calls += 1

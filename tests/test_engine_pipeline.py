@@ -609,6 +609,26 @@ def test_ladderless_backends_close_on_the_span_cap_as_before():
     eng.shutdown()
 
 
+def test_a_cold_laddered_engine_does_not_overshoot_the_span_cap():
+    """A ladder, and no call with real rows retired yet: the budget is
+    still in spans, but the request that would pass it is held, so that
+    the first call stays on a rung that was warmed (a cap of two frames
+    and a ladder whose top rung holds two: a third would run past it).
+    Once rows are learned the budget is the rung's."""
+    eng = rung_engine()
+    eng.cfg = EngineConfig(model="mock", max_batch_spans=6144)
+    eng._ewma_spans_per_row = None
+    reqs = enqueue(eng, [FRAME] * 5)
+    before = closed_counts()
+    assert same(eng._collect(block=False), reqs[:2])   # 7,860 > 6,144
+    assert eng._closed == ("cap", None) and eng._held[0] is reqs[2]
+    assert closed_counts()["cap"] - before["cap"] == 1
+    assert same(eng._collect(block=False), reqs[2:4])  # the held one leads
+    assert same(eng._collect(block=False), reqs[4:])
+    assert eng._closed == ("drained", None)
+    eng.shutdown()
+
+
 def test_no_request_is_lost_between_submitters_worker_and_shutdown():
     """The held request is shared state (the worker holds and takes it,
     shutdown() takes it when the worker is gone): under many submitters

@@ -193,12 +193,21 @@ class TestTracedWindow:
 # ------------------------------------------------------- the model's parts
 
 
+# a block kind's extra fields, and operations its lowered program names
+KINDS = {
+    "encoder": ({}, ("block_0/attn/", "block_1/mlp/Dense_1")),
+    "decoder": ({"block": "decoder", "passes": 4, "rope_theta": 1e6},
+                ("block_0/attn/q_proj", "block_1/mlp/down_proj",
+                 "block_0/norm/attn_norm", "stack/norm/final_rms")),
+}
+
+
 class TestNamedScopes:
-    def _model_and_args(self):
+    def _model_and_args(self, kind="encoder"):
         from odigos_tpu.models.transformer import (TraceTransformer,
                                                    TransformerConfig)
 
-        model = TraceTransformer(TransformerConfig(**TINY))
+        model = TraceTransformer(TransformerConfig(**TINY, **KINDS[kind][0]))
         variables = model.init(jax.random.PRNGKey(0))
         rng = np.random.default_rng(0)
         R, L = 4, TINY["max_len"]
@@ -208,14 +217,15 @@ class TestNamedScopes:
                 jnp.asarray(np.tile(np.arange(L), (R, 1)), jnp.int32))
         return model, variables, args
 
-    def test_scopes_change_names_and_nothing_else(self, monkeypatch):
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_scopes_change_names_and_nothing_else(self, monkeypatch, kind):
         import contextlib
 
-        model, variables, args = self._model_and_args()
+        model, variables, args = self._model_and_args(kind)
         scores = np.asarray(model.score_packed(variables, *args))
         monkeypatch.setattr(jax, "named_scope",
                             lambda name: contextlib.nullcontext())
-        bare, bare_vars, _ = self._model_and_args()
+        bare, bare_vars, _ = self._model_and_args(kind)
         paths = jax.tree_util.tree_structure(variables)
         assert paths == jax.tree_util.tree_structure(bare_vars)
         for a, b in zip(jax.tree_util.tree_leaves(variables),
@@ -224,27 +234,31 @@ class TestNamedScopes:
         bare_scores = np.asarray(bare.score_packed(bare_vars, *args))
         assert np.array_equal(scores, bare_scores)   # bit for bit
 
-    def test_the_lowered_text_names_the_parts(self):
-        from odigos_tpu.models.layers import PARTS
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_the_lowered_text_names_the_parts(self, kind):
+        from odigos_tpu.models.layers import BLOCK_PARTS, PARTS
 
-        model, variables, args = self._model_and_args()
+        assert BLOCK_PARTS["encoder"] is PARTS
+        model, variables, args = self._model_and_args(kind)
         text = model.score_packed.lower(variables, *args).as_text(
             debug_info=True)
-        for part in PARTS:
+        for part in BLOCK_PARTS[kind]:
             assert f"/{part}/" in text or f"/{part}\"" in text, part
-        assert "block_0/attn/" in text and "block_1/mlp/Dense_1" in text
+        for named in KINDS[kind][1]:
+            assert named in text, named
 
-    def test_the_mesh_plan_carries_them_through(self):
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_the_mesh_plan_carries_them_through(self, kind):
         """The dp x tp plan jits the model's own traced body, so its
         sharded program names the same parts."""
-        from odigos_tpu.models.layers import PARTS
+        from odigos_tpu.models.layers import BLOCK_PARTS
         from odigos_tpu.parallel import compile_plan, make_mesh
 
-        model, variables, args = self._model_and_args()
+        model, variables, args = self._model_and_args(kind)
         plan = compile_plan(model, make_mesh({"data": 2}))
         text = plan._packed_jit.lower(
             plan.place_variables(variables), *args).as_text(debug_info=True)
-        for part in PARTS:
+        for part in BLOCK_PARTS[kind]:
             assert f"/{part}/" in text or f"/{part}\"" in text, part
 
     def test_the_int8_scorer_carries_the_same_parts(self):
