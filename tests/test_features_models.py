@@ -1,5 +1,7 @@
 """Featurizer + model tests (CPU backend, tiny shapes)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -381,6 +383,90 @@ def test_score_packed_segment_isolation():
                                 jnp.asarray(segs), jnp.asarray(poss))
     np.testing.assert_allclose(np.asarray(alone)[0, :n_a],
                                np.asarray(shared)[0, :n_a], atol=1e-5)
+
+
+# ----------------------------- the encoder block's fusion boundary
+# EncoderBlock parts its feed-forward's two products with
+# jax.lax.optimization_barrier (the identity; ISSUE 30): what reaches the
+# compiler, that it differentiates, and that no score moves by it.
+TINY_BLOCKS = {
+    "encoder": (dict(n_layers=3), 3),
+    "decoder": (dict(n_layers=2, block="decoder", passes=2), 0),
+}
+
+
+def tiny_block_model(**over):
+    return TraceTransformer(dataclasses.replace(
+        TINY_TF, **{"n_layers": 3, **over}))
+
+
+def without_boundary(monkeypatch):
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+
+
+@pytest.mark.parametrize("kind", sorted(TINY_BLOCKS))
+def test_lowered_program_parts_the_encoder_blocks_feed_forward(kind):
+    over, want = TINY_BLOCKS[kind]
+    model = tiny_block_model(**over)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    rows = jax.ShapeDtypeStruct((4, 16), jnp.int32)
+    text = jax.jit(model._score_packed_impl).lower(
+        variables, jax.ShapeDtypeStruct((4, 16, 5), jnp.int32),
+        jax.ShapeDtypeStruct((4, 16, 3), jnp.float32), rows,
+        rows).as_text(debug_info=True)
+    assert text.count("stablehlo.optimization_barrier") == want
+    named = [line for line in text.splitlines()
+             if line.startswith("#loc") and "optimization_barrier" in line]
+    assert len(named) == want
+    assert all('/mlp/optimization_barrier"' in line for line in named)
+
+
+def test_boundary_differentiates_as_the_identity(tiny_seqs, monkeypatch):
+    rng = np.random.default_rng(0)
+    args = (jnp.asarray(tiny_seqs.categorical),
+            jnp.asarray(tiny_seqs.continuous), jnp.asarray(tiny_seqs.mask),
+            jnp.asarray((rng.random(tiny_seqs.mask.shape) < 0.2)
+                        & tiny_seqs.mask),
+            jnp.asarray(rng.random(tiny_seqs.n_traces) < 0.5))
+    model = tiny_block_model()
+    variables = model.init(jax.random.PRNGKey(0))
+    grad = jax.jit(jax.grad(model.loss_fn))
+    assert "optimization_barrier" in grad.lower(variables, *args).as_text()
+    with_it = grad(variables, *args)
+    without_boundary(monkeypatch)
+    grad = jax.jit(jax.grad(tiny_block_model().loss_fn))
+    assert "optimization_barrier" not in grad.lower(
+        variables, *args).as_text()
+    without = grad(variables, *args)
+    moved = 0.0
+    for got, want in zip(jax.tree.leaves(with_it), jax.tree.leaves(without)):
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, want)
+        moved += float(jnp.abs(got).sum())
+    assert moved > 0
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_served_scores_do_not_move_with_the_boundary(dtype, monkeypatch):
+    from odigos_tpu.serving import EngineConfig, ScoringEngine
+
+    def served():
+        eng = ScoringEngine(EngineConfig(
+            model="transformer", max_len=16, trace_bucket=8,
+            bucket_ladder=2, model_config=dataclasses.replace(
+                TINY_TF, n_layers=3, dtype=dtype))).start()
+        try:
+            return [eng.score_sync(synthesize_traces(n, seed=n),
+                                   timeout_s=120.0) for n in (6, 20)]
+        finally:
+            eng.shutdown()
+
+    with_it = served()
+    without_boundary(monkeypatch)
+    for got, want in zip(with_it, served()):
+        assert len(got) and np.isfinite(got).all()
+        np.testing.assert_array_equal(got, want)
 
 
 class TestQuantizedScorer:
