@@ -1,7 +1,7 @@
 # Developer entrypoints (reference: Makefile at the repo root).
 # No install step: the package runs from the repo root.
 
-.PHONY: test test-fast chip-smoke bench dryrun multichip multichip-simulated ui preflight soak
+.PHONY: test test-fast chip-smoke dryrun ui preflight
 
 CPU_MESH = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
@@ -15,20 +15,8 @@ test-fast:       ## everything but the slow parallel/e2e/auc suites
 chip-smoke:      ## wire-to-score path on one TPU chip; exits non-zero anywhere else
 	python chip_smoke.py
 
-bench:           ## host-clock device timings (needs a TPU; JSON lines, last one complete)
-	python bench.py
-
-soak:            ## e2e wire-path soak on whatever platform JAX finds (writes SOAK.json)
-	python tools/e2e_soak.py --seconds 30 --senders 2
-
 dryrun:          ## multi-chip sharding compile+execute on 8 virtual CPU devices
 	$(CPU_MESH) python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
-
-multichip:       ## wire-fed dp-scaling bench on real chips (fails when too few)
-	python tools/multichip_bench.py
-
-multichip-simulated: ## the same bench on a virtual CPU mesh (record marked simulated)
-	python tools/multichip_bench.py --simulated
 
 ui:              ## operator dashboard over the local install
 	python -m odigos_tpu.cli ui

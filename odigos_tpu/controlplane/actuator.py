@@ -50,8 +50,8 @@ Surfaces: ``odigos_actuator_*`` metrics (proposals / canaries /
 promotions / rollbacks / refusals by rule and knob), an
 ``actuator/<rule>`` condition row on every rollup while an actuation is
 in flight, ``GET /api/actuator``, ``/debug/actuatorz``, the dashboard
-panel, describe and diagnose. ``tools/e2e_soak.py --actuate`` records
-the whole loop live (ACTUATOR.json).
+panel, describe and diagnose. ``tests/test_actuator.py`` drives the
+whole loop on a live in-process collector.
 """
 
 from __future__ import annotations
@@ -679,7 +679,7 @@ class FleetActuator:
             # the reload LANDED but via the full-rebuild path (patch
             # fallback, or a dirty graph that bypassed the differ) —
             # the caller reverts; recording "incremental" here would
-            # let ACTUATOR.json claim a teardown never happened
+            # let the history claim a teardown never happened
             return "full", None, True
         return expected, None, True
 

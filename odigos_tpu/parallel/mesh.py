@@ -46,9 +46,8 @@ def mesh_key(shape) -> str:
 
 def ensure_host_devices(n_devices: int) -> int:
     """Force an n-device virtual CPU platform, for an EXPLICIT CPU dry
-    run of the dp×tp serving path (``__graft_entry__.dryrun_multichip``,
-    ``tools/multichip_bench.py --simulated``). It takes the process off
-    any accelerator, so nothing may call it because a probe failed or a
+    run of the dp×tp serving path (``__graft_entry__.dryrun_multichip``).
+    It takes the process off any accelerator, so nothing may call it because a probe failed or a
     device was not found: a run that was meant for the chip fails
     instead. Must run before the jax backend initializes — XLA_FLAGS is
     only read once; afterwards this degrades to reporting the device

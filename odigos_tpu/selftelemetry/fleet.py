@@ -930,7 +930,7 @@ class FleetPlane:
 
     def tick(self) -> None:
         """One timer step (also callable inline by harnesses that own
-        their own cadence — e2e_soak's wait loop)."""
+        their own cadence)."""
         with self._lock:
             pulls = [(e.collector_id, e.group, e.source)
                      for e in self._collectors.values()

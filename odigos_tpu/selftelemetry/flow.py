@@ -5,8 +5,7 @@ boundary (the OTel Collector's ``obsreport`` seam that odigos builds its
 UI data-flow and CRD status conditions on). This module is that layer
 for our pipelines: **in = out + dropped(reason) + failed(error_class)**,
 provable per pipeline, always on, cheap enough for the hot path (one
-counter bump per batch per edge — bench.py ``flow_overhead`` holds it
-under 2%).
+counter bump per batch per edge).
 
 Model:
 

@@ -2,7 +2,7 @@
 and multi-window burn-rate SLOs.
 
 The flow ledger (PR 5) proves *what* flows — conservation per edge,
-named drops — but not *where time goes*: SOAK.json records a 360 ms p99
+named drops — but not *where time goes*: a frame's tail latency arrives
 with zero attribution across
 wire→admission→decode→featurize→queue→pack→device→harvest→tag→forward.
 This module is that attribution layer, the signal the ROADMAP's
@@ -52,8 +52,7 @@ sizes, ladder rungs, replica counts") is blocked on:
 
 ``ODIGOS_LATENCY=0`` disables the layer (clocks become no-ops, nothing
 records) — the same opt-out contract as ``ODIGOS_FLOW`` /
-``ODIGOS_SELFTRACE``. bench.py ``latency_attribution_overhead`` holds
-the enabled cost under 2 % on the fast-path soak route.
+``ODIGOS_SELFTRACE``.
 """
 
 from __future__ import annotations

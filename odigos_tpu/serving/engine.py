@@ -544,7 +544,7 @@ class SequenceBackend:
         arrays of one device call. Returns what ``enqueue`` takes."""
         if self.cfg.model == "transformer":
             # packed rows: block-diagonal attention, ~6x the MXU density of
-            # naive per-trace padding (bench.py measures this path)
+            # naive per-trace padding
             packed = pack_sequences(batch, features, max_len=self.max_len,
                                     pad_rows_to=self._round_rows)
             # scoring-span attributes: device shape + padding waste (the
@@ -1220,7 +1220,7 @@ class ScoringEngine:
 
     def failover_status(self) -> Optional[dict[str, Any]]:
         """The breaker's state snapshot (None = no breaker configured)
-        — surfaced in pipeline_stats and the chaos soak's CHAOS.json."""
+        — surfaced in pipeline_stats."""
         return self.failover.status() if self.failover is not None \
             else None
 
@@ -1255,8 +1255,8 @@ class ScoringEngine:
         return out
 
     def pipeline_stats(self) -> dict[str, Any]:
-        """Pipeline observability snapshot (bench.py reports this next to
-        spans_per_sec_per_chip_scored so the overlap win is visible)."""
+        """Pipeline observability snapshot: per-stage percentiles, depth
+        and overlap of the device calls in flight."""
         log = list(self._stage_log)
 
         def pcts(key: str) -> dict[str, float]:

@@ -27,8 +27,8 @@ the degradation rung between "engine errors" and "pipeline dies"
   a failed probe re-opens it and re-arms the timer.
 * state is **observable end to end**: ``odigos_failover_*`` metrics
   (state gauge, trips/recoveries, per-result probe counters, fallback-
-  scored span volume), a bounded transition history (the chaos soak's
-  ``CHAOS.json`` timeline), and a ``ModelFailover`` condition raised
+  scored span volume), a bounded transition history, and a
+  ``ModelFailover`` condition raised
   through the flow ledger's :class:`HealthRollup` as the
   ``engine/<model>`` row — Degraded while the fallback serves, back to
   Healthy on recovery, so the scenario oracle can assert the round trip.

@@ -5,14 +5,13 @@ The componentwise route re-touches every span several times between the
 socket and the device: the memory limiter estimates bytes, the batch
 processor buffers and re-concatenates (string tables re-interned
 span-by-span), and the engine re-derives features for each merged batch.
-``SOAK.json`` shows the consequence — a single sender drives e2e p99 to
-~1.2 s while the device itself scores in 2 ms. This module is the
-shortcut the ROADMAP's "kill the soak tail" item asks for:
+The consequence is a tail set by the host and not by the device: every
+frame waits behind work that was already done once. This module is the
+shortcut:
 
 * the receiver hands each zero-copy ``decode_frame`` batch straight to
   :class:`IngestFastPath`, which reserves window capacity and returns —
-  wire intake never pays featurize (20.7 ms mean in the PR 8 record) or
-  scoring per frame;
+  wire intake never pays featurize or scoring per frame;
 * a pool of **submit lanes** featurizes each frame ONCE (hash tables
   memoized per interned string pool, attr slots memoized per store) and
   submits to the scoring engine with an **admission deadline**;

@@ -27,7 +27,7 @@ allocator:
 * every collection — janitor-paced or threshold-triggered, any thread —
   is timed via ``gc.callbacks`` into the ``odigos_gc_pause_ms{gen=}``
   histogram, so "GC left the waterfall" is a measurable claim, not a
-  vibe (the soak embeds the pause stats in SOAK.json).
+  vibe.
 
 The callback deliberately never touches the meter (a threshold
 collection can fire INSIDE a meter lock hold — re-entering the meter

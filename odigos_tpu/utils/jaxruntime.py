@@ -13,7 +13,7 @@ machine whose accelerator belongs to ONE process at a time:
 * ``configure_compile_cache()`` — where the persistent XLA compile
   cache lives. Called once by every process entry point that will
   compile (``chip_smoke.py``, ``python -m odigos_tpu.pipeline``,
-  ``python -m odigos_tpu.serving.sidecar``, ``bench.py``, ``tools/``).
+  ``python -m odigos_tpu.serving.sidecar``, ``benchmark/run.py``).
   ``JAX_COMPILATION_CACHE_DIR`` wins when set (jax reads it into
   ``jax_compilation_cache_dir`` itself, so nothing is set here);
   otherwise the cache is one fixed directory inside the checkout. The

@@ -330,8 +330,7 @@ class TestFastPathPoolLifecycle:
         # one submit lane = one pool, drain after EVERY frame: the
         # in-flight depth is pinned at 1, so the warm set is exactly
         # one frame's buffers and the zero-miss claim is deterministic
-        # under any CI load (bench.py steady_state_allocs measures the
-        # concurrent/amortized version of the same claim)
+        # under any CI load
         fp, eng = self._fp(submit_lanes=1, lanes=2)
         try:
             batches = [synthesize_traces(24, seed=s) for s in range(4)]

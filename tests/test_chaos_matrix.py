@@ -171,11 +171,17 @@ def drop_total(reason: str, component: str = "") -> int:
     return total
 
 
-def assert_incident(fault: str) -> dict:
+def assert_incident(fault: str, consequences=None) -> dict:
     """Oracle part 5: the flight recorder froze EXACTLY ONE
     ``chaos_injection`` incident and it names this scenario's injected
     fault — the black box saw the chaos, and nothing spurious rode
-    along. Returns the bundle for scenario-specific follow-ups."""
+    along. ``consequences`` closes the set: the only other triggers the
+    fault may have frozen (the breaker it tripped, the alert it fired).
+    Returns the bundle for scenario-specific follow-ups."""
+    if consequences is not None:
+        stray = [(i["id"], i["trigger"]) for i in flight_recorder.incidents()
+                 if i["trigger"] not in ("chaos_injection", *consequences)]
+        assert not stray, f"incidents the fault does not explain: {stray}"
     incs = [i for i in flight_recorder.incidents()
             if i["trigger"] == "chaos_injection"]
     assert len(incs) == 1, (
@@ -295,7 +301,8 @@ class TestDeviceLossFailover:
             assert sup.trips >= 1 and sup.recoveries >= 1
             assert sup.fallback_spans > 0
             assert_conserved()
-            assert_incident("device_fault")
+            assert_incident("device_fault",
+                            consequences=("breaker_trip", "alert_firing"))
             # the breaker trip froze its own incident alongside
             assert any(i["trigger"] == "breaker_trip"
                        for i in flight_recorder.incidents())
@@ -412,7 +419,8 @@ class TestDestinationOutageRetrySpill:
             assert stats["dropped_spans"] == 0
             assert stats["delivered_spans"] == sent["spans"]
             assert_conserved()
-            assert_incident("destination_outage")
+            assert_incident("destination_outage",
+                            consequences=("alert_firing",))
 
 
 class TestDestinationOutageQueueOverflow:
@@ -474,7 +482,8 @@ class TestDestinationOutageQueueOverflow:
                 == sent["spans"]
             assert _db(env).span_count == stats["delivered_spans"]
             assert_conserved()
-            assert_incident("destination_outage")
+            assert_incident("destination_outage",
+                            consequences=("alert_firing",))
 
 
 class TestMemoryPressureBackpressure:
@@ -823,7 +832,9 @@ class TestActuatorCanaryPromote:
             anomaly=AnomalyStageConfiguration(
                 enabled=True, model="zscore", timeout_ms=3.0,
                 fast_path=True, fast_path_predictive=False,
-                slo=SloConfiguration(scored_fraction=0.9,
+                # 0.98, not looser: the fast burn pages at 14.4x, and a
+                # target of 0.9 caps the burn at 10x (un-pageable)
+                slo=SloConfiguration(scored_fraction=0.98,
                                      fast_window_s=3.0,
                                      slow_window_s=6.0)),
             alerts=[self.ALERT])
@@ -881,6 +892,11 @@ class TestActuatorCanaryPromote:
             burst(e)
             return scored_spans() > state["scored_at_promote"] + 200
 
+        def slo_burning(e):
+            burst(e)
+            return expect_condition(e, "slo/traces/in", "Degraded",
+                                    "SLOBurn")
+
         with E2EEnvironment(nodes=1, config=cfg) as env:
             Scenario("actuator-canary-promote", [
                 Step("add destination",
@@ -892,6 +908,8 @@ class TestActuatorCanaryPromote:
                      assert_fn=overload_expires, timeout_s=30.0),
                 Step("expiry alert fired",
                      assert_fn=alert_fires, timeout_s=15.0),
+                Step("the overload burns the scored-fraction SLO",
+                     assert_fn=slo_burning, timeout_s=15.0),
                 Step("held recommendation canaries the deadline "
                      "(condition row raised, knob turned on the "
                      "canary)",
@@ -900,6 +918,9 @@ class TestActuatorCanaryPromote:
                      assert_fn=promoted, timeout_s=30.0),
                 Step("scoring recovers under the raised deadline",
                      assert_fn=scoring_recovers, timeout_s=20.0),
+                Step("the SLO burn clears with no operator input",
+                     assert_fn=lambda e: not slo_burning(e),
+                     timeout_s=20.0),
             ], finally_steps=[
                 Step("clear all faults",
                      script=lambda e: clear_all(e)),

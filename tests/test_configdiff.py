@@ -77,6 +77,25 @@ def wire_config(fast_path=True, threshold=0.6, port=0, **fp_overrides):
     }
 
 
+def start_sender(exp, stop):
+    """A thread that ships 16-trace frames through ``exp``, paced and
+    bounded to 8 queued, until ``stop`` is set."""
+    batches = [synthesize_traces(16, seed=s) for s in range(4)]
+
+    def sender():
+        k = 0
+        while not stop.is_set():
+            exp.export(batches[k % 4])
+            k += 1
+            while exp.queued > 8 and not stop.is_set():
+                time.sleep(0.001)
+            time.sleep(0.002)
+
+    t = threading.Thread(target=sender, daemon=True)
+    t.start()
+    return t
+
+
 # --------------------------------------------------- differ classification
 
 
@@ -320,19 +339,7 @@ class TestIncrementalReload:
             exp = WireExporter("t", {"endpoint": f"127.0.0.1:{port}",
                                      "max_elapsed_s": 30.0})
             exp.start()
-            batches = [synthesize_traces(16, seed=s) for s in range(4)]
-
-            def sender():
-                k = 0
-                while not stop.is_set():
-                    exp.export(batches[k % 4])
-                    k += 1
-                    while exp.queued > 8 and not stop.is_set():
-                        time.sleep(0.001)
-                    time.sleep(0.002)
-
-            t = threading.Thread(target=sender, daemon=True)
-            t.start()
+            t = start_sender(exp, stop)
             time.sleep(0.2)
             new = wire_config(fast_path=True, threshold=0.9)
             c.reload(new)
@@ -352,6 +359,66 @@ class TestIncrementalReload:
             bal = flow_ledger.conservation()["traces/in"]
             assert bal["leak"] == 0, bal
             assert c.graph.exporters["tracedb"].span_count > 0
+        finally:
+            stop.set()
+            c.shutdown()
+
+    def test_reload_storm_under_load_stays_incremental(self):
+        """A storm of single-knob reloads under live wire traffic, on a
+        scorer that jits: every reload reconfigures in place (no node
+        replaced, none rebuilt), the warm engine compiles nothing, and
+        the stream stays conserved — an empty storm certifies nothing,
+        so the count is asserted too."""
+        from odigos_tpu.models import jitstats
+
+        def nodes(action):
+            return meter.counter(
+                f"odigos_collector_reload_nodes_total{{action={action}}}")
+
+        flow_ledger.reset()
+        cfg = wire_config(fast_path=True)
+        cfg["processors"]["tpuanomaly"]["model"] = "zscore"
+        c = Collector(cfg).start()
+        stop = threading.Event()
+        try:
+            g0 = c.graph
+            fp0 = c.graph.fastpaths["traces/in"]
+            engine0 = fp0.engine
+            port = c.graph.receivers["otlpwire"].port
+            exp = WireExporter("t", {"endpoint": f"127.0.0.1:{port}",
+                                     "max_elapsed_s": 30.0})
+            exp.start()
+            sink = c.graph.exporters["tracedb"]
+            t = start_sender(exp, stop)
+            # every shape the traffic brings is compiled before the storm
+            assert wait_for(lambda: sink.span_count >= 3000)
+            incremental0 = meter.snapshot().get(
+                "odigos_collector_reload_ms_count{mode=incremental}", 0)
+            storm = 4
+            for k in range(storm):
+                new = copy.deepcopy(c.config)
+                new["processors"]["tpuanomaly"]["threshold"] = \
+                    0.6 + 0.001 * ((k % 2) + 1)
+                replaced0, reconf0 = nodes("replaced"), nodes("reconfigured")
+                compiles0 = sum(jitstats.cache_sizes().values())
+                c.reload(new)
+                assert nodes("replaced") == replaced0
+                assert nodes("reconfigured") >= reconf0 + 1
+                assert sum(jitstats.cache_sizes().values()) == compiles0, \
+                    "a knob change recompiled the warm engine"
+                assert c.graph is g0 and fp0.engine is engine0
+                time.sleep(0.1)
+            assert meter.snapshot().get(
+                "odigos_collector_reload_ms_count{mode=incremental}",
+                0) == incremental0 + storm
+            stop.set()
+            t.join(timeout=10)
+            assert not t.is_alive()
+            assert exp.flush(30.0)
+            exp.shutdown()
+            c.drain_receivers(30.0)
+            bal = flow_ledger.conservation()["traces/in"]
+            assert bal["leak"] == 0, bal
         finally:
             stop.set()
             c.shutdown()

@@ -307,7 +307,11 @@ class TestDeviceAttribution:
         assert wf["total_ms"] == pytest.approx(
             sum(wf["stages"].values()), abs=0.01)
         assert wf["fused_device_ms"] > 0
-        assert wf["reconcile_ratio"] > 0
+        # the sampled sub-stage sum reconciles with the opaque fused
+        # stamp: ~1 on an idle box, and wide enough here that only a
+        # broken decomposition (a stamp in the wrong unit, a stage that
+        # did not run) fails it, not a preempted worker
+        assert 0.02 <= wf["reconcile_ratio"] <= 50.0
 
     def test_skip_reason_keys_closed(self, fused_env):
         _, backend, _ = fused_env
@@ -532,16 +536,15 @@ class TestDeviceSnapshot:
 class TestOverheadGuard:
     def test_armed_sampler_overhead_under_2_percent(self):
         """Armed-vs-disarmed host wall of ``dispatch_columns`` on the
-        warmed SOAK-geometry fused backend with the 1-in-32 sampler
-        (bench.py's ``device_attribution_overhead_bench`` pairing, as
-        a tier-1 bar): the identical frame dispatched in both modes
+        warmed miniature fused backend with the 1-in-32 sampler, as a
+        tier-1 bar: the identical frame dispatched in both modes
         back to back on one backend, within-pair order alternating,
         harvest blocking OUTSIDE the timer, median of the paired
         ratios. The bound is the 31-of-32 claim — a non-sampled armed
         frame pays only the ordinal tick and a None check — so each
         window aligns to the grid with the sampled tick consumed
         OUTSIDE it: the sampled frame's own waterfall cost is the
-        price of the feature, reported separately by the bench, and
+        price of the feature, and
         its ~300× dispatch mid-window measurably disturbs the frames
         after it (allocator/clock state) in both modes. Up to three
         windows: one clean window proves the sampler CAN run under

@@ -166,8 +166,8 @@ class TestBackendParity:
     """dispatch_columns == dispatch/harvest, per span, every backend."""
 
     def test_product_bounds_are_the_ones_pinned_here(self):
-        """chip_smoke.py, bench.py and the soak's parity gate judge with
-        serving/fused.py's bounds; this file is where they are set."""
+        """chip_smoke.py's parity gate judges with serving/fused.py's
+        bounds; this file is where they are set."""
         assert PARITY_F32 == (FUSED_RTOL, FUSED_ATOL)
         assert PARITY_BF16 == (1e-2, 1e-4)  # test_bfloat16_backend_parity
         assert PARITY_INT8 == (0.05, 5e-3)  # test_quantized_backend_parity
@@ -394,6 +394,13 @@ class TestFusedFastPath:
         # every span still scored (host route, not a shed)
         assert all(SCORE_ATTR in d for b in sink.batches
                    for d in b.span_attrs)
+        # restored: the switch is read per frame, so the very next
+        # frames ride the fused route again and none falls back
+        monkeypatch.delenv("ODIGOS_FUSED")
+        sink = run_fastpath(frames, {"fused": True})
+        assert sink.span_count == sum(len(f) for f in frames)
+        assert meter.counter(self.FUSED_KEY) == len(frames)
+        assert meter.counter(self.fallback_key("disabled")) == len(frames)
 
     def test_mixed_storm_conserves_exact(self):
         """Covered, legacy-JSON, and misaligned frames interleaved: every

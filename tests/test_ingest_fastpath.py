@@ -25,6 +25,7 @@ import pytest
 from odigos_tpu.features import FeaturizerConfig, featurize
 from odigos_tpu.pdata import synthesize_traces
 from odigos_tpu.pipeline.service import Collector
+from odigos_tpu.selftelemetry.flightrecorder import flight_recorder
 from odigos_tpu.selftelemetry.flow import flow_ledger
 from odigos_tpu.serving import EngineConfig, ScoringEngine
 from odigos_tpu.serving.fastpath import (
@@ -441,6 +442,7 @@ class TestHotReload:
 class TestConservation:
     def test_burst_conserves_and_pending_counts(self):
         flow_ledger.reset()
+        flight_recorder.reset()
         collector = Collector(soak_config(fast_path=True)).start()
         try:
             port = collector.graph.receivers["otlpwire"].port
@@ -461,8 +463,13 @@ class TestConservation:
             bal = flow_ledger.conservation()["traces/in"]
             assert bal["items_in"] == total
             assert bal["leak"] == 0, bal
+            # a clean wire-fed burst (no fault injected, nothing shed)
+            # freezes no incident: anything here is a trigger misfiring
+            assert flight_recorder.enabled
+            assert flight_recorder.incidents() == []
         finally:
             collector.shutdown()
+            flight_recorder.reset()
 
     def test_flow_pending_reflects_window(self):
         eng = ScoringEngine(EngineConfig(model="mock"))  # not started

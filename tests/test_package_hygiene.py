@@ -62,8 +62,7 @@ def test_every_module_is_imported_somewhere():
                 p = os.path.join(dirpath, n)
                 files[_module_name(p)] = p
     # tests and the driver entry also count as importers
-    extra = [os.path.join(REPO_ROOT, "bench.py"),
-             os.path.join(REPO_ROOT, "__graft_entry__.py")]
+    extra = [os.path.join(REPO_ROOT, "__graft_entry__.py")]
     tests_dir = os.path.dirname(os.path.abspath(__file__))
     extra += [os.path.join(tests_dir, n) for n in os.listdir(tests_dir)
               if n.endswith(".py")]
@@ -725,28 +724,6 @@ class TestFleetRuleHygiene:
                                 f"in sizing.TUNING_KNOBS")
         assert not problems, "\n".join(problems)
 
-    def test_soak_alert_rules_resolve(self):
-        """The soak harness's shipped alert stanza must reference real
-        metrics — SOAK.json claiming an alert loop over series that can
-        never exist would be worse than no alert at all."""
-        import importlib.util
-
-        from odigos_tpu.selftelemetry.fleet import (
-            referenced_metric, validate_alert_rules)
-
-        spec = importlib.util.spec_from_file_location(
-            "e2e_soak_lint", os.path.join(REPO_ROOT, "tools",
-                                          "e2e_soak.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert validate_alert_rules(mod.SOAK_ALERTS) == []
-        assert validate_alert_rules(mod.CHAOS_ALERTS) == []
-        registry = self._registered_metric_names()
-        for rule in mod.SOAK_ALERTS + mod.CHAOS_ALERTS:
-            metric = referenced_metric(rule["expr"])
-            assert self._resolves(metric, registry), \
-                f"soak alert {rule['name']}: {metric!r} unregistered"
-
     def test_typoed_metric_fails_resolution(self):
         """The lint's own oracle: a plausible-but-wrong name must NOT
         resolve (guards against the registry scan degenerating into
@@ -901,29 +878,6 @@ class TestActuatorKnobHygiene:
         assert len(FALLBACK_REASONS) == len(set(FALLBACK_REASONS))
         for reason in FALLBACK_REASONS:
             assert re.fullmatch(r"[a-z_]+", reason), reason
-
-    def test_soak_actuate_rules_resolve(self):
-        """The --actuate soak's rule/alert tables reference real
-        metrics and real knobs (the SOAK_ALERTS discipline)."""
-        import importlib.util
-
-        from odigos_tpu.config.sizing import KNOB_SPECS
-        from odigos_tpu.selftelemetry.fleet import (
-            referenced_metric, validate_alert_rules)
-
-        spec = importlib.util.spec_from_file_location(
-            "e2e_soak_lint2", os.path.join(REPO_ROOT, "tools",
-                                           "e2e_soak.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert validate_alert_rules(mod.ACTUATE_ALERTS) == []
-        registry = TestFleetRuleHygiene._registered_metric_names()
-        lint = TestFleetRuleHygiene()
-        for rule in mod.ACTUATE_RULES:
-            metric = referenced_metric(rule["expr"])
-            assert lint._resolves(metric, registry), \
-                f"actuate rule {rule['name']}: {metric!r} unregistered"
-            assert rule["knob"] in KNOB_SPECS
 
 
 class TestChaosInjectorHygiene:
@@ -1717,3 +1671,78 @@ class TestDeviceSubStageHygiene:
         # the footprint gauge is published with a literal name in the
         # DeviceRuntimeCollector — the registry scan must see it
         assert "odigos_device_table_bytes" in registry
+
+
+class TestRootAndDocumentHygiene:
+    """One way to measure, one record (ISSUE 32): ``benchmark/`` and the
+    ledger are the measurement, so the root keeps no other record, and a
+    document names no file that is not there — a how-to built on a
+    deleted harness is worse than none."""
+
+    DOCUMENTS = ("README.md", "DEVELOPMENT.md", "docs/architecture.md",
+                 "docs/benchmarks.md", "docs/self-telemetry.md",
+                 "docs/migration.md", "Makefile",
+                 ".claude/skills/verify/SKILL.md")
+    PREFIXES = ("odigos_tpu/", "tests/", "benchmark/", "tools/", "docs/",
+                "distribution/")
+    ROOT_FILE = re.compile(r"[\w.-]+\.(?:py|jsonl|json|md)")
+
+    @staticmethod
+    def _bundle_members() -> set:
+        """Members of the diagnose archive (``latency.json`` ...): files
+        of a bundle, not of the repository."""
+        with open(os.path.join(PKG_ROOT, "cli", "diagnose.py")) as f:
+            return set(re.findall(r'add\(\s*"([\w.-]+)"', f.read()))
+
+    @classmethod
+    def _stale_paths(cls, text: str, recipes: bool) -> list:
+        import glob
+
+        spans = re.findall(r"`([^`\n]+)`", text)
+        if recipes:
+            spans += [ln for ln in text.splitlines() if ln.startswith("\t")]
+        members = cls._bundle_members()
+        stale = set()
+        for span in spans:
+            for tok in span.split():
+                tok = tok.strip("\"'()[],;").split("::")[0]
+                tok = re.sub(r":\d+(-\d+)?$", "", tok).rstrip(".:")
+                if "<" in tok or "{" in tok or "$" in tok or tok in members:
+                    continue
+                if not (cls.ROOT_FILE.fullmatch(tok)
+                        or tok.startswith(cls.PREFIXES)):
+                    continue
+                if not glob.glob(os.path.join(REPO_ROOT, tok)):
+                    stale.add(tok)
+        return sorted(stale)
+
+    def test_root_json_is_the_benchmark_and_the_baseline(self):
+        import fnmatch
+
+        with open(os.path.join(REPO_ROOT, ".gitignore")) as f:
+            ignored = [ln.strip() for ln in f if ln.strip()]
+        found = sorted(
+            n for n in os.listdir(REPO_ROOT) if n.endswith(".json")
+            and not any(fnmatch.fnmatch(n, pat) for pat in ignored))
+        assert found == ["BASELINE.json", "BENCHMARK.json"], (
+            "a record at the root that nothing reads; the driver's "
+            "readings live in PERF_LEDGER.jsonl")
+
+    @pytest.mark.parametrize("document", DOCUMENTS)
+    def test_documents_name_only_paths_that_exist(self, document):
+        with open(os.path.join(REPO_ROOT, document)) as f:
+            text = f.read()
+        stale = self._stale_paths(text, recipes=document == "Makefile")
+        assert not stale, f"{document} names {stale}, which do not exist"
+
+    def test_scan_catches_a_stale_path(self):
+        """The lint's own oracle: a deleted harness in backticks or in a
+        recipe is caught, a bundle member and a placeholder are not."""
+        text = ("run `python tools/gone.py --x` then `GONE.json`, see "
+                "`tests/test_package_hygiene.py::TestX::test_y`, "
+                "`latency.json` and `benchmark/configs/<name>.json`\n"
+                "\tpython gone_too.py\n")
+        assert self._stale_paths(text, recipes=True) == [
+            "GONE.json", "gone_too.py", "tools/gone.py"]
+        assert self._stale_paths(text, recipes=False) == [
+            "GONE.json", "tools/gone.py"]

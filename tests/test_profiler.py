@@ -178,9 +178,15 @@ class TestDeviceRuntimeCollector:
         assert meter.gauge(key) is None
 
     def test_cpu_jax_state_graceful(self):
-        # conftest imported jax on CPU: live_arrays works, memory_stats
-        # is None on CPU devices — the collector must not raise and must
-        # not publish device-memory gauges it cannot observe
+        # the collector reads device facts only once THIS process has
+        # initialised a backend (imported is not initialised: telemetry
+        # never claims the chip), so initialise the CPU one here. Then
+        # live_arrays works, memory_stats is None on CPU devices — the
+        # collector must not raise and must not publish device-memory
+        # gauges it cannot observe
+        import jax
+
+        jax.devices()
         out = DeviceRuntimeCollector()._collect_jax()
         assert "odigos_device_live_arrays" in out
         assert not any(k.startswith("odigos_device_bytes_in_use")
