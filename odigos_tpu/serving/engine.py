@@ -109,6 +109,9 @@ STAGE_DEVICE_METRIC = "odigos_anomaly_stage_device_ms"
 STAGE_HARVEST_METRIC = "odigos_anomaly_stage_harvest_ms"
 ADAPTIVE_CAP_GAUGE = "odigos_engine_adaptive_cap_spans"
 COALESCE_CLOSED_METRIC = "odigos_anomaly_coalesce_closed_total"
+# when a coalesced call was committed (closed, packed, enqueued): idle,
+# filled, due, blind (ScoringEngine._collect)
+COMMIT_METRIC = "odigos_anomaly_commit_total"
 # blocks applied, calls x passes x layers: a build that runs fewer passes
 # than its configuration states shows in the program's own telemetry
 LAYER_APPLICATIONS_METRIC = "odigos_anomaly_layer_applications_total"
@@ -119,17 +122,33 @@ MESH_UNAVAILABLE_METRIC = "odigos_engine_mesh_unavailable_total"
 # load shifts within ~5 calls without letting one outlier call resize
 # the next batch
 _ADAPT_ALPHA = 0.2
-# spans a packed row is counted on to hold: the mean less this many mean
-# deviations (the retransmit timer's srtt + 4 rttvar, turned round). A
-# call closed for a rung whose pack spills past it runs the next rung,
-# twice the time, so the estimate leans towards the row that holds less
-_ROW_MARGIN_DEVS = 4.0
+# the margin an estimate carries, in mean deviations (the retransmit
+# timer's srtt + 4 rttvar). Spans a packed row is counted on to hold: the
+# mean less this many (a call closed for a rung whose pack spills past it
+# runs the next rung, twice the time, so the estimate leans towards the
+# row that holds less). The lead a call is committed ahead of the chip
+# freeing: the mean pack plus this many (a pack that overruns the lead
+# leaves the chip idle for the difference)
+_MARGIN_DEVS = 4.0
+# while a call is held open for the commit point the worker wakes this
+# often, to see a stop or that the call ahead has landed already
+_HOLD_SLICE_S = 0.005
 
 
 def _ewma(old: Optional[float], new: float) -> float:
     """One step of the adaptive estimators' smoothing (None: first)."""
     return new if old is None else \
         (1 - _ADAPT_ALPHA) * old + _ADAPT_ALPHA * new
+
+
+def _ewma_dev(mean: Optional[float], dev: float,
+              new: float) -> tuple[float, float]:
+    """One step of a mean and of its mean deviation, the deviation
+    taken against the mean before the step (None: first, no deviation
+    yet)."""
+    if mean is not None:
+        dev = _ewma(dev, abs(new - mean))
+    return _ewma(mean, new), dev
 
 
 def _mesh_label(mesh_spec) -> str:
@@ -175,7 +194,11 @@ class EngineConfig:
     # request's result waits behind depth-1 device calls) without adding
     # overlap — two stages can only hide one call — so 2 is the sweet spot
     # inside the 5 ms budget (docs/architecture.md "Scoring engine
-    # pipelining").
+    # pipelining"). The depth bounds the window; WHEN the free slot is
+    # filled the engine decides call by call: at once where waiting can
+    # gain nothing, else when the running call is about to end, so that
+    # the second call is in the window for the pack's length and not for
+    # a whole step (late commit, ``ScoringEngine._collect``).
     pipeline_depth: int = 2
     bucket_ladder: int = 4      # geometric row buckets above trace_bucket
     warm_ladder: bool = False   # compile the whole ladder at start()
@@ -594,6 +617,12 @@ class SequenceBackend:
             self.variables, jnp.asarray(cat), jnp.asarray(cont),
             jnp.asarray(mask))
 
+    def ready(self, handle: Any) -> bool:
+        """Whether the enqueued call's result is there already (asked,
+        never waited for): ``fetch`` would return at once."""
+        is_ready = getattr(handle[1], "is_ready", None)
+        return bool(is_ready()) if is_ready is not None else False
+
     def fetch(self, handle: Any, call: int = -1) -> Any:
         """Harvest stage, the wait: block on the device result (the only
         blocking host<->device interaction) and nothing else. Returns
@@ -865,6 +894,12 @@ class _InflightGroup:
     # whether it was closed for one rung and packed past it
     closed: str = "drained"
     spilled: bool = False
+    # when it was committed (idle, filled, due, blind), how long it was
+    # held open after its first request, and the end the engine expected
+    # of the call ahead of it (monotonic ns; None: nothing to expect)
+    commit: str = "idle"
+    held_ms: float = 0.0
+    ahead_end_ns: Optional[int] = None
 
 
 class ScoringEngine:
@@ -1023,6 +1058,19 @@ class ScoringEngine:
         self._closed_keys = {
             reason: labeled_key(COALESCE_CLOSED_METRIC, reason=reason)
             for reason in ("drained", "rung", "cap")}
+        # what packing and enqueueing a call takes the host (pack_ms of
+        # the retired calls) and its mean deviation: the lead a call is
+        # committed ahead of the expected end of the one that runs
+        # (_lead_ms). None until a call retired: nothing to lead by
+        self._ewma_pack_ms: Optional[float] = None
+        self._ewma_pack_ms_dev = 0.0
+        # when the call just collected was committed, how long it was
+        # held open for that, and the expected end of the call ahead
+        # (worker-owned, like _closed)
+        self._commit: tuple[str, float, Optional[int]] = ("idle", 0.0, None)
+        self._commit_keys = {
+            when: labeled_key(COMMIT_METRIC, when=when)
+            for when in ("idle", "filled", "due", "blind")}
         # per-mesh step-cost learning (ISSUE 7 tentpole d): the estimate
         # is keyed by (model, mesh) so deadline-sized coalescing scales
         # with device count instead of assuming one chip — an 8-device
@@ -1288,6 +1336,8 @@ class ScoringEngine:
             "rung_ms": {r: round(ms, 3)
                         for r, ms in sorted(self._rung_ms.items())},
             "harvest_ms": round(self._ewma_harvest_ms, 4),
+            "pack_ms": self._ewma_pack_ms,
+            "lead_ms": self._lead_ms(),
             "last_cap_spans": self._last_adaptive_cap,
             "mesh": self._mesh_label,
         }
@@ -1302,11 +1352,16 @@ class ScoringEngine:
     # -------------------------------------------------------------- worker
     def _worker(self, stop: threading.Event) -> None:
         """Two-stage pipelined loop: fill the in-flight window (pack +
-        dispatch) ahead of harvesting, retire FIFO. With an empty queue the
-        window drains immediately (no latency added when there is nothing
-        to overlap with); on stop the queue and window drain losslessly.
-        ``stop`` is THIS run's event (see start()): a zombie run never
-        consults the replacement's."""
+        dispatch) ahead of harvesting, retire FIFO. With nothing in
+        flight a call is committed on its first request (the engine is
+        work-conserving, and an idle one adds no latency). While a call
+        runs, the next one is held open until the running call is about
+        to end and committed then (``_collect``): the chip executes
+        calls one after another, so an earlier commit only decides which
+        requests miss the call. With an empty queue at that point the
+        window drains; on stop the queue and window drain losslessly and
+        no call is held open. ``stop`` is THIS run's event (see
+        start()): a zombie run never consults the replacement's."""
         name_thread("odigos-engine")
         inflight: deque[_InflightGroup] = deque()
         while True:
@@ -1321,8 +1376,11 @@ class ScoringEngine:
                 if len(inflight) < self._depth:
                     with annotate("engine/collect") as collecting:
                         reqs = self._collect(
-                            block=not inflight and not stopping)
-                        collecting.set(queued=len(reqs) if reqs else 0)
+                            block=not inflight and not stopping,
+                            ahead=inflight[0] if inflight else None,
+                            stop=stop)
+                        collecting.set(queued=len(reqs) if reqs else 0,
+                                       held_ms=round(self._commit[1], 3))
                     if reqs is not None:
                         grp = self._dispatch_group(reqs,
                                                    overlapped=bool(inflight))
@@ -1368,7 +1426,10 @@ class ScoringEngine:
         except queue.Empty:
             return None
 
-    def _collect(self, block: bool) -> Optional[list[ScoreRequest]]:
+    def _collect(self, block: bool,
+                 ahead: Optional[_InflightGroup] = None,
+                 stop: Optional[threading.Event] = None,
+                 ) -> Optional[list[ScoreRequest]]:
         """Pack-stage intake: one request (blocking briefly only when the
         pipeline is idle) plus whatever else is already waiting, up to
         the call's budget (``_budget``). Where the backend has a ladder
@@ -1379,10 +1440,67 @@ class ScoringEngine:
         Backends without a ladder have no rung to fill: their budget is
         in spans and the request that reaches it closes the call. A
         laddered backend that has reported no real rows yet is budgeted
-        in spans too, and holds the request that would pass the cap."""
+        in spans too, and holds the request that would pass the cap.
+
+        Late commit: while ``ahead``, the call that runs on the device,
+        is not about to end, a call that what waits does not fill stays
+        open for what arrives, up to the commit point: the expected end
+        of ``ahead`` (``_expected_end``) less the lead that packing and
+        enqueueing take (``_lead_ms``). The call is committed at once
+        wherever waiting can gain nothing or could cost, and the span
+        says which it was (``commit.when``, one count each):
+
+        * ``idle``: nothing is in flight, or ``ahead`` was seen to have
+          landed already: the device is free;
+        * ``filled``: what waited closed the call for ``rung`` or
+          ``cap``: a backlog is dispatched as greedily as ever;
+        * ``blind``: no observed cost to expect the end of ``ahead``
+          from (a cold engine, a backend without a ladder, a rung not
+          timed yet), or the worker is stopping;
+        * ``due``: held to the commit point (which may have passed)."""
+        end = None
+        if ahead is None:
+            when = "idle"
+        elif stop is not None and stop.is_set():
+            when = "blind"
+        else:
+            end = self._expected_end(ahead)
+            when = "blind" if end is None else "due"
+        due = end - int(self._lead_ms() * 1e6) if end is not None else None
+        landed = getattr(ahead.backend, "ready", None) \
+            if due is not None else None
+
+        def more() -> Optional[ScoreRequest]:
+            """The next request for this call: one that waits, else,
+            while the call is held open, the one that arrives before
+            the commit point."""
+            nonlocal when, due
+            while True:
+                nxt = self._take(block=False)
+                if nxt is not None or due is None:
+                    return nxt
+                left = due - time.monotonic_ns()
+                if left > 0:
+                    if stop is not None and stop.is_set():
+                        when = "blind"
+                    elif landed is not None and landed(ahead.handle):
+                        when = "idle"
+                    else:
+                        try:
+                            return self._queue.get(
+                                timeout=min(left / 1e9, _HOLD_SLICE_S))
+                        except queue.Empty:
+                            continue
+                due = None
+                return None
+
         first = self._take(block)
         if first is None:
+            first = more()
+        if first is None:
+            self._commit = (when, 0.0, end)
             return None
+        t_first = time.monotonic_ns()
         reqs = [first]
         total = len(first.batch)
         cap, row_cap = self._budget(first.deadline_ns)
@@ -1393,7 +1511,7 @@ class ScoringEngine:
         if row_cap is None:
             laddered = getattr(self.backend, "ladder", None) is not None
             while total < cap:
-                nxt = self._take(block=False)
+                nxt = more()
                 if nxt is None:
                     break
                 if laddered and total + len(nxt.batch) > cap:
@@ -1413,7 +1531,7 @@ class ScoringEngine:
             per_row = self._spans_per_row()
             rung = ladder.round_rows(math.ceil(total / per_row))
             while True:
-                nxt = self._take(block=False)
+                nxt = more()
                 if nxt is None:
                     break
                 grown = total + len(nxt.batch)
@@ -1430,8 +1548,12 @@ class ScoringEngine:
                     continue
                 self._held.append(nxt)
                 break
+        if reason != "drained" and end is not None:
+            when = "filled"
         self._closed = (reason, rung)
+        self._commit = (when, (time.monotonic_ns() - t_first) / 1e6, end)
         meter.add(self._closed_keys[reason])
+        meter.add(self._commit_keys[when])
         # re-report the drained depth: watermark consumers (the wire
         # receiver's admission gate) read the CURRENT value — leaving the
         # submit-time high reading in place would keep shedding traffic
@@ -1439,6 +1561,31 @@ class ScoringEngine:
         FlowContext.watermark(f"engine/{self.cfg.model}", "queue_depth",
                               self._queued())
         return reqs
+
+    def _expected_end(self, grp: _InflightGroup) -> Optional[int]:
+        """When the call that runs is expected to end (monotonic ns):
+        from when it had the device to itself (its dispatch, or the
+        retirement of the call ahead of it where that came later: what
+        ``_retire_inner`` times a rung from), what its rung was observed
+        to cost. None where nothing was observed to expect it from: no
+        call retired yet, a backend without a ladder, a rung whose
+        calls so far were its compile."""
+        cost = self._rung_ms.get(grp.shape[0]) if grp.shape else None
+        if cost is None or self._ewma_pack_ms is None:
+            return None
+        return max(grp.t_dispatch, self._busy_until) + int(cost * 1e6)
+
+    def _lead_ms(self) -> Optional[float]:
+        """Milliseconds ahead of the running call's expected end at
+        which the next call is committed (None until a call retired):
+        what packing and enqueueing a call took, the mean plus
+        ``_MARGIN_DEVS`` mean deviations. Too long a lead costs what an
+        early commit costs (what arrives inside it waits a step); too
+        short a one leaves the chip idle for the difference."""
+        mean = self._ewma_pack_ms
+        if mean is None:
+            return None
+        return mean + _MARGIN_DEVS * self._ewma_pack_ms_dev
 
     def _climb_pays(self, rung: int, row_cap: int, total: int,
                     grown: int, taken: int) -> bool:
@@ -1471,13 +1618,13 @@ class ScoringEngine:
 
     def _spans_per_row(self) -> Optional[float]:
         """Spans a packed row is counted on to hold (None until a call
-        with real rows retired): the mean less ``_ROW_MARGIN_DEVS`` mean
+        with real rows retired): the mean less ``_MARGIN_DEVS`` mean
         deviations, and never under the one span a real row has."""
         mean = self._ewma_spans_per_row
         if not mean:
             return None
         return max(1.0, mean
-                   - _ROW_MARGIN_DEVS * self._ewma_spans_per_row_dev)
+                   - _MARGIN_DEVS * self._ewma_spans_per_row_dev)
 
     def _budget(self, deadline_ns: Optional[int]
                 ) -> tuple[int, Optional[int]]:
@@ -1687,6 +1834,7 @@ class ScoringEngine:
         for r in reqs:
             r.release_features()
         closed, rung = self._closed
+        commit, held_ms, ahead_end_ns = self._commit
         spilled = bool(real_rows is not None and rung is not None
                        and shape and shape[0] > rung)
         if spilled:
@@ -1707,7 +1855,8 @@ class ScoringEngine:
             lease=lease, backend=backend, probe=probe, fused=fused,
             attrib=attrib, span_bucket=span_bucket,
             cold_dispatch_s=cold_dispatch_s, call=call,
-            real_rows=real_rows, closed=closed, spilled=spilled)
+            real_rows=real_rows, closed=closed, spilled=spilled,
+            commit=commit, held_ms=held_ms, ahead_end_ns=ahead_end_ns)
 
     def _retire(self, grp: _InflightGroup) -> None:
         """Harvest stage: block on the oldest in-flight device call, split
@@ -1841,12 +1990,11 @@ class ScoringEngine:
             self._ewma_call_spans = _ewma(self._ewma_call_spans,
                                           float(grp.n_spans))
             if grp.real_rows and grp.shape:
-                spr = grp.n_spans / grp.real_rows
-                mean = self._ewma_spans_per_row
-                if mean is not None:
-                    self._ewma_spans_per_row_dev = _ewma(
-                        self._ewma_spans_per_row_dev, abs(spr - mean))
-                self._ewma_spans_per_row = _ewma(mean, spr)
+                (self._ewma_spans_per_row,
+                 self._ewma_spans_per_row_dev) = _ewma_dev(
+                    self._ewma_spans_per_row,
+                    self._ewma_spans_per_row_dev,
+                    grp.n_spans / grp.real_rows)
                 rung = grp.shape[0]
                 ladder = getattr(backend, "ladder", None)
                 if grp.bucket_hit is not False and ladder is not None \
@@ -1856,6 +2004,12 @@ class ScoringEngine:
                     self._rung_ms[rung] = _ewma(self._rung_ms.get(rung),
                                                 alone_ns / 1e6)
         self._ewma_harvest_ms = _ewma(self._ewma_harvest_ms, harvest_ms)
+        if grp.bucket_hit is not False:
+            # the lead the next call is committed by (_lead_ms): what this
+            # one's pack and enqueue took (a shape's first sight holds
+            # its compile, which no later call pays)
+            self._ewma_pack_ms, self._ewma_pack_ms_dev = _ewma_dev(
+                self._ewma_pack_ms, self._ewma_pack_ms_dev, pack_ms)
         if self.mesh is not None and self._adapt_key is not None:
             # publish the learned per-mesh cost so the next engine on
             # this (model geometry, mesh) starts informed (dict store is
@@ -1926,6 +2080,13 @@ class ScoringEngine:
         if grp.real_rows is not None:
             sp.set_attr("rows.real", grp.real_rows)
         sp.set_attr("coalesce.closed", grp.closed)
+        sp.set_attr("commit.when", grp.commit)
+        sp.set_attr("commit.held_ms", round(grp.held_ms, 3))
+        if grp.ahead_end_ns is not None:
+            # enqueue's end to the expected end of the call ahead:
+            # negative, the pack overran the lead and the chip waited
+            sp.set_attr("commit.slack_ms", round(
+                (grp.ahead_end_ns - grp.t_dispatch) / 1e6, 3))
         if grp.spilled:
             sp.set_attr("rung.spill", True)
         if grp.padding_waste is not None:

@@ -16,6 +16,7 @@ in-flight window. These tests pin the correctness contract of that overlap:
 
 import math
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -629,16 +630,21 @@ def test_a_cold_laddered_engine_does_not_overshoot_the_span_cap():
     eng.shutdown()
 
 
-def test_no_request_is_lost_between_submitters_worker_and_shutdown():
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_no_request_is_lost_between_submitters_worker_and_shutdown(
+        pipelined):
     """The held request is shared state (the worker holds and takes it,
     shutdown() takes it when the worker is gone): under many submitters
     and a shutdown in mid-stream every accepted request is signalled
     exactly once, scored or failed, and those scored come back in the
-    order they were accepted."""
+    order they were accepted. ``pipelined``: two calls deep on the stub
+    device, so the worker also holds calls open for the commit point
+    while the submitters and the shutdown race it."""
     import sys
     import time as _time
 
-    eng = rung_engine()
+    eng = timed_engine(_TimedBackend(step_s=0.005), lead_ms=1.0) \
+        if pipelined else rung_engine()
     eng._queue.maxsize = 0          # admission is not what is tested
     signalled = []
     accepted = [[] for _ in range(16)]
@@ -684,3 +690,306 @@ def test_no_request_is_lost_between_submitters_worker_and_shutdown():
         assert same([r for r in signalled
                      if id(r) in ids and r.scores is not None],
                     [r for r in mine if r.scores is not None])
+
+
+# ------------------- committing the next call when the chip is about to free
+
+STEP_S = 0.4        # the stub device's step, whatever the call holds
+
+
+class _TimedBackend(_RungBackend):
+    """The rung backend, pipelined, on a stub device that runs one call
+    at a time: a call's result is there ``step_s`` after the device was
+    free to start it, ``fetch`` waits for it, ``pack`` takes the host
+    ``pack_s``."""
+
+    def __init__(self, step_s=STEP_S, pack_s=0.0, can_tell_ready=True):
+        super().__init__()
+        self.step_s, self.pack_s = step_s, pack_s
+        self.free_at = 0.0
+        self.rungs = []
+        if not can_tell_ready:
+            self.ready = None
+
+    def pack(self, batch, features):
+        time.sleep(self.pack_s)
+        n = len(batch)
+        self.last_real_rows = math.ceil(n / self.per_row)
+        self.last_shape = [self.ladder.round_rows(self.last_real_rows), 64]
+        self.last_bucket_hit = True
+        return n
+
+    def enqueue(self, staged, call=-1):
+        self.free_at = max(time.monotonic(), self.free_at) + self.step_s
+        self.calls.append(staged)
+        self.rungs.append(self.last_shape and self.last_shape[0])
+        return ("stub", self.free_at, staged)
+
+    def dispatch(self, batch, features):
+        return self.enqueue(self.pack(batch, features))
+
+    def ready(self, handle):
+        return time.monotonic() >= handle[1]
+
+    def fetch(self, handle, call=-1):
+        time.sleep(max(0.0, handle[1] - time.monotonic()))
+        return handle
+
+    def harvest(self, handle):
+        return np.arange(handle[2], dtype=np.float32)
+
+    def score(self, batch, features):
+        return self.harvest(self.fetch(self.dispatch(batch, features)))
+
+
+class _LadderlessTimedBackend(_TimedBackend):
+    """... that has no ladder and reports no rows."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        del self.ladder
+
+    def pack(self, batch, features):
+        self.last_real_rows = self.last_shape = None
+        self.last_bucket_hit = None
+        return len(batch)
+
+
+def timed_engine(backend=None, learned=True, lead_ms=20.0):
+    """A depth-2 engine on the stub device that has learned, unless it
+    is to be cold, what a row holds, what the rungs cost and what a pack
+    takes."""
+    eng = ScoringEngine(EngineConfig(model="mock", max_batch_spans=32768))
+    eng.backend = backend or _TimedBackend()
+    eng._depth = 2
+    if learned:
+        eng._ewma_spans_per_row = PER_ROW
+        eng._rung_ms = {r: eng.backend.step_s * 1e3 for r in RUNG_MS}
+        eng._ewma_pack_ms = lead_ms
+    return eng
+
+
+def submit(eng, n=FRAME, **kw):
+    from odigos_tpu.features.featurizer import SpanFeatures
+
+    req = eng.submit(_Sized(n), SpanFeatures(np.zeros((n, 1), np.int32),
+                                             np.zeros((n, 1), np.float32)),
+                     **kw)
+    assert req is not None
+    return req
+
+
+def commit_counts():
+    from odigos_tpu.serving.engine import COMMIT_METRIC
+
+    return {w: meter.counter(f"{COMMIT_METRIC}{{when={w}}}")
+            for w in ("idle", "filled", "due", "blind")}
+
+
+def score_spans():
+    from odigos_tpu.selftelemetry.tracer import tracer
+
+    return [s for s in tracer.ring.snapshot() if s.name == "tpu/score"]
+
+
+def running_call(eng, frames=1):
+    """Dispatch a call by hand and leave it running on the stub: what
+    the worker has in its window when it collects the next one."""
+    enqueue(eng, [FRAME] * frames)
+    return eng._dispatch_group(eng._collect(block=False), overlapped=False)
+
+
+def test_a_request_that_arrives_while_a_call_runs_rides_the_next_call():
+    """Paced arrivals on a learned engine: the first request finds the
+    device idle and goes at once; the second is collected while that call
+    runs and is held open for the commit point, so the third, which
+    arrives in the middle of the step, rides the same call and not the
+    one after. The call is enqueued ahead of the expected end of the
+    call before it by about the lead's margin, not by a step."""
+    from odigos_tpu.selftelemetry.tracer import tracer
+
+    eng = timed_engine().start()
+    tracer.ring.drain()
+    before = commit_counts()
+    try:
+        t0 = time.monotonic()
+        first = submit(eng)
+        time.sleep(0.05)
+        second = submit(eng)
+        time.sleep(STEP_S / 2 - 0.05)
+        third = submit(eng)
+        for r in (first, second, third):
+            assert r.done.wait(10.0) and r.scores is not None
+        wall = time.monotonic() - t0
+    finally:
+        eng.shutdown()
+    assert eng.backend.calls == [FRAME, 2 * FRAME]
+    # two steps back to back, not three: the hold cost the chip nothing
+    assert 2 * STEP_S <= wall < 2.75 * STEP_S
+    one, two = score_spans()
+    assert one.attrs["commit.when"] == "idle" and one.attrs["requests"] == 1
+    assert "commit.slack_ms" not in one.attrs
+    assert two.attrs["commit.when"] == "due" and two.attrs["requests"] == 2
+    assert two.attrs["coalesce.closed"] == "drained"
+    # held from the second request's arrival to the commit point, one
+    # lead short of the running call's end
+    assert STEP_S * 1e3 / 2 - 20 < two.attrs["commit.held_ms"] \
+        <= STEP_S * 1e3 - 50 - 20 + 5
+    assert -STEP_S * 1e3 / 2 < two.attrs["commit.slack_ms"] <= 20
+    after = commit_counts()
+    assert {w: after[w] - before[w] for w in after} == \
+        {"idle": 1.0, "filled": 0.0, "due": 1.0, "blind": 0.0}
+    assert eng.pipeline_stats()["adaptive"]["lead_ms"] is not None
+
+
+def test_a_queue_that_fills_the_call_commits_at_once():
+    """A backlog: what waits fills the rung the budget allows, so the
+    call goes at once however long the call ahead still runs, and the
+    sequence of calls is what it was before the engine held any."""
+    from odigos_tpu.selftelemetry.tracer import tracer
+
+    eng = timed_engine()
+    ahead = running_call(eng)
+    reqs = enqueue(eng, [FRAME] * 20)
+    before = commit_counts()
+    t0 = time.monotonic()
+    call = eng._collect(block=False, ahead=ahead)
+    assert time.monotonic() - t0 < STEP_S / 4
+    assert same(call, reqs[:11]) and eng._closed == ("rung", 512)
+    assert eng._commit[0] == "filled" and eng._commit[2] is not None
+    assert commit_counts()["filled"] - before["filled"] == 1
+    eng._retire(ahead)
+    eng.shutdown()
+
+    # the whole loop: 33 frames wait before the worker starts
+    eng = timed_engine(_TimedBackend(step_s=0.05))
+    reqs = enqueue(eng, [FRAME] * 33)
+    tracer.ring.drain()
+    eng.start()
+    try:
+        assert all(r.done.wait(10.0) for r in reqs)
+    finally:
+        eng.shutdown()
+    assert eng.backend.calls == [11 * FRAME] * 3
+    assert eng.backend.rungs == [512] * 3
+    spans = score_spans()
+    assert [s.attrs["coalesce.closed"] for s in spans] == \
+        ["rung", "rung", "drained"]
+    assert [s.attrs["commit.when"] for s in spans][:2] == ["idle", "filled"]
+
+
+@pytest.mark.parametrize("case", ["cold", "ladderless", "rung_not_timed",
+                                  "stopping"])
+def test_with_nothing_to_expect_the_end_from_the_call_commits_at_once(case):
+    """``blind``: a cold engine, a backend without a ladder, a running
+    call whose rung has no observed cost, a worker that is stopping."""
+    stop = threading.Event()
+    if case == "cold":
+        eng = timed_engine(learned=False)
+    elif case == "ladderless":
+        eng = timed_engine(_LadderlessTimedBackend())
+        eng._ewma_spans_per_row = None
+    else:
+        eng = timed_engine()
+    ahead = running_call(eng)
+    assert ahead.shape == (None if case == "ladderless" else [256, 64])
+    if case == "rung_not_timed":
+        del eng._rung_ms[256]
+    if case == "stopping":
+        stop.set()
+    reqs = enqueue(eng, [FRAME])
+    before = commit_counts()
+    t0 = time.monotonic()
+    call = eng._collect(block=False, ahead=ahead, stop=stop)
+    assert time.monotonic() - t0 < STEP_S / 4
+    assert same(call, reqs) and eng._commit[0] == "blind"
+    assert commit_counts()["blind"] - before["blind"] == 1
+    # ... and with nothing waiting the window drains, as ever
+    t0 = time.monotonic()
+    assert eng._collect(block=False, ahead=ahead, stop=stop) is None
+    assert time.monotonic() - t0 < STEP_S / 4
+    eng._retire(ahead)
+    eng.shutdown()
+
+
+def test_with_nothing_in_flight_the_first_request_commits_the_call():
+    eng = timed_engine()
+    reqs = enqueue(eng, [FRAME] * 2)
+    before = commit_counts()
+    t0 = time.monotonic()
+    assert same(eng._collect(block=True), reqs)
+    assert time.monotonic() - t0 < STEP_S / 4
+    assert eng._commit[0] == "idle" and eng._commit[2] is None
+    assert commit_counts()["idle"] - before["idle"] == 1
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("can_tell_ready, when", [(True, "idle"),
+                                                  (False, "due")])
+def test_an_end_expected_too_late_holds_no_longer_than_the_call_runs(
+        can_tell_ready, when):
+    """The rung was timed at ten times what the running call takes: a
+    backend that can say its result is there ends the hold when it is
+    (the device is idle); one that cannot is held to the commit point."""
+    eng = timed_engine(_TimedBackend(step_s=0.1,
+                                     can_tell_ready=can_tell_ready))
+    eng._rung_ms[256] = 1000.0
+    ahead = running_call(eng)
+    reqs = enqueue(eng, [FRAME])
+    t0 = time.monotonic()
+    call = eng._collect(block=False, ahead=ahead, stop=threading.Event())
+    took = time.monotonic() - t0
+    assert same(call, reqs) and eng._commit[0] == when
+    assert (0.05 < took < 0.5) if can_tell_ready else (0.9 < took < 1.5)
+    eng._retire(ahead)
+    eng.shutdown()
+
+
+def test_a_pack_slower_than_the_lead_loses_nothing_and_lengthens_it():
+    """The lead was learned at 2 ms and a pack takes 60: the call is
+    enqueued after the one ahead ended (negative slack), every request
+    is scored, in order, and the lead grows."""
+    from odigos_tpu.selftelemetry.tracer import tracer
+
+    eng = timed_engine(_TimedBackend(step_s=0.15, pack_s=0.06), lead_ms=2.0)
+    lead0 = eng._lead_ms()
+    signalled = []
+    tracer.ring.drain()
+    eng.start()
+    try:
+        reqs = []
+        for _ in range(6):
+            reqs.append(submit(eng, on_done=signalled.append))
+            time.sleep(0.06)
+        assert all(r.done.wait(10.0) for r in reqs)
+    finally:
+        eng.shutdown()
+    assert same(signalled, reqs)
+    assert all(r.scores is not None and len(r.scores) == FRAME
+               for r in reqs)
+    assert sum(eng.backend.calls) == 6 * FRAME
+    held = [s for s in score_spans() if s.attrs["commit.when"] == "due"]
+    assert held and held[0].attrs["commit.slack_ms"] < 0
+    assert eng._lead_ms() > lead0 + 10
+
+
+def test_shutdown_during_a_hold_drains_at_once_and_loses_nothing():
+    """A call is held open for a commit point seconds away (the rung was
+    timed far too long, and the stub cannot say its result is there):
+    shutdown() does not wait the hold out, and what was held, what was
+    queued and what was in flight are all scored."""
+    eng = timed_engine(_TimedBackend(step_s=0.1, can_tell_ready=False))
+    eng._rung_ms[256] = 20_000.0
+    eng.start()
+    reqs = [submit(eng)]
+    time.sleep(0.03)
+    reqs.append(submit(eng))         # held open behind the first call
+    time.sleep(0.03)
+    t0 = time.monotonic()
+    reqs.append(submit(eng))
+    eng.shutdown()
+    assert time.monotonic() - t0 < 2.0
+    assert all(r.done.is_set() and r.scores is not None
+               and len(r.scores) == FRAME for r in reqs)
+    assert sum(eng.backend.calls) == 3 * FRAME
+    assert not eng._held and eng._queued() == 0
