@@ -1,8 +1,12 @@
-"""Shared flax modules: span embedding trunk + the transformer's two block
-kinds (``BLOCK_PARTS``): the pre-LN bidirectional encoder block under a
-learned position table, and the decoder block (sandwich RMS norms, rotary
+"""Shared flax modules: span embedding trunk + the transformer's three
+block kinds (``BLOCK_PARTS``): the pre-LN bidirectional encoder block under
+a learned position table; the decoder block (sandwich RMS norms, rotary
 positions, causal attention within a trace, SwiGLU, no biases) whose stack
-runs ``passes`` times over the same parameters as a loop on the device.
+runs ``passes`` times over the same parameters as a loop on the device; and
+the routed block (pre-norm residuals, grouped query heads over fewer
+key/value heads, rotary positions and a window layer by layer, a router
+ahead of attention that sends each span to ``experts_per_span`` of
+``n_experts`` ReLU-gated experts, parameters held in bfloat16).
 
 MXU discipline (see /opt/skills/guides/pallas_guide.md and SURVEY.md env
 notes): feature dims multiples of 128, bfloat16 activations with float32
@@ -12,11 +16,13 @@ einsums that XLA tiles onto the systolic array.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.sharding import PartitionSpec
 
 from ..features.featurizer import CAT_FIELDS
 
@@ -35,27 +41,36 @@ class SpanEmbedder(nn.Module):
     attr_vocab: int
     d_model: int
     dtype: Any = jnp.bfloat16
+    # how the tables and the continuous projection are drawn: flax's own
+    # defaults, under which a wide model's input is the projection of the
+    # log-duration column alone (a table's row has variance 1 / d_model an
+    # element, the projection's a third of a column that reads 3 to 10).
+    # A block that routes on its raw input draws them otherwise
+    # (``MoeDecoder``).
+    table_init: Any = nn.linear.default_embed_init
+    cont_init: Any = nn.linear.default_kernel_init
 
     @nn.compact
     def __call__(self, categorical: jnp.ndarray,
                  continuous: jnp.ndarray) -> jnp.ndarray:
         d = self.d_model
-        svc_table = nn.Embed(self.service_vocab, d, dtype=self.dtype,
-                             name="service_embed")
+
+        def table(rows: int, name: str) -> nn.Embed:
+            return nn.Embed(rows, d, dtype=self.dtype, name=name,
+                            embedding_init=self.table_init)
+
+        svc_table = table(self.service_vocab, "service_embed")
         x = svc_table(categorical[..., 0])
-        x += nn.Embed(self.name_vocab, d, dtype=self.dtype,
-                      name="name_embed")(categorical[..., 1])
-        x += nn.Embed(8, d, dtype=self.dtype,
-                      name="kind_embed")(categorical[..., 2])
-        x += nn.Embed(4, d, dtype=self.dtype,
-                      name="status_embed")(categorical[..., 3])
+        x += table(self.name_vocab, "name_embed")(categorical[..., 1])
+        x += table(8, "kind_embed")(categorical[..., 2])
+        x += table(4, "status_embed")(categorical[..., 3])
         x += svc_table(categorical[..., 4])  # parent edge, shared table
         n_attr = categorical.shape[-1] - len(CAT_FIELDS)
         if n_attr > 0:
-            attr_table = nn.Embed(self.attr_vocab, d, dtype=self.dtype,
-                                  name="attr_embed")
+            attr_table = table(self.attr_vocab, "attr_embed")
             x += attr_table(categorical[..., len(CAT_FIELDS):]).sum(axis=-2)
-        x += nn.Dense(d, dtype=self.dtype, name="cont_proj")(
+        x += nn.Dense(d, dtype=self.dtype, name="cont_proj",
+                      kernel_init=self.cont_init)(
             continuous.astype(self.dtype))
         return x
 
@@ -103,9 +118,14 @@ PARTS = ("embed", "attn_mask", "attn", "mlp", "final_norm", "head")
 # block's RMS norms (four a block, one closing each pass) are a part of
 # their own, ``norm``: element-wise and bandwidth-bound, 4 n_layers + 1 a
 # pass, where the encoder's LayerNorms sit inside ``attn`` and ``mlp``.
+# The routed block adds ``route``: the router's product, the top-k and its
+# softmax, the sort by expert, the gather into expert order and the
+# weighted combine back; its ``mlp`` is the experts' grouped products, the
+# ReLU and the gate's multiply alone.
 BLOCK_PARTS = {
     "encoder": PARTS,
     "decoder": ("embed", "attn_mask", "attn", "mlp", "norm", "head"),
+    "moe": ("embed", "attn_mask", "attn", "route", "mlp", "norm", "head"),
 }
 
 
@@ -175,6 +195,32 @@ def rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
+def causal_mask(mask: jnp.ndarray, positions: jnp.ndarray,
+                segments: jnp.ndarray | None) -> jnp.ndarray:
+    """(T, L, L) bool: span i may attend to span j where both are real,
+    j is no later than i in its trace and (packed rows) both are of one
+    trace."""
+    allowed = mask[..., None] & mask[..., None, :] \
+        & (positions[..., None] >= positions[..., None, :])
+    if segments is not None:
+        allowed &= segments[..., None] == segments[..., None, :]
+    return allowed
+
+
+def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+              mask: jnp.ndarray, dtype: Any) -> jnp.ndarray:
+    """Dot-product attention of both decoder kinds, the softmax in
+    float32: ``q`` (..., L, heads, head_dim), ``k`` and ``v`` (..., L,
+    kv_heads, head_dim). With fewer key/value heads than query heads,
+    query head g reads key/value head g // (heads // kv_heads)."""
+    group = q.shape[-2] // k.shape[-2]
+    if group > 1:
+        k, v = (jnp.repeat(u, group, axis=-2) for u in (k, v))
+    return nn.dot_product_attention(
+        q, k, v, mask=mask, deterministic=True, dtype=dtype,
+        force_fp32_for_softmax=True)
+
+
 class DecoderBlock(nn.Module):
     """Decoder block with sandwich norms: an RMS norm before and after each
     sublayer, rotary positions on queries and keys, SwiGLU feed-forward, no
@@ -206,9 +252,7 @@ class DecoderBlock(nn.Module):
             k = rotate(dense(self.d_model, "k_proj")(h).reshape(heads),
                        cos, sin)
             v = dense(self.d_model, "v_proj")(h).reshape(heads)
-            h = nn.dot_product_attention(
-                q, k, v, mask=attn_mask, deterministic=True,
-                dtype=self.dtype, force_fp32_for_softmax=True)
+            h = attention(q, k, v, attn_mask, self.dtype)
             h = dense(self.d_model, "o_proj")(h.reshape(x.shape))
         x = x + norm("attn_out_norm", h)
         h = norm("mlp_norm", x)
@@ -276,11 +320,7 @@ class LoopedDecoder(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(mask.shape[-1]),
                                          mask.shape)
         with jax.named_scope("attn_mask"):
-            attn_mask = mask[..., None] & mask[..., None, :] \
-                & (positions[..., None] >= positions[..., None, :])
-            if segments is not None:
-                attn_mask &= segments[..., None] == segments[..., None, :]
-            attn_mask = attn_mask[:, None]
+            attn_mask = causal_mask(mask, positions, segments)[:, None]
         with jax.named_scope("attn"):
             cos, sin = rotary_tables(positions, self.d_model // self.n_heads,
                                      self.rope_theta, self.dtype)
@@ -296,3 +336,272 @@ class LoopedDecoder(nn.Module):
                     self.dtype, self.norm_eps, name="stack")(
             x, attn_mask, cos, sin)
         return x
+
+
+def rounded_lecun(batch_axis: tuple[int, ...] = ()):
+    """Lecun-normal over the kernel's own fan-in, drawn in float32 and
+    rounded once to the dtype the parameter is held in: the same numbers
+    whatever that dtype is, where a draw in bfloat16 is another stream.
+    ``batch_axis`` names the axes that count no fan (an expert axis)."""
+    draw = nn.initializers.variance_scaling(
+        1.0, "fan_in", "truncated_normal", batch_axis=batch_axis)
+
+    def init(key, shape, dtype=jnp.float32):
+        return draw(key, shape, jnp.float32).astype(dtype)
+
+    return init
+
+
+class ExpertKernel(nn.Module):
+    """One kernel for each expert, (n_experts, fan_in, fan_out), under the
+    parameter path a ``Dense`` of that name would have."""
+
+    shape: tuple[int, int, int]
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self) -> jnp.ndarray:
+        return self.param("kernel", rounded_lecun(batch_axis=(0,)),
+                          self.shape, self.param_dtype)
+
+
+# rows a tile of the grouped kernels: the assignments are padded up to
+# whole tiles (rows that belong to no expert)
+GROUP_ROWS = 512
+
+
+def _experts_ragged(x, gate, up, down, load):
+    """The experts' three grouped products over rows sorted by expert,
+    ``load[e]`` rows for expert e: relu(x Wg) * (x Wu), then Wd."""
+    y = nn.relu(jax.lax.ragged_dot(x, gate, load)) \
+        * jax.lax.ragged_dot(x, up, load)
+    return jax.lax.ragged_dot(y, down, load)
+
+
+def _experts_gmm(x, gate, up, down, load, interpret: bool = False):
+    """The same three products as Pallas grouped-matmul kernels
+    (``megablox.gmm``), which the TPU runs at two thirds of its peak where
+    the kernel XLA makes of ``ragged_dot`` ran at 42% (v5e, 196,608 rows x
+    2560 x 768; PERF.md section 6, PR 34), and which keeps its scope in
+    the device trace. It visits the tiles that hold an expert's rows and
+    no others: rows past the last expert are left as they were."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    def product(lhs, rhs):
+        tiles = (GROUP_ROWS,) + tuple(min(n, 1280) for n in rhs.shape[1:])
+        return gmm(lhs, rhs, load, preferred_element_type=lhs.dtype,
+                   tiling=tiles, interpret=interpret)
+
+    return product(nn.relu(product(x, gate)) * product(x, up), down)
+
+
+def routed_experts(h: jnp.ndarray, logits: jnp.ndarray, real: jnp.ndarray,
+                   gate: jnp.ndarray, up: jnp.ndarray, down: jnp.ndarray,
+                   k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed feed-forward over ``h`` (spans, d): each real span
+    goes to the ``k`` experts of its largest ``logits`` (spans,
+    n_experts; float32), weighted by the softmax over those k. The
+    experts run as grouped products over the assignments sorted by
+    expert: no span is dropped and there is no capacity. A slot that
+    holds no span (``real`` False) is sorted past the last expert, so
+    it enters no product, and comes back zero. Returns (spans, d) and
+    the assignments each expert took, (n_experts,) int32."""
+    spans, n_experts = logits.shape
+    with jax.named_scope("route"):
+        top, which = jax.lax.top_k(logits, k)
+        weight = jax.nn.softmax(top, axis=-1) * real[:, None]
+        expert = jnp.where(real[:, None], which, n_experts).reshape(-1)
+        order = jnp.argsort(expert, stable=True)
+        load = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0,
+                       dtype=jnp.int32)
+        whole = -order.shape[0] % GROUP_ROWS      # up to whole tiles
+        sorted_h = h[jnp.pad(order, (0, whole)) // k]
+    with jax.named_scope("mlp"):
+        y = jax.lax.platform_dependent(sorted_h, gate, up, down, load,
+                                       tpu=_experts_gmm,
+                                       default=_experts_ragged)
+    with jax.named_scope("route"):
+        # back to span order with the k-th choices as the leading axis,
+        # (k, spans, d): summed over whole slabs, where (spans, k, d)
+        # would pad k up to a tile's 8 rows
+        y = y[jnp.argsort(order).reshape(spans, k).T]
+        # a row past the last expert's holds whatever the grouped product
+        # left there: its weight is zero, and it is taken out whole so
+        # that it cannot be a NaN either
+        y = jnp.where(real[None, :, None], y, 0).astype(jnp.float32)
+        out = jnp.sum(y * weight.T[:, :, None], axis=0)
+    return out.astype(h.dtype), load
+
+
+# the mesh axis a plan splits the packed rows over (parallel/sharding.py)
+ROWS_AXIS = "data"
+
+
+def each_device_its_rows(route):
+    """``route(h, logits, real, gate, up, down) -> (out, load)`` run by
+    each device on its own rows where the mesh in context splits the rows
+    (a plan traces its call inside its mesh): the grouped products are
+    Pallas kernels on the TPU, which the partitioner cannot split
+    ("Mosaic kernels cannot be automatically partitioned"), and a span's
+    experts need no other device's rows, the kernels being whole on every
+    device. The loads are summed over the devices. With no mesh in
+    context, or one device along the axis, ``route`` itself."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get(ROWS_AXIS, 1) == 1:
+        return route
+
+    def local(*args):
+        out, load = route(*args)
+        return out, jax.lax.psum(load, ROWS_AXIS)
+
+    rows, whole = PartitionSpec(ROWS_AXIS), PartitionSpec()
+    return jax.shard_map(local, in_specs=(rows,) * 3 + (whole,) * 3,
+                         out_specs=(rows, whole), check_vma=False)
+
+
+class MoeBlock(nn.Module):
+    """Routed decoder block: pre-norm residuals (two RMS norms), grouped
+    query heads, rotary positions where ``rope`` says, and in place of a
+    dense feed-forward ``n_experts`` ReLU-gated experts of width
+    ``d_expert`` of which a span takes ``experts_per_span``. The router
+    reads the block's raw input, ahead of the norm and the attention; its
+    product, the top-k and the softmax over the chosen run in float32.
+    No bias anywhere; parameters are held in ``param_dtype``."""
+
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    n_experts: int
+    experts_per_span: int
+    d_expert: int
+    rope: bool
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, mask: jnp.ndarray,
+                 attn_mask: jnp.ndarray, cos: jnp.ndarray,
+                 sin: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+        def dense(features: int, name: str, dtype: Any = self.dtype,
+                  **kw) -> nn.Dense:
+            return nn.Dense(features, use_bias=False, dtype=dtype,
+                            param_dtype=self.param_dtype,
+                            kernel_init=rounded_lecun(), name=name, **kw)
+
+        def norm(name: str, h: jnp.ndarray) -> jnp.ndarray:
+            with jax.named_scope("norm"):
+                return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                                  param_dtype=self.param_dtype,
+                                  name=name)(h)
+
+        with jax.named_scope("route"):
+            logits = dense(self.n_experts, "router", jnp.float32,
+                           precision=jax.lax.Precision.HIGHEST)(x)
+        h = norm("attn_norm", x)
+        with jax.named_scope("attn"):
+            q_shape = x.shape[:-1] + (self.n_heads, self.head_dim)
+            kv_shape = x.shape[:-1] + (self.n_kv_heads, self.head_dim)
+            q = dense(self.n_heads * self.head_dim, "q_proj")(h)
+            k = dense(self.n_kv_heads * self.head_dim, "k_proj")(h)
+            v = dense(self.n_kv_heads * self.head_dim, "v_proj")(h)
+            q, k = q.reshape(q_shape), k.reshape(kv_shape)
+            if self.rope:
+                q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+            h = attention(q, k, v.reshape(kv_shape), attn_mask, self.dtype)
+            h = dense(self.d_model, "o_proj")(
+                h.reshape(x.shape[:-1] + (self.n_heads * self.head_dim,)))
+        x = x + h
+        h = norm("mlp_norm", x)
+        kernels = [ExpertKernel((self.n_experts, a, b), self.param_dtype,
+                                name=name)().astype(self.dtype)
+                   for name, a, b in (
+                       ("experts_gate", self.d_model, self.d_expert),
+                       ("experts_up", self.d_model, self.d_expert),
+                       ("experts_down", self.d_expert, self.d_model))]
+        h, load = each_device_its_rows(
+            partial(routed_experts, k=self.experts_per_span))(
+            h.reshape(-1, self.d_model),
+            logits.reshape(-1, self.n_experts), mask.reshape(-1),
+            *kernels)
+        return x + h.reshape(x.shape), load
+
+
+# How the routed stack's embedder is drawn: each table at unit variance
+# an element (a lookup is a product with a one-hot row, one input active),
+# the continuous projection at unit norm a column (variance 1 / d_model an
+# element). A router reads its layer's raw input and a top-k is blind to a
+# positive scale: under flax's defaults that input is one direction (the
+# log-duration column's) scaled by each span's log-duration, and every
+# span of a call takes the same experts in every layer (PERF.md section
+# 6, PR 34). Drawn so, a span's identity (service, operation, kind,
+# status, parent) leads its input, as a token's does in a language model.
+ROUTED_EMBED_INITS = (
+    nn.initializers.normal(1.0),
+    nn.initializers.variance_scaling(1.0, "fan_out", "normal"))
+
+
+class MoeDecoder(nn.Module):
+    """Embedding trunk + a stack of routed blocks, each applied once.
+    ``rope_layout`` and ``window_layout`` say layer by layer whether the
+    block rotates its queries and keys and whether its attention is cut to
+    the last ``window`` spans of the trace: the two masks are built once
+    and each block takes its own. Attention is causal within a trace, as
+    the looped stack's. Returns the normed output and, (n_layers,
+    n_experts), the assignments each expert of each layer took."""
+
+    service_vocab: int
+    name_vocab: int
+    attr_vocab: int
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    n_experts: int
+    experts_per_span: int
+    d_expert: int
+    rope_layout: tuple[int, ...]
+    window_layout: tuple[int, ...]
+    window: int
+    rope_theta: float
+    norm_eps: float
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, categorical, continuous, mask,
+                 deterministic: bool = True,
+                 positions: jnp.ndarray | None = None,
+                 segments: jnp.ndarray | None = None):
+        with jax.named_scope("embed"):
+            x = SpanEmbedder(self.service_vocab, self.name_vocab,
+                             self.attr_vocab, self.d_model, self.dtype,
+                             *ROUTED_EMBED_INITS,
+                             name="embed")(categorical, continuous)
+            x = x * mask[..., None].astype(self.dtype)
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(mask.shape[-1]),
+                                         mask.shape)
+        with jax.named_scope("attn_mask"):
+            whole = causal_mask(mask, positions, segments)
+            near = positions[..., None] - positions[..., None, :] \
+                < self.window
+            masks = (whole[:, None], (whole & near)[:, None])
+        with jax.named_scope("attn"):
+            cos, sin = rotary_tables(positions, self.head_dim,
+                                     self.rope_theta, self.dtype)
+        loads = []
+        for i, (rope, window) in enumerate(zip(self.rope_layout,
+                                               self.window_layout)):
+            x, load = MoeBlock(
+                self.d_model, self.n_heads, self.n_kv_heads, self.head_dim,
+                self.n_experts, self.experts_per_span, self.d_expert,
+                bool(rope), self.dtype, self.param_dtype, self.norm_eps,
+                name=f"block_{i}")(x, mask, masks[bool(window)], cos, sin)
+            loads.append(load)
+        with jax.named_scope("norm"):
+            x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           param_dtype=self.param_dtype,
+                           name="final_rms")(x)
+        return x, jnp.stack(loads)

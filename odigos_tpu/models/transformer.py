@@ -8,6 +8,15 @@ in bfloat16, data-parallel across the mesh (odigos_tpu.parallel).
 
 Default dims are MXU-shaped: d_model 256, d_ff 1024, heads 4 — all multiples
 of the 128-lane tile.
+
+``TransformerConfig.block`` picks one of three block kinds (``layers.py``
+``BLOCK_PARTS``): the default pre-LN bidirectional ``encoder``; the
+``decoder`` block whose stack is looped ``passes`` times on the device; and
+the routed ``moe`` block (grouped query heads, rotary positions and a
+window layer by layer, a router ahead of attention over ``n_experts``
+experts of which a span takes ``experts_per_span``, parameters held in
+``param_dtype``), whose ``score_packed_counted`` also returns what the
+router assigned.
 """
 
 from __future__ import annotations
@@ -21,13 +30,15 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from . import jitstats
-from .layers import BLOCK_PARTS, Encoder, LoopedDecoder
+from .layers import BLOCK_PARTS, Encoder, LoopedDecoder, MoeDecoder
 
 # Shape-bucketing strategy per jitted scoring entry point (the package
 # hygiene test asserts every jit path in models/ and parallel/ declares
 # one — an undeclared path is an unbounded-recompile hazard at serving
 # rates). Values are documentation; the mechanisms live where named.
 SHAPE_BUCKETING = {
+    "score_packed_counted": "the routed block's score_packed with its "
+                            "counts: the same rows, the same ladder",
     "score_spans": "leading trace axis padded by the engine's BucketLadder "
                    "(serving.engine) or a fixed trace_bucket multiple; "
                    "L/C fixed by TransformerConfig",
@@ -35,6 +46,11 @@ SHAPE_BUCKETING = {
                     "(geometric ladder over trace_bucket, warmed at "
                     "engine start); L/C fixed by TransformerConfig",
 }
+
+
+# expert assignments of real spans, as a routed model's own top-k counted
+# them on the device (spans x experts a span x layers a call)
+EXPERT_ASSIGNMENTS_METRIC = "odigos_anomaly_expert_assignments_total"
 
 
 @dataclass(frozen=True)
@@ -54,25 +70,107 @@ class TransformerConfig:
     # rotary positions (no table: max_len bounds the row, not the model),
     # causal within a trace, SwiGLU, no biases, the stack of n_layers run
     # ``passes`` times over the same parameters with the final norm
-    # closing every pass. The three keys below are the decoder block's.
+    # closing every pass. "moe": the routed block (pre-norm RMS residuals,
+    # causal within a trace, no biases, each layer applied once): n_heads
+    # query heads of head_dim over n_kv_heads key/value heads, a router
+    # ahead of attention sending each span to experts_per_span of
+    # n_experts ReLU-gated experts d_expert wide (d_ff is unused), layer i
+    # rotating its queries and keys where rope_layout[i] and cutting its
+    # attention to the last ``window`` spans where window_layout[i], the
+    # parameters held in param_dtype. passes is the decoder block's;
+    # rope_theta and norm_eps are the decoder and the routed block's; the
+    # keys from n_kv_heads down are the routed block's alone.
     block: str = "encoder"
     passes: int = 1
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    n_experts: int = 0
+    experts_per_span: int = 0
+    d_expert: int = 0
+    rope_layout: tuple[int, ...] = ()
+    window_layout: tuple[int, ...] = ()
+    window: int = 0
+    param_dtype: Any = jnp.float32
 
     def __post_init__(self) -> None:
         if self.block not in BLOCK_PARTS:
             raise ValueError(f"unknown block kind {self.block!r} "
                              f"(known: {sorted(BLOCK_PARTS)})")
-        if self.passes < 1 or (self.block == "encoder" and self.passes != 1):
+        if self.passes < 1 or (self.block != "decoder" and self.passes != 1):
             raise ValueError(f"passes {self.passes!r} with block "
                              f"{self.block!r}: only the decoder block's "
                              f"stack is looped, and at least once")
+        for key in ("rope_layout", "window_layout"):  # hashable from JSON
+            object.__setattr__(self, key, tuple(getattr(self, key)))
+        if self.block != "moe":
+            stray = {k: getattr(self, k) for k in _ROUTED_KEYS
+                     if getattr(self, k) != getattr(type(self), k)}
+            if stray:
+                raise ValueError(f"{stray} with block {self.block!r}: "
+                                 f"these keys are the routed block's")
+            return
+        faults = []
+        if not (self.n_kv_heads > 0 and self.head_dim > 0
+                and self.n_heads % max(self.n_kv_heads, 1) == 0):
+            faults.append(f"n_heads {self.n_heads} is not a multiple of "
+                          f"n_kv_heads {self.n_kv_heads}, or head_dim "
+                          f"{self.head_dim} is not set")
+        if self.head_dim % 2:
+            faults.append(f"head_dim {self.head_dim} is odd: rotary "
+                          f"positions pair its columns")
+        if not 0 < self.experts_per_span <= self.n_experts \
+                or self.d_expert < 1:
+            faults.append(f"experts_per_span {self.experts_per_span} of "
+                          f"n_experts {self.n_experts}, d_expert "
+                          f"{self.d_expert}")
+        if not len(self.rope_layout) == len(self.window_layout) \
+                == self.n_layers:
+            faults.append(f"rope_layout ({len(self.rope_layout)}) and "
+                          f"window_layout ({len(self.window_layout)}) "
+                          f"each state all n_layers {self.n_layers}")
+        if any(self.window_layout) and self.window < 1:
+            faults.append(f"window {self.window} with a layer that has one")
+        if faults:
+            raise ValueError("block 'moe' does not compose: "
+                             + "; ".join(faults))
 
     @property
     def layer_applications(self) -> int:
         """Blocks a span passes through in one scoring call."""
         return self.passes * self.n_layers
+
+    @property
+    def span_attrs(self) -> dict[str, Any]:
+        """What every ``tpu/score`` span says of the model behind the
+        call: the block kind and how many blocks a span passes through;
+        of the routed block also its experts, how many a span takes, and
+        how many layers rotate and how many have a window."""
+        attrs = {"model.block": self.block, "model.passes": self.passes,
+                 "model.layer_applications": self.layer_applications}
+        if self.block == "moe":
+            attrs.update({
+                "model.experts": self.n_experts,
+                "model.experts_per_span": self.experts_per_span,
+                "model.layers_rotary": sum(map(bool, self.rope_layout)),
+                "model.layers_window": sum(map(bool, self.window_layout))})
+        return attrs
+
+    @property
+    def call_counters(self) -> dict[str, str]:
+        """Which of the numbers a call counts on the device
+        (``score_packed_counted``, by span attribute name) is added to
+        which counter; nothing for a block that counts nothing."""
+        if self.block == "moe":
+            return {"moe.assignments": EXPERT_ASSIGNMENTS_METRIC}
+        return {}
+
+
+# the routed block's own keys, refused under the two other blocks
+_ROUTED_KEYS = ("n_kv_heads", "head_dim", "n_experts", "experts_per_span",
+                "d_expert", "rope_layout", "window_layout", "window",
+                "param_dtype")
 
 
 class _TraceTransformerModule(nn.Module):
@@ -86,13 +184,26 @@ class _TraceTransformerModule(nn.Module):
             backbone = Encoder(c.service_vocab, c.name_vocab, c.attr_vocab,
                                c.d_model, c.n_heads, c.n_layers, c.d_ff,
                                c.max_len, c.dtype, name="encoder")
-        else:
+        elif c.block == "decoder":
             backbone = LoopedDecoder(
                 c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
                 c.n_heads, c.n_layers, c.d_ff, c.passes, c.rope_theta,
                 c.norm_eps, c.dtype, name="encoder")
+        else:
+            backbone = MoeDecoder(
+                c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
+                c.n_heads, c.n_kv_heads, c.head_dim, c.n_experts,
+                c.experts_per_span, c.d_expert, c.rope_layout,
+                c.window_layout, c.window, c.rope_theta, c.norm_eps,
+                c.dtype, c.param_dtype, name="encoder")
         h = backbone(categorical, continuous, mask, deterministic,
                      positions=positions, segments=segments)
+        if c.block == "moe":
+            # (n_layers, n_experts) assignments of the call, for whoever
+            # applies with the collection mutable (score_packed_counted)
+            h, load = h
+            if not self.is_initializing():    # params alone are variables
+                self.sow("moe", "load", load)
         with jax.named_scope("head"):
             span_logit = nn.Dense(1, dtype=jnp.float32,
                                   name="span_head")(h)[..., 0]
@@ -123,6 +234,16 @@ class TraceTransformer:
         score_packed = jax.jit(self._score_packed_impl)
         self.score_packed = jitstats.track_jit("transformer.score_packed",
                                                score_packed)
+        # a block that counts on the device what a call did (the routed
+        # block: assignments by layer and expert) has a second entry that
+        # returns the counts beside the scores, one program and one fetch;
+        # None for a block that counts nothing. The engine runs this one
+        # where there is one.
+        self.score_packed_counted = None
+        if self.cfg.block == "moe":
+            score_packed_counted = jax.jit(self._score_packed_counted_impl)
+            self.score_packed_counted = jitstats.track_jit(
+                "transformer.score_packed_counted", score_packed_counted)
 
     def init(self, rng: jax.Array, sample_cat=None, sample_cont=None,
              sample_mask=None):
@@ -159,6 +280,25 @@ class TraceTransformer:
             positions=positions, segments=segments)
         with jax.named_scope("head"):
             return jax.nn.sigmoid(span_logit)
+
+    def _score_packed_counted_impl(self, variables, categorical, continuous,
+                                   segments, positions):
+        """``score_packed`` and, from the router's own top-k, what the
+        call's real spans were assigned: how many assignments in all
+        (spans x experts_per_span x n_layers) and the busiest (layer,
+        expert)'s over the mean one's, under the names they have on the
+        ``tpu/score`` span."""
+        mask = segments > 0
+        (span_logit, _), state = self.module.apply(
+            variables, categorical, continuous, mask,
+            positions=positions, segments=segments, mutable=["moe"])
+        (load,) = state["moe"]["load"]
+        with jax.named_scope("head"):
+            total = load.sum()
+            return jax.nn.sigmoid(span_logit), {
+                "moe.assignments": total,
+                "moe.load_max_over_mean":
+                    load.max() * load.size / jnp.maximum(total, 1)}
 
     def loss_fn(self, variables, categorical, continuous, mask,
                 span_labels, trace_labels, rngs=None):

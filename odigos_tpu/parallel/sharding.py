@@ -10,6 +10,13 @@ over the mesh from parallel.mesh:
 * decoder block: q/k/v (d_model, heads x head_dim) and gate/up (d_model,
   d_ff) columns on "model"; out and down rows on "model"; RMS norms
   replicated
+* routed block: q/k/v/out as the decoder block's; the router and the
+  three expert kernels (n_experts, fan_in, fan_out) whole on every device,
+  and a mesh with a "model" axis over 1 refused (``WHOLE_ONLY``) where the
+  plan is built. Under ``mesh {data: n}`` each device routes its own rows
+  (``layers.each_device_its_rows``, which asks for the mesh the plan
+  traces its call inside): held by a test on four virtual CPU devices
+  and a compile for a described v5e 2 x 2, not yet run on four chips
 * autoencoder decoder ffn + wide vocab heads: d_ff / vocab on "model"
 * embedding tables + layernorms + small heads: replicated
 * batch (packed-row / trace) axis of inputs: "data"
@@ -91,6 +98,11 @@ PARTITION_SPECS = {
 
 # ------------------------------------------------------ partition rules
 
+# parameters that only ever sit whole on a device: a plan whose mesh has
+# a "model" axis over 1 refuses a model that holds any (the rule that
+# places them, below, says why)
+WHOLE_ONLY = r"block_\d+/(router|experts_(gate|up|down))/kernel$"
+
 # First-match-wins (re.search over the '/'-joined param path). The
 # catch-all replicates embeddings, norms, biases, and small heads —
 # sharding those only buys per-call collectives. Param names cover BOTH
@@ -105,11 +117,48 @@ PARTITION_RULES: tuple[tuple[str, P], ...] = (
     # gate/up columns d_ff; out and down contract over them
     (r"block_\d+/(q|k|v|gate|up)_proj/kernel$", P(None, "model")),
     (r"block_\d+/(o|down)_proj/kernel$", P("model", None)),
+    # the routed block's q/k/v/o_proj are 2D and fall under the two rules
+    # above. Its router and its expert kernels (experts_gate, experts_up:
+    # (n_experts, d_model, d_expert); experts_down: (n_experts, d_expert,
+    # d_model)) are replicated: the grouped products run over rows sorted
+    # by expert, a data-dependent split that no static spec of d_expert
+    # or of the expert axis divides, and an expert axis over "model" needs
+    # a layer told which experts it holds and an exchange of spans
+    # (ROADMAP C5). WHOLE_ONLY below refuses a "model" axis for them
+    # rather than replicating 95% of the parameters in silence.
+    (WHOLE_ONLY, P()),
     (r"dec_ff1/kernel$", P(None, "model")),            # autoencoder decoder
     (r"dec_ff2/kernel$", P("model", None)),
     (r"(service|name)_head/kernel$", P(None, "model")),  # wide vocab heads
     (r"", P()),  # embeddings, norms, biases, small heads: replicated
 )
+
+
+def _param_name(path: tuple) -> str:
+    """The '/'-joined parameter path the rules are matched against."""
+    return "/".join(str(k.key) for k in path)
+
+
+def refuse_unplaceable(variables: Any, mesh: Mesh) -> None:
+    """Raise where the mesh asks the rule table for a placement it does
+    not have: a "model" axis over 1 and a ``WHOLE_ONLY`` parameter."""
+    tp = int(mesh.shape.get("model", 1))
+    if tp <= 1:
+        return
+    whole = [name for name in (
+        _param_name(path) for path, _
+        in jax.tree_util.tree_leaves_with_path(variables))
+        if re.search(WHOLE_ONLY, name)]
+    if whole:
+        raise ValueError(
+            f"a mesh with model axis {tp} cannot place {len(whole)} "
+            f"parameters (the first: {whole[0]}): PARTITION_RULES holds a "
+            f"routed block's router and expert kernels whole on every "
+            f"device, since its grouped products split rows by expert at "
+            f"run time. mesh {{data: n}} replicates them, each device "
+            f"routing its own rows (equal scores on four virtual CPU "
+            f"devices and a compile for a v5e 2 x 2; not yet run on four "
+            f"chips: PERF.md section 7)")
 
 
 def match_partition_rules(params: Any,
@@ -122,7 +171,7 @@ def match_partition_rules(params: Any,
         if getattr(leaf, "ndim", 0) == 0 or np.prod(
                 getattr(leaf, "shape", ())) == 1:
             return P()
-        name = "/".join(str(k.key) for k in path)
+        name = _param_name(path)
         for rule, spec in rules:
             if re.search(rule, name) is not None:
                 return spec
@@ -225,11 +274,17 @@ def _packed_score_jit(model, mesh: Mesh):
         return None
     row = NamedSharding(mesh, P("data", None))
     row3 = NamedSharding(mesh, P("data", None, None))
+    out = row
+    if getattr(model, "score_packed_counted", None) is not None:
+        # the model's counted entry: scores on "data", the call's counts
+        # (scalars) on every device
+        impl = model._score_packed_counted_impl
+        out = (row, NamedSharding(mesh, P()))
     return jitstats.track_jit(
         f"parallel.plan.score_packed[{mesh_key(mesh)}]",
         jax.jit(impl,
                 in_shardings=(None, row3, row3, row, row),
-                out_shardings=row))
+                out_shardings=out))
 
 
 class ScoringPlan:
@@ -247,7 +302,9 @@ class ScoringPlan:
     """
 
     def __init__(self, model: Any, mesh: Mesh,
-                 rules: tuple = PARTITION_RULES):
+                 rules: tuple = PARTITION_RULES, variables: Any = None):
+        if variables is not None:    # refuse at once, not at first call
+            refuse_unplaceable(variables, mesh)
         self.model = model
         self.mesh = mesh
         self.rules = rules
@@ -286,6 +343,14 @@ class ScoringPlan:
                      positions):
         """Sharded packed scoring; returns the (R, L) device array
         WITHOUT blocking (the engine's harvest stage fetches it)."""
+        return self.score_packed_counted(variables, categorical, continuous,
+                                         segments, positions)[0]
+
+    def score_packed_counted(self, variables, categorical, continuous,
+                             segments, positions):
+        """``score_packed`` and what the model counted on the device of
+        the call (None for a model that counts nothing), neither waited
+        for."""
         R = np.asarray(segments).shape[0]
         if R % self.dp:
             raise ValueError(
@@ -295,8 +360,13 @@ class ScoringPlan:
         v = self.place_variables(variables)
         categorical, continuous, segments, positions = _shard_inputs(
             self.mesh, (categorical, continuous, segments, positions))
-        return self._packed_jit(v, categorical, continuous, segments,
-                                positions)
+        # traced inside the mesh: a model whose rows each device works
+        # alone (the routed block's grouped products) asks which mesh
+        # splits them
+        with jax.set_mesh(self.mesh):
+            out = self._packed_jit(v, categorical, continuous, segments,
+                                   positions)
+        return out if isinstance(out, tuple) else (out, None)
 
     def placed_bytes(self) -> int:
         """Bytes held on device by the cached placed weight pytree (the
@@ -322,10 +392,11 @@ class ScoringPlan:
         return self.model.score_spans(v, categorical, continuous, mask)
 
 
-def compile_plan(model, mesh: Mesh, *,
-                 rules: tuple = PARTITION_RULES) -> ScoringPlan:
-    """Build the (model, mesh) serving plan."""
-    return ScoringPlan(model, mesh, rules=rules)
+def compile_plan(model, mesh: Mesh, *, rules: tuple = PARTITION_RULES,
+                 variables: Any = None) -> ScoringPlan:
+    """Build the (model, mesh) serving plan; given the weights, refuse
+    at once a mesh that cannot place them."""
+    return ScoringPlan(model, mesh, rules=rules, variables=variables)
 
 
 # ------------------------------------------------ legacy factory seams

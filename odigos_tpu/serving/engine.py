@@ -50,7 +50,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Optional, Protocol
+from typing import Any, Callable, NamedTuple, Optional, Protocol
 
 import numpy as np
 
@@ -492,6 +492,8 @@ class SequenceBackend:
         self.jit_site = ("transformer.score_packed"
                          if cfg.model == "transformer"
                          else "autoencoder.score_spans")
+        if getattr(self.model, "score_packed_counted", None) is not None:
+            self.jit_site = "transformer.score_packed_counted"
         self.last_shape: Optional[list[int]] = None
         # rows (traces, on the sequence route) the packer filled before
         # the ladder padded them up to last_shape[0]
@@ -501,11 +503,12 @@ class SequenceBackend:
         self.variables = variables if variables is not None else \
             self.model.init(jax.random.PRNGKey(cfg.seed))
         # what every tpu/score span says of the model behind the call
-        mc = self.model.cfg
-        self.score_attrs: dict[str, Any] = {
-            "model.block": mc.block, "model.passes": mc.passes,
-            "model.layer_applications": mc.layer_applications,
-        } if cfg.model == "transformer" else {}
+        self.score_attrs: dict[str, Any] = self.model.cfg.span_attrs \
+            if cfg.model == "transformer" else {}
+        # and which of a call's own counts (``call_attrs``) feed which
+        # counter: the model declares them, the engine names none
+        self.call_counters: dict[str, str] = self.model.cfg.call_counters \
+            if cfg.model == "transformer" else {}
         self._plan = None
         self._quantized = None
         if cfg.quantized and cfg.model == "transformer":
@@ -527,7 +530,8 @@ class SequenceBackend:
             # packed rows on "data". Non-blocking by design: the engine
             # harvests the device array itself so the fetch overlaps the
             # next in-flight call.
-            self._plan = compile_plan(self.model, mesh)
+            self._plan = compile_plan(self.model, mesh,
+                                      variables=self.variables)
             if cfg.model == "transformer":
                 # per-mesh compile attribution: each mesh shape warms its
                 # own ladder, and the jitstats ledger must say which one
@@ -535,13 +539,16 @@ class SequenceBackend:
 
     # ------------------------------------------------------- device stage
 
-    def _device_call(self, packed) -> Any:
-        """Enqueue the packed scoring call; returns the device array
-        WITHOUT blocking on it (JAX async dispatch)."""
+    def _device_call(self, packed) -> tuple[Any, Any]:
+        """Enqueue the packed scoring call; returns the device array of
+        scores WITHOUT blocking on it (JAX async dispatch) and, where the
+        model counts on the device what the call did, those counts
+        (scalars by span attribute name, of the same program; else
+        None)."""
         import jax.numpy as jnp
 
         if self._plan is not None:  # dp×tp across chips (partition plan)
-            return self._plan.score_packed(
+            return self._plan.score_packed_counted(
                 self.variables, packed.categorical, packed.continuous,
                 packed.segments, packed.positions)
         if self._quantized is not None:  # int8 serving path
@@ -549,12 +556,14 @@ class SequenceBackend:
                 jnp.asarray(packed.categorical),
                 jnp.asarray(packed.continuous),
                 jnp.asarray(packed.segments),
+                jnp.asarray(packed.positions)), None
+        args = (self.variables, jnp.asarray(packed.categorical),
+                jnp.asarray(packed.continuous),
+                jnp.asarray(packed.segments),
                 jnp.asarray(packed.positions))
-        return self.model.score_packed(
-            self.variables, jnp.asarray(packed.categorical),
-            jnp.asarray(packed.continuous),
-            jnp.asarray(packed.segments),
-            jnp.asarray(packed.positions))
+        if self.model.score_packed_counted is not None:
+            return self.model.score_packed_counted(*args)
+        return self.model.score_packed(*args), None
 
     def _round_rows(self, real: int) -> int:
         """The ladder's rounding, remembering what it rounded: the
@@ -594,12 +603,13 @@ class SequenceBackend:
         kind, host, n = staged
         with annotate("engine/enqueue", call=call,
                       rows=host.categorical.shape[0], spans=n):
+            counts = None
             if kind == "packed":
-                dev = self._device_call(host)
+                dev, counts = self._device_call(host)
             else:
                 dev, _ = self._seq_call(host.categorical, host.continuous,
                                         host.mask)
-        return (kind, dev, host.span_index, host.mask, n)
+        return _HostCall(kind, dev, host.span_index, host.mask, n, counts)
 
     def dispatch(self, batch: SpanBatch, features: SpanFeatures) -> Any:
         """Pack stage: ``pack`` then ``enqueue``. Returns an opaque
@@ -630,12 +640,23 @@ class SequenceBackend:
         for ``harvest``."""
         with annotate("engine/harvest", call=call):
             host = np.asarray(handle[1], dtype=np.float32)
-        return (handle[0], host) + tuple(handle[2:])
+            if isinstance(handle, _HostCall):
+                # the call's own counts, of the program that just ended
+                return handle._replace(scores=host, counts={
+                    key: value.item()
+                    for key, value in (handle.counts or {}).items()})
+        return (handle[0], host) + tuple(handle[2:])    # the fused route's
+
+    def call_attrs(self, handle: Any) -> dict[str, Any]:
+        """What a call counted on the device, by the name it has on the
+        call's ``tpu/score`` span: host numbers once ``fetch`` has run.
+        Nothing for a model that counts nothing and for the fused route."""
+        return getattr(handle, "counts", None) or {}
 
     def harvest(self, handle: Any) -> np.ndarray:
         """Harvest stage: block on the device result, unless ``fetch``
         already has, and scatter scores back to span rows."""
-        kind, dev, span_index, mask, n = handle
+        kind, dev, span_index, mask, n, _ = handle
         span_scores = np.asarray(dev, dtype=np.float32)
         if kind == "seq":
             # raw reconstruction error is unbounded; squash to (0, 1) so the
@@ -669,7 +690,7 @@ class SequenceBackend:
                     np.zeros((R, L, D), np.float32),
                     np.zeros((R, L), np.int32),
                     np.zeros((R, L), np.int32))
-                dev = self._device_call(zero)
+                dev, _ = self._device_call(zero)
             else:
                 zero = (np.zeros((R, L, C), np.int32),
                         np.zeros((R, L, D), np.float32),
@@ -696,7 +717,8 @@ class SequenceBackend:
         if self._plan is not None or self._quantized is not None:
             return
         if self.cfg.model == "transformer":
-            fn = self.model.score_packed
+            # the entry the rung just ran
+            fn = self.model.score_packed_counted or self.model.score_packed
             args = (self.variables, zero.categorical, zero.continuous,
                     zero.segments, zero.positions)
         else:
@@ -704,6 +726,17 @@ class SequenceBackend:
             fn = type(self.model).score_spans
             args = (self.model, self.variables, *zero)
         cost_ledger.capture(site, f"r{R}", fn, args)
+
+
+class _HostCall(NamedTuple):
+    """The host route's handle from ``enqueue`` to ``harvest``."""
+
+    kind: str                  # "packed" or "seq"
+    scores: Any                # the device array; the host's after fetch
+    span_index: np.ndarray
+    mask: np.ndarray
+    n: int
+    counts: Optional[dict[str, Any]]   # what the model counted, or None
 
 
 @dataclass(frozen=True)
@@ -900,6 +933,9 @@ class _InflightGroup:
     commit: str = "idle"
     held_ms: float = 0.0
     ahead_end_ns: Optional[int] = None
+    # what the call counted on the device (backend.call_attrs), read once
+    # its result is fetched
+    call_attrs: Optional[dict[str, Any]] = None
 
 
 class ScoringEngine:
@@ -1905,6 +1941,8 @@ class ScoringEngine:
             except Exception as e:
                 self._harvest_failed(grp, backend, e)
                 return
+            grp.call_attrs = backend.call_attrs(handle) \
+                if hasattr(backend, "call_attrs") else None
         with annotate("engine/scatter", call=grp.call):
             try:
                 harvest = getattr(backend, "harvest", None)
@@ -2094,11 +2132,15 @@ class ScoringEngine:
         if grp.bucket_hit is not None:
             sp.set_attr("bucket.hit", grp.bucket_hit)
         sp.set_attr("call.serial", grp.call)
-        attrs = getattr(grp.backend if grp.backend is not None
-                        else self.backend, "score_attrs", {})
-        for key, value in attrs.items():
+        backend = grp.backend if grp.backend is not None else self.backend
+        attrs = getattr(backend, "score_attrs", {})
+        counted = grp.call_attrs or {}
+        for key, value in {**attrs, **counted}.items():
             sp.set_attr(key, value)
         if attrs:
             meter.add(LAYER_APPLICATIONS_METRIC,
                       attrs["model.layer_applications"])
+        for key, metric in getattr(backend, "call_counters", {}).items():
+            if key in counted:
+                meter.add(metric, counted[key])
         self._device_calls += 1

@@ -53,8 +53,9 @@ def _resolve_dtype(name: str) -> Any:
 def config_to_dict(model_config: Any) -> dict[str, Any]:
     """JSON-safe dict of a TransformerConfig/AutoencoderConfig."""
     d = dataclasses.asdict(model_config)
-    if "dtype" in d:
-        d["dtype"] = _dtype_name(d["dtype"])
+    for key in ("dtype", "param_dtype"):
+        if key in d:
+            d[key] = _dtype_name(d[key])
     return d
 
 
@@ -63,8 +64,9 @@ def make_model_config(model: str, fields: Optional[dict[str, Any]] = None):
     fields (e.g. a pipeline-config ``model_config`` block or a bundle's
     model.json). Unknown keys are rejected so config typos fail loudly."""
     fields = dict(fields or {})
-    if "dtype" in fields and isinstance(fields["dtype"], str):
-        fields["dtype"] = _resolve_dtype(fields["dtype"])
+    for key in ("dtype", "param_dtype"):
+        if isinstance(fields.get(key), str):
+            fields[key] = _resolve_dtype(fields[key])
     if model == "transformer":
         from ..models import TransformerConfig
 
