@@ -1,12 +1,18 @@
-"""Shared flax modules: span embedding trunk + the transformer's three
+"""Shared flax modules: span embedding trunk + the transformer's four
 block kinds (``BLOCK_PARTS``): the pre-LN bidirectional encoder block under
 a learned position table; the decoder block (sandwich RMS norms, rotary
 positions, causal attention within a trace, SwiGLU, no biases) whose stack
-runs ``passes`` times over the same parameters as a loop on the device; and
-the routed block (pre-norm residuals, grouped query heads over fewer
+runs ``passes`` times over the same parameters as a loop on the device; the
+routed block (pre-norm residuals, grouped query heads over fewer
 key/value heads, rotary positions and a window layer by layer, a router
 ahead of attention that sends each span to ``experts_per_span`` of
-``n_experts`` ReLU-gated experts, parameters held in bfloat16).
+``n_experts`` ReLU-gated experts, parameters held in bfloat16); and the
+latent routed block (latent attention: low-rank queries, one compressed
+key/value and one rotary key a span shared by every head; a sigmoid router
+with a selection bias over SiLU-gated experts beside a shared expert; the
+stack's leading layers dense). Both routed blocks run one dispatch,
+``routed_experts``, each under its own rule of choice (``top_softmax``,
+``biased_sigmoid``).
 
 MXU discipline (see /opt/skills/guides/pallas_guide.md and SURVEY.md env
 notes): feature dims multiples of 128, bfloat16 activations with float32
@@ -121,11 +127,18 @@ PARTS = ("embed", "attn_mask", "attn", "mlp", "final_norm", "head")
 # The routed block adds ``route``: the router's product, the top-k and its
 # softmax, the sort by expert, the gather into expert order and the
 # weighted combine back; its ``mlp`` is the experts' grouped products, the
-# ReLU and the gate's multiply alone.
+# gate's activation and its multiply alone. The latent routed block adds
+# two more, siblings of the rest: ``latent`` (the four low-rank products
+# that make queries, keys and values, the two latent norms, the rotary of
+# the rotary columns, the shared key's broadcast and the concatenations;
+# its ``attn`` is the core and the output product alone) and ``dense``
+# (the shared expert of a routed layer and a dense layer's feed-forward).
 BLOCK_PARTS = {
     "encoder": PARTS,
     "decoder": ("embed", "attn_mask", "attn", "mlp", "norm", "head"),
     "moe": ("embed", "attn_mask", "attn", "route", "mlp", "norm", "head"),
+    "latent_moe": ("embed", "attn_mask", "latent", "attn", "route", "mlp",
+                   "dense", "norm", "head"),
 }
 
 
@@ -338,31 +351,48 @@ class LoopedDecoder(nn.Module):
         return x
 
 
-def rounded_lecun(batch_axis: tuple[int, ...] = ()):
-    """Lecun-normal over the kernel's own fan-in, drawn in float32 and
-    rounded once to the dtype the parameter is held in: the same numbers
-    whatever that dtype is, where a draw in bfloat16 is another stream.
-    ``batch_axis`` names the axes that count no fan (an expert axis)."""
-    draw = nn.initializers.variance_scaling(
-        1.0, "fan_in", "truncated_normal", batch_axis=batch_axis)
-
+def _rounded(draw):
+    """``draw`` in float32, rounded once to the dtype the parameter is held
+    in: the same numbers whatever that dtype is, where a draw in bfloat16
+    is another stream."""
     def init(key, shape, dtype=jnp.float32):
         return draw(key, shape, jnp.float32).astype(dtype)
 
     return init
 
 
-class ExpertKernel(nn.Module):
-    """One kernel for each expert, (n_experts, fan_in, fan_out), under the
-    parameter path a ``Dense`` of that name would have."""
+def rounded_lecun(batch_axis: tuple[int, ...] = ()):
+    """Lecun-normal over the kernel's own fan-in, ``_rounded``.
+    ``batch_axis`` names the axes that count no fan (an expert axis)."""
+    return _rounded(nn.initializers.variance_scaling(
+        1.0, "fan_in", "truncated_normal", batch_axis=batch_axis))
 
-    shape: tuple[int, int, int]
+
+class Kernel(nn.Module):
+    """A kernel without its product, for a caller that multiplies by it
+    otherwise than a ``Dense`` would (grouped by expert, or by parts):
+    (fan_in, fan_out), or one for each expert (n_experts, fan_in,
+    fan_out), under the parameter path and from the key a ``Dense`` of
+    that name would have."""
+
+    shape: tuple[int, ...]
     param_dtype: Any
 
     @nn.compact
     def __call__(self) -> jnp.ndarray:
-        return self.param("kernel", rounded_lecun(batch_axis=(0,)),
+        experts = tuple(range(len(self.shape) - 2))   # count no fan
+        return self.param("kernel", rounded_lecun(batch_axis=experts),
                           self.shape, self.param_dtype)
+
+
+def expert_kernels(n_experts: int, d_model: int, d_expert: int,
+                   param_dtype: Any, dtype: Any) -> list[jnp.ndarray]:
+    """The three kernels of a routed block's experts (gate, up, down) as
+    parameters of the calling module, in the activations' ``dtype``."""
+    return [Kernel((n_experts, a, b), param_dtype, name=name)().astype(dtype)
+            for name, a, b in (("experts_gate", d_model, d_expert),
+                               ("experts_up", d_model, d_expert),
+                               ("experts_down", d_expert, d_model))]
 
 
 # rows a tile of the grouped kernels: the assignments are padded up to
@@ -370,15 +400,28 @@ class ExpertKernel(nn.Module):
 GROUP_ROWS = 512
 
 
-def _experts_ragged(x, gate, up, down, load):
+def _whole_tile(n: int, most: int = 1280) -> int:
+    """The widest tile of a grouped product along an axis of ``n``
+    columns: the largest multiple of 128 up to ``most`` that divides
+    ``n``, so that no tile is part empty (1280 of 2560, 768 of 768; 1024
+    of 2048 and 768 of 1536, where tiles of 1280 worked 2560 columns of
+    each and the three products ran at half their share of the peak:
+    PERF.md section 6, PR 37); ``n`` up to ``most`` itself where no such
+    multiple divides it."""
+    return max((t for t in range(128, min(n, most) + 1, 128) if n % t == 0),
+               default=min(n, most))
+
+
+def _experts_ragged(x, gate, up, down, load, act=nn.relu):
     """The experts' three grouped products over rows sorted by expert,
-    ``load[e]`` rows for expert e: relu(x Wg) * (x Wu), then Wd."""
-    y = nn.relu(jax.lax.ragged_dot(x, gate, load)) \
+    ``load[e]`` rows for expert e: act(x Wg) * (x Wu), then Wd."""
+    y = act(jax.lax.ragged_dot(x, gate, load)) \
         * jax.lax.ragged_dot(x, up, load)
     return jax.lax.ragged_dot(y, down, load)
 
 
-def _experts_gmm(x, gate, up, down, load, interpret: bool = False):
+def _experts_gmm(x, gate, up, down, load, act=nn.relu,
+                 interpret: bool = False):
     """The same three products as Pallas grouped-matmul kernels
     (``megablox.gmm``), which the TPU runs at two thirds of its peak where
     the kernel XLA makes of ``ragged_dot`` ran at 42% (v5e, 196,608 rows x
@@ -388,28 +431,55 @@ def _experts_gmm(x, gate, up, down, load, interpret: bool = False):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     def product(lhs, rhs):
-        tiles = (GROUP_ROWS,) + tuple(min(n, 1280) for n in rhs.shape[1:])
+        tiles = (GROUP_ROWS,) + tuple(_whole_tile(n) for n in rhs.shape[1:])
         return gmm(lhs, rhs, load, preferred_element_type=lhs.dtype,
                    tiling=tiles, interpret=interpret)
 
-    return product(nn.relu(product(x, gate)) * product(x, up), down)
+    return product(act(product(x, gate)) * product(x, up), down)
 
 
-def routed_experts(h: jnp.ndarray, logits: jnp.ndarray, real: jnp.ndarray,
-                   gate: jnp.ndarray, up: jnp.ndarray, down: jnp.ndarray,
-                   k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The routed feed-forward over ``h`` (spans, d): each real span
-    goes to the ``k`` experts of its largest ``logits`` (spans,
-    n_experts; float32), weighted by the softmax over those k. The
-    experts run as grouped products over the assignments sorted by
-    expert: no span is dropped and there is no capacity. A slot that
-    holds no span (``real`` False) is sorted past the last expert, so
-    it enters no product, and comes back zero. Returns (spans, d) and
-    the assignments each expert took, (n_experts,) int32."""
-    spans, n_experts = logits.shape
+def top_softmax(logits: jnp.ndarray, k: int,
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed block's rule: the ``k`` largest ``logits`` (spans,
+    n_experts; float32) first, the softmax over those k. Returns which
+    experts a span takes and how it weighs them, both (spans, k)."""
     with jax.named_scope("route"):
         top, which = jax.lax.top_k(logits, k)
-        weight = jax.nn.softmax(top, axis=-1) * real[:, None]
+        return which, jax.nn.softmax(top, axis=-1)
+
+
+def biased_sigmoid(logits: jnp.ndarray, bias: jnp.ndarray, k: int,
+                   scale: float) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The latent routed block's rule: a span's scores are the sigmoids of
+    its ``logits``; it takes the ``k`` experts of the largest score plus
+    ``bias`` (n_experts,), and weighs them by their unbiased scores,
+    normalised over the k and scaled: the bias chooses, it does not
+    weigh. All float32. Returns (which, weight), both (spans, k)."""
+    with jax.named_scope("route"):
+        score = jax.nn.sigmoid(logits)
+        _, which = jax.lax.top_k(score + bias.astype(score.dtype), k)
+        chosen = jnp.take_along_axis(score, which, axis=-1)
+        return which, scale * chosen / jnp.sum(chosen, axis=-1,
+                                               keepdims=True)
+
+
+def routed_experts(h: jnp.ndarray, which: jnp.ndarray, weight: jnp.ndarray,
+                   real: jnp.ndarray, gate: jnp.ndarray, up: jnp.ndarray,
+                   down: jnp.ndarray, act=nn.relu,
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed feed-forward over ``h`` (spans, d): each real span goes
+    to the k experts ``which`` (spans, k) names, weighted by ``weight``
+    (spans, k; float32), whatever rule chose them (``top_softmax``,
+    ``biased_sigmoid``); ``act`` is the gate's activation. The experts
+    run as grouped products over the assignments sorted by expert: no
+    span is dropped and there is no capacity. A slot that holds no span
+    (``real`` False) is sorted past the last expert, so it enters no
+    product, and comes back zero. Returns (spans, d) and the assignments
+    each expert took, (n_experts,) int32."""
+    spans, k = which.shape
+    n_experts = gate.shape[0]
+    with jax.named_scope("route"):
+        weight = weight * real[:, None]
         expert = jnp.where(real[:, None], which, n_experts).reshape(-1)
         order = jnp.argsort(expert, stable=True)
         load = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0,
@@ -418,8 +488,9 @@ def routed_experts(h: jnp.ndarray, logits: jnp.ndarray, real: jnp.ndarray,
         sorted_h = h[jnp.pad(order, (0, whole)) // k]
     with jax.named_scope("mlp"):
         y = jax.lax.platform_dependent(sorted_h, gate, up, down, load,
-                                       tpu=_experts_gmm,
-                                       default=_experts_ragged)
+                                       tpu=partial(_experts_gmm, act=act),
+                                       default=partial(_experts_ragged,
+                                                       act=act))
     with jax.named_scope("route"):
         # back to span order with the k-th choices as the leading axis,
         # (k, spans, d): summed over whole slabs, where (spans, k, d)
@@ -438,7 +509,8 @@ ROWS_AXIS = "data"
 
 
 def each_device_its_rows(route):
-    """``route(h, logits, real, gate, up, down) -> (out, load)`` run by
+    """``route(h, which, weight, real, gate, up, down) -> (out, load)``
+    (``routed_experts``) run by
     each device on its own rows where the mesh in context splits the rows
     (a plan traces its call inside its mesh): the grouped products are
     Pallas kernels on the TPU, which the partitioner cannot split
@@ -455,7 +527,7 @@ def each_device_its_rows(route):
         return out, jax.lax.psum(load, ROWS_AXIS)
 
     rows, whole = PartitionSpec(ROWS_AXIS), PartitionSpec()
-    return jax.shard_map(local, in_specs=(rows,) * 3 + (whole,) * 3,
+    return jax.shard_map(local, in_specs=(rows,) * 4 + (whole,) * 3,
                          out_specs=(rows, whole), check_vma=False)
 
 
@@ -514,16 +586,12 @@ class MoeBlock(nn.Module):
                 h.reshape(x.shape[:-1] + (self.n_heads * self.head_dim,)))
         x = x + h
         h = norm("mlp_norm", x)
-        kernels = [ExpertKernel((self.n_experts, a, b), self.param_dtype,
-                                name=name)().astype(self.dtype)
-                   for name, a, b in (
-                       ("experts_gate", self.d_model, self.d_expert),
-                       ("experts_up", self.d_model, self.d_expert),
-                       ("experts_down", self.d_expert, self.d_model))]
-        h, load = each_device_its_rows(
-            partial(routed_experts, k=self.experts_per_span))(
-            h.reshape(-1, self.d_model),
-            logits.reshape(-1, self.n_experts), mask.reshape(-1),
+        kernels = expert_kernels(self.n_experts, self.d_model, self.d_expert,
+                                 self.param_dtype, self.dtype)
+        which, weight = top_softmax(logits.reshape(-1, self.n_experts),
+                                    self.experts_per_span)
+        h, load = each_device_its_rows(routed_experts)(
+            h.reshape(-1, self.d_model), which, weight, mask.reshape(-1),
             *kernels)
         return x + h.reshape(x.shape), load
 
@@ -600,6 +668,210 @@ class MoeDecoder(nn.Module):
                 bool(rope), self.dtype, self.param_dtype, self.norm_eps,
                 name=f"block_{i}")(x, mask, masks[bool(window)], cos, sin)
             loads.append(load)
+        with jax.named_scope("norm"):
+            x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           param_dtype=self.param_dtype,
+                           name="final_rms")(x)
+        return x, jnp.stack(loads)
+
+
+# scale of the selection bias's draw (normal, float32, rounded once to the
+# dtype it is held in). A trained checkpoint's bias is what its balancing
+# left; at zero, which is where training starts it, the mechanism would
+# not run. The fourth and fifth of 64 sigmoid scores of unit-variance
+# logits lie 0.018 apart in the mean: at 0.02 the bias is the size of the
+# gap it arbitrates and moves four choices in ten. It is drawn blind to
+# an expert's load, so it spreads nothing: at 0.05 the first routed layer
+# of the published cut kept 31 of 64 experts busy where 41 are at zero
+# and 37-38 at 0.02 (PERF.md section 6, PR 37).
+SELECTION_BIAS_SCALE = 0.02
+
+
+class LatentMoeBlock(nn.Module):
+    """Latent routed decoder block: pre-norm residuals (two RMS norms).
+
+    Attention is latent: queries through a rank-``q_rank`` chain with an
+    RMS norm in its middle, keys and values through one compressed
+    ``kv_rank`` latent a span (normed, then expanded to every head's
+    ``qk_nope_dim`` unrotated key columns and ``v_dim`` value columns)
+    beside one rotary key of ``qk_rope_dim`` columns that all heads
+    share; a head's query and key are [unrotated | rotary]. Nothing is
+    cached and nothing absorbed into the output product: a row's keys and
+    values live for one call.
+
+    The feed-forward of a ``routed`` layer is ``n_experts`` SiLU-gated
+    experts of width ``d_expert`` of which a span takes
+    ``experts_per_span`` by ``biased_sigmoid`` (the router reads the
+    normed input; its product, the sigmoid and the top-k run in float32)
+    plus, where ``shared_experts`` > 0, one SiLU-gated expert of width
+    ``shared_experts * d_expert`` that every span takes; that of a layer
+    that is not routed is one SwiGLU of width ``d_ff``, and it returns no
+    load. No bias but the router's selection bias; parameters are held in
+    ``param_dtype``."""
+
+    d_model: int
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+    d_ff: int
+    n_experts: int
+    experts_per_span: int
+    d_expert: int
+    shared_experts: int
+    route_scale: float
+    routed: bool
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, mask: jnp.ndarray,
+                 attn_mask: jnp.ndarray, cos: jnp.ndarray,
+                 sin: jnp.ndarray) -> tuple[jnp.ndarray, Any]:
+        def dense(features: int, name: str, dtype: Any = self.dtype,
+                  **kw) -> nn.Dense:
+            return nn.Dense(features, use_bias=False, dtype=dtype,
+                            param_dtype=self.param_dtype,
+                            kernel_init=rounded_lecun(), name=name, **kw)
+
+        def rms(name: str, h: jnp.ndarray) -> jnp.ndarray:
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              param_dtype=self.param_dtype, name=name)(h)
+
+        def norm(name: str, h: jnp.ndarray) -> jnp.ndarray:
+            with jax.named_scope("norm"):
+                return rms(name, h)
+
+        def swiglu(h: jnp.ndarray, width: int, gate: str, up: str,
+                   down: str) -> jnp.ndarray:
+            with jax.named_scope("dense"):
+                h = nn.silu(dense(width, gate)(h)) * dense(width, up)(h)
+                return dense(self.d_model, down)(h)
+
+        def expand(latent: jnp.ndarray, name: str, first: int,
+                   second: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+            """Out of a latent to every head's ``first`` and ``second``
+            columns, (..., H, first) and (..., H, second): one kernel
+            (rank, H x (first + second)), a head's columns side by side
+            as published, cut here and not in its product, so that no
+            (spans, H, first + second) value is made to be sliced."""
+            w = Kernel((latent.shape[-1], H * (first + second)),
+                       self.param_dtype, name=name)()
+            w = w.astype(self.dtype).reshape(-1, H, first + second)
+            return tuple(jnp.einsum("...r,rhd->...hd", latent, part)
+                         for part in (w[..., :first], w[..., first:]))
+
+        H, d_n, d_r, d_v = (self.n_heads, self.qk_nope_dim,
+                            self.qk_rope_dim, self.v_dim)
+        h = norm("attn_norm", x)
+        with jax.named_scope("latent"):
+            q_n, q_r = expand(
+                rms("q_a_norm", dense(self.q_rank, "q_a_proj")(h)),
+                "q_b_proj", d_n, d_r)
+            q = jnp.concatenate([q_n, rotate(q_r, cos, sin)], axis=-1)
+            kv = dense(self.kv_rank + d_r, "kv_a_proj")(h)
+            # one rotary key a span, the same for every head
+            k_r = rotate(kv[..., None, self.kv_rank:], cos, sin)
+            k_n, v = expand(rms("kv_a_norm", kv[..., :self.kv_rank]),
+                            "kv_b_proj", d_n, d_v)
+            k = jnp.concatenate(
+                [k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (d_r,))],
+                axis=-1)
+        with jax.named_scope("attn"):
+            h = attention(q, k, v, attn_mask, self.dtype)
+            h = dense(self.d_model, "o_proj")(
+                h.reshape(x.shape[:-1] + (H * d_v,)))
+        x = x + h
+        h = norm("mlp_norm", x)
+        if not self.routed:
+            return x + swiglu(h, self.d_ff, "gate_proj", "up_proj",
+                              "down_proj"), None
+        with jax.named_scope("route"):
+            logits = dense(self.n_experts, "router", jnp.float32,
+                           precision=jax.lax.Precision.HIGHEST)(h)
+            bias = self.param("router_bias",
+                              _rounded(nn.initializers.normal(
+                                  SELECTION_BIAS_SCALE)),
+                              (self.n_experts,), self.param_dtype)
+        kernels = expert_kernels(self.n_experts, self.d_model, self.d_expert,
+                                 self.param_dtype, self.dtype)
+        which, weight = biased_sigmoid(
+            logits.reshape(-1, self.n_experts), bias, self.experts_per_span,
+            self.route_scale)
+        y, load = each_device_its_rows(partial(routed_experts, act=nn.silu))(
+            h.reshape(-1, self.d_model), which, weight, mask.reshape(-1),
+            *kernels)
+        x = x + y.reshape(x.shape)
+        if self.shared_experts:
+            x = x + swiglu(h, self.shared_experts * self.d_expert,
+                           "shared_gate", "shared_up", "shared_down")
+        return x, load
+
+
+class LatentMoeDecoder(nn.Module):
+    """Embedding trunk + a stack of latent routed blocks, each applied
+    once: the first ``dense_layers`` with a dense feed-forward, the rest
+    routed. Every layer rotates its rotary columns; attention is causal
+    within a trace. Returns the normed output and, (routed layers,
+    n_experts), the assignments each expert of each routed layer took."""
+
+    service_vocab: int
+    name_vocab: int
+    attr_vocab: int
+    d_model: int
+    n_heads: int
+    n_layers: int
+    dense_layers: int
+    q_rank: int
+    kv_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+    d_ff: int
+    n_experts: int
+    experts_per_span: int
+    d_expert: int
+    shared_experts: int
+    route_scale: float
+    rope_theta: float
+    norm_eps: float
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, categorical, continuous, mask,
+                 deterministic: bool = True,
+                 positions: jnp.ndarray | None = None,
+                 segments: jnp.ndarray | None = None):
+        with jax.named_scope("embed"):
+            x = SpanEmbedder(self.service_vocab, self.name_vocab,
+                             self.attr_vocab, self.d_model, self.dtype,
+                             *ROUTED_EMBED_INITS,
+                             name="embed")(categorical, continuous)
+            x = x * mask[..., None].astype(self.dtype)
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(mask.shape[-1]),
+                                         mask.shape)
+        with jax.named_scope("attn_mask"):
+            attn_mask = causal_mask(mask, positions, segments)[:, None]
+        with jax.named_scope("latent"):
+            cos, sin = rotary_tables(positions, self.qk_rope_dim,
+                                     self.rope_theta, self.dtype)
+        loads = []
+        for i in range(self.n_layers):
+            x, load = LatentMoeBlock(
+                self.d_model, self.n_heads, self.q_rank, self.kv_rank,
+                self.qk_nope_dim, self.qk_rope_dim, self.v_dim, self.d_ff,
+                self.n_experts, self.experts_per_span, self.d_expert,
+                self.shared_experts, self.route_scale,
+                i >= self.dense_layers, self.dtype, self.param_dtype,
+                self.norm_eps, name=f"block_{i}")(x, mask, attn_mask, cos,
+                                                  sin)
+            if load is not None:
+                loads.append(load)
         with jax.named_scope("norm"):
             x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                            param_dtype=self.param_dtype,

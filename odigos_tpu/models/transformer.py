@@ -9,13 +9,17 @@ in bfloat16, data-parallel across the mesh (odigos_tpu.parallel).
 Default dims are MXU-shaped: d_model 256, d_ff 1024, heads 4 — all multiples
 of the 128-lane tile.
 
-``TransformerConfig.block`` picks one of three block kinds (``layers.py``
+``TransformerConfig.block`` picks one of four block kinds (``layers.py``
 ``BLOCK_PARTS``): the default pre-LN bidirectional ``encoder``; the
-``decoder`` block whose stack is looped ``passes`` times on the device; and
-the routed ``moe`` block (grouped query heads, rotary positions and a
+``decoder`` block whose stack is looped ``passes`` times on the device; the
+routed ``moe`` block (grouped query heads, rotary positions and a
 window layer by layer, a router ahead of attention over ``n_experts``
 experts of which a span takes ``experts_per_span``, parameters held in
-``param_dtype``), whose ``score_packed_counted`` also returns what the
+``param_dtype``); and the latent routed ``latent_moe`` block (latent
+attention through ``q_rank`` and ``kv_rank`` with one rotary key a span, a
+sigmoid router with a selection bias over SiLU-gated experts beside
+``shared_experts`` shared ones, the first ``dense_layers`` layers dense at
+``d_ff``). A routed kind's ``score_packed_counted`` also returns what the
 router assigned.
 """
 
@@ -30,14 +34,15 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from . import jitstats
-from .layers import BLOCK_PARTS, Encoder, LoopedDecoder, MoeDecoder
+from .layers import (BLOCK_PARTS, Encoder, LatentMoeDecoder, LoopedDecoder,
+                     MoeDecoder)
 
 # Shape-bucketing strategy per jitted scoring entry point (the package
 # hygiene test asserts every jit path in models/ and parallel/ declares
 # one — an undeclared path is an unbounded-recompile hazard at serving
 # rates). Values are documentation; the mechanisms live where named.
 SHAPE_BUCKETING = {
-    "score_packed_counted": "the routed block's score_packed with its "
+    "score_packed_counted": "a routed block's score_packed with its "
                             "counts: the same rows, the same ladder",
     "score_spans": "leading trace axis padded by the engine's BucketLadder "
                    "(serving.engine) or a fixed trace_bucket multiple; "
@@ -77,9 +82,20 @@ class TransformerConfig:
     # n_experts ReLU-gated experts d_expert wide (d_ff is unused), layer i
     # rotating its queries and keys where rope_layout[i] and cutting its
     # attention to the last ``window`` spans where window_layout[i], the
-    # parameters held in param_dtype. passes is the decoder block's;
-    # rope_theta and norm_eps are the decoder and the routed block's; the
-    # keys from n_kv_heads down are the routed block's alone.
+    # parameters held in param_dtype. "latent_moe": the latent routed
+    # block (pre-norm RMS residuals, causal within a trace, each layer
+    # applied once and every layer rotary): n_heads heads whose queries
+    # come through a q_rank chain and whose keys and values through one
+    # kv_rank latent a span, a head's query and key qk_nope_dim unrotated
+    # columns beside qk_rope_dim rotary ones (the rotary key one a span,
+    # shared by the heads), its value v_dim wide; the first dense_layers
+    # layers with a SwiGLU d_ff wide, the rest routing each span by
+    # sigmoid scores plus a selection bias to experts_per_span of
+    # n_experts SiLU-gated experts d_expert wide, weighted by the unbiased
+    # scores normalised and times route_scale, beside shared_experts
+    # experts every span takes. passes is the decoder block's; rope_theta
+    # and norm_eps are the decoder and the routed blocks'; _BLOCK_KEYS
+    # says which of the keys from n_kv_heads down each routed block takes.
     block: str = "encoder"
     passes: int = 1
     rope_theta: float = 10000.0
@@ -93,6 +109,14 @@ class TransformerConfig:
     window_layout: tuple[int, ...] = ()
     window: int = 0
     param_dtype: Any = jnp.float32
+    q_rank: int = 0
+    kv_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_dim: int = 0
+    shared_experts: int = 0
+    dense_layers: int = 0
+    route_scale: float = 1.0
 
     def __post_init__(self) -> None:
         if self.block not in BLOCK_PARTS:
@@ -104,13 +128,22 @@ class TransformerConfig:
                              f"stack is looped, and at least once")
         for key in ("rope_layout", "window_layout"):  # hashable from JSON
             object.__setattr__(self, key, tuple(getattr(self, key)))
-        if self.block != "moe":
-            stray = {k: getattr(self, k) for k in _ROUTED_KEYS
-                     if getattr(self, k) != getattr(type(self), k)}
-            if stray:
-                raise ValueError(f"{stray} with block {self.block!r}: "
-                                 f"these keys are the routed block's")
-            return
+        stray = {k: getattr(self, k) for k, blocks in _BLOCK_KEYS.items()
+                 if self.block not in blocks
+                 and getattr(self, k) != getattr(type(self), k)}
+        if stray:
+            kinds = sorted({b for k in stray for b in _BLOCK_KEYS[k]})
+            raise ValueError(f"{stray} with block {self.block!r}: these "
+                             f"keys are the routed block's "
+                             f"({', '.join(kinds)})")
+        faults = {"moe": self._moe_faults,
+                  "latent_moe": self._latent_moe_faults}.get(
+            self.block, list)()
+        if faults:
+            raise ValueError(f"block {self.block!r} does not compose: "
+                             + "; ".join(faults))
+
+    def _moe_faults(self) -> list[str]:
         faults = []
         if not (self.n_kv_heads > 0 and self.head_dim > 0
                 and self.n_heads % max(self.n_kv_heads, 1) == 0):
@@ -120,11 +153,7 @@ class TransformerConfig:
         if self.head_dim % 2:
             faults.append(f"head_dim {self.head_dim} is odd: rotary "
                           f"positions pair its columns")
-        if not 0 < self.experts_per_span <= self.n_experts \
-                or self.d_expert < 1:
-            faults.append(f"experts_per_span {self.experts_per_span} of "
-                          f"n_experts {self.n_experts}, d_expert "
-                          f"{self.d_expert}")
+        faults += self._expert_faults()
         if not len(self.rope_layout) == len(self.window_layout) \
                 == self.n_layers:
             faults.append(f"rope_layout ({len(self.rope_layout)}) and "
@@ -132,9 +161,40 @@ class TransformerConfig:
                           f"each state all n_layers {self.n_layers}")
         if any(self.window_layout) and self.window < 1:
             faults.append(f"window {self.window} with a layer that has one")
-        if faults:
-            raise ValueError("block 'moe' does not compose: "
-                             + "; ".join(faults))
+        return faults
+
+    def _expert_faults(self) -> list[str]:
+        if 0 < self.experts_per_span <= self.n_experts \
+                and self.d_expert >= 1:
+            return []
+        return [f"experts_per_span {self.experts_per_span} of n_experts "
+                f"{self.n_experts}, d_expert {self.d_expert}"]
+
+    def _latent_moe_faults(self) -> list[str]:
+        faults = []
+        sizes = {k: getattr(self, k) for k in (
+            "q_rank", "kv_rank", "qk_nope_dim", "qk_rope_dim", "v_dim")}
+        if min(sizes.values()) < 1:
+            faults.append(f"latent attention needs every one of {sizes} "
+                          f"set")
+        if self.qk_rope_dim % 2:
+            faults.append(f"qk_rope_dim {self.qk_rope_dim} is odd: rotary "
+                          f"positions pair its columns")
+        faults += self._expert_faults()
+        if not 0 <= self.dense_layers < self.n_layers:
+            faults.append(f"dense_layers {self.dense_layers} of n_layers "
+                          f"{self.n_layers} leaves no routed layer (a "
+                          f"stack with none is the decoder block's)")
+        if self.shared_experts < 0 or not self.route_scale > 0:
+            faults.append(f"shared_experts {self.shared_experts}, "
+                          f"route_scale {self.route_scale}")
+        return faults
+
+    @property
+    def routed(self) -> bool:
+        """Whether the block routes spans to experts (and so counts its
+        assignments on the device)."""
+        return self.block in ("moe", "latent_moe")
 
     @property
     def layer_applications(self) -> int:
@@ -145,16 +205,25 @@ class TransformerConfig:
     def span_attrs(self) -> dict[str, Any]:
         """What every ``tpu/score`` span says of the model behind the
         call: the block kind and how many blocks a span passes through;
-        of the routed block also its experts, how many a span takes, and
-        how many layers rotate and how many have a window."""
+        of a routed block also its experts and how many a span takes; of
+        the routed block how many layers rotate and how many have a
+        window, of the latent routed block its attention's ranks, its
+        shared experts and how many leading layers are dense."""
         attrs = {"model.block": self.block, "model.passes": self.passes,
                  "model.layer_applications": self.layer_applications}
+        if self.routed:
+            attrs.update({"model.experts": self.n_experts,
+                          "model.experts_per_span": self.experts_per_span})
         if self.block == "moe":
             attrs.update({
-                "model.experts": self.n_experts,
-                "model.experts_per_span": self.experts_per_span,
                 "model.layers_rotary": sum(map(bool, self.rope_layout)),
                 "model.layers_window": sum(map(bool, self.window_layout))})
+        if self.block == "latent_moe":
+            attrs.update({"model.attention": "latent",
+                          "model.q_rank": self.q_rank,
+                          "model.kv_rank": self.kv_rank,
+                          "model.shared_experts": self.shared_experts,
+                          "model.layers_dense": self.dense_layers})
         return attrs
 
     @property
@@ -162,15 +231,21 @@ class TransformerConfig:
         """Which of the numbers a call counts on the device
         (``score_packed_counted``, by span attribute name) is added to
         which counter; nothing for a block that counts nothing."""
-        if self.block == "moe":
+        if self.routed:
             return {"moe.assignments": EXPERT_ASSIGNMENTS_METRIC}
         return {}
 
 
-# the routed block's own keys, refused under the two other blocks
-_ROUTED_KEYS = ("n_kv_heads", "head_dim", "n_experts", "experts_per_span",
-                "d_expert", "rope_layout", "window_layout", "window",
-                "param_dtype")
+# the routed blocks' own keys and the blocks that take each; any other
+# block refuses one that is set
+_BLOCK_KEYS = {
+    **{k: ("latent_moe", "moe") for k in (
+        "n_experts", "experts_per_span", "d_expert", "param_dtype")},
+    **{k: ("moe",) for k in (
+        "n_kv_heads", "head_dim", "rope_layout", "window_layout", "window")},
+    **{k: ("latent_moe",) for k in (
+        "q_rank", "kv_rank", "qk_nope_dim", "qk_rope_dim", "v_dim",
+        "shared_experts", "dense_layers", "route_scale")}}
 
 
 class _TraceTransformerModule(nn.Module):
@@ -189,18 +264,27 @@ class _TraceTransformerModule(nn.Module):
                 c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
                 c.n_heads, c.n_layers, c.d_ff, c.passes, c.rope_theta,
                 c.norm_eps, c.dtype, name="encoder")
-        else:
+        elif c.block == "moe":
             backbone = MoeDecoder(
                 c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
                 c.n_heads, c.n_kv_heads, c.head_dim, c.n_experts,
                 c.experts_per_span, c.d_expert, c.rope_layout,
                 c.window_layout, c.window, c.rope_theta, c.norm_eps,
                 c.dtype, c.param_dtype, name="encoder")
+        else:
+            backbone = LatentMoeDecoder(
+                c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
+                c.n_heads, c.n_layers, c.dense_layers, c.q_rank, c.kv_rank,
+                c.qk_nope_dim, c.qk_rope_dim, c.v_dim, c.d_ff, c.n_experts,
+                c.experts_per_span, c.d_expert, c.shared_experts,
+                c.route_scale, c.rope_theta, c.norm_eps, c.dtype,
+                c.param_dtype, name="encoder")
         h = backbone(categorical, continuous, mask, deterministic,
                      positions=positions, segments=segments)
-        if c.block == "moe":
-            # (n_layers, n_experts) assignments of the call, for whoever
-            # applies with the collection mutable (score_packed_counted)
+        if c.routed:
+            # (routed layers, n_experts) assignments of the call, for
+            # whoever applies with the collection mutable
+            # (score_packed_counted)
             h, load = h
             if not self.is_initializing():    # params alone are variables
                 self.sow("moe", "load", load)
@@ -234,13 +318,13 @@ class TraceTransformer:
         score_packed = jax.jit(self._score_packed_impl)
         self.score_packed = jitstats.track_jit("transformer.score_packed",
                                                score_packed)
-        # a block that counts on the device what a call did (the routed
+        # a block that counts on the device what a call did (a routed
         # block: assignments by layer and expert) has a second entry that
         # returns the counts beside the scores, one program and one fetch;
         # None for a block that counts nothing. The engine runs this one
         # where there is one.
         self.score_packed_counted = None
-        if self.cfg.block == "moe":
+        if self.cfg.routed:
             score_packed_counted = jax.jit(self._score_packed_counted_impl)
             self.score_packed_counted = jitstats.track_jit(
                 "transformer.score_packed_counted", score_packed_counted)
@@ -285,8 +369,10 @@ class TraceTransformer:
                                    segments, positions):
         """``score_packed`` and, from the router's own top-k, what the
         call's real spans were assigned: how many assignments in all
-        (spans x experts_per_span x n_layers) and the busiest (layer,
-        expert)'s over the mean one's, under the names they have on the
+        (spans x experts_per_span x routed layers), the busiest (layer,
+        expert)'s over the mean one's, and the fewest experts that hold a
+        span in any routed layer (a collapsed routing reads
+        experts_per_span here), under the names they have on the
         ``tpu/score`` span."""
         mask = segments > 0
         (span_logit, _), state = self.module.apply(
@@ -298,7 +384,8 @@ class TraceTransformer:
             return jax.nn.sigmoid(span_logit), {
                 "moe.assignments": total,
                 "moe.load_max_over_mean":
-                    load.max() * load.size / jnp.maximum(total, 1)}
+                    load.max() * load.size / jnp.maximum(total, 1),
+                "moe.experts_busy_min": (load > 0).sum(axis=-1).min()}
 
     def loss_fn(self, variables, categorical, continuous, mask,
                 span_labels, trace_labels, rngs=None):

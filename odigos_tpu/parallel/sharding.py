@@ -17,6 +17,12 @@ over the mesh from parallel.mesh:
   (``layers.each_device_its_rows``, which asks for the mesh the plan
   traces its call inside): held by a test on four virtual CPU devices
   and a compile for a described v5e 2 x 2, not yet run on four chips
+* latent routed block: the two expansions out of the latents (q_b_proj,
+  kv_b_proj: columns are heads) and the shared expert's gate and up
+  columns on "model", out / down / shared down rows on "model"; the two
+  compressions (q_a_proj, kv_a_proj: every head reads the whole latent)
+  and the latent norms replicated; the router, its selection bias and the
+  expert kernels whole on every device, as the routed block's
 * autoencoder decoder ffn + wide vocab heads: d_ff / vocab on "model"
 * embedding tables + layernorms + small heads: replicated
 * batch (packed-row / trace) axis of inputs: "data"
@@ -101,7 +107,8 @@ PARTITION_SPECS = {
 # parameters that only ever sit whole on a device: a plan whose mesh has
 # a "model" axis over 1 refuses a model that holds any (the rule that
 # places them, below, says why)
-WHOLE_ONLY = r"block_\d+/(router|experts_(gate|up|down))/kernel$"
+WHOLE_ONLY = (r"block_\d+/(router_bias|(router|experts_(gate|up|down))"
+              r"/kernel)$")
 
 # First-match-wins (re.search over the '/'-joined param path). The
 # catch-all replicates embeddings, norms, biases, and small heads —
@@ -117,6 +124,16 @@ PARTITION_RULES: tuple[tuple[str, P], ...] = (
     # gate/up columns d_ff; out and down contract over them
     (r"block_\d+/(q|k|v|gate|up)_proj/kernel$", P(None, "model")),
     (r"block_\d+/(o|down)_proj/kernel$", P("model", None)),
+    # the latent routed block: a dense layer's gate/up/down_proj and every
+    # layer's o_proj fall under the two rules above. The expansions out of
+    # the latents have a head's columns side by side, as q/k/v_proj have,
+    # and the shared expert is a SwiGLU like the decoder block's; the
+    # compressions into the latents (q_a_proj, kv_a_proj) are read whole
+    # by every head and stay replicated, with the latent norms
+    (r"block_\d+/((q|kv)_b_proj|shared_(gate|up))/kernel$",
+     P(None, "model")),
+    (r"block_\d+/shared_down/kernel$", P("model", None)),
+    (r"block_\d+/(q|kv)_a_proj/kernel$", P()),
     # the routed block's q/k/v/o_proj are 2D and fall under the two rules
     # above. Its router and its expert kernels (experts_gate, experts_up:
     # (n_experts, d_model, d_expert); experts_down: (n_experts, d_expert,
@@ -124,8 +141,9 @@ PARTITION_RULES: tuple[tuple[str, P], ...] = (
     # by expert, a data-dependent split that no static spec of d_expert
     # or of the expert axis divides, and an expert axis over "model" needs
     # a layer told which experts it holds and an exchange of spans
-    # (ROADMAP C5). WHOLE_ONLY below refuses a "model" axis for them
-    # rather than replicating 95% of the parameters in silence.
+    # (ROADMAP C5). The latent routed block's selection bias goes with its
+    # router. WHOLE_ONLY below refuses a "model" axis for them rather than
+    # replicating 95% of the parameters in silence.
     (WHOLE_ONLY, P()),
     (r"dec_ff1/kernel$", P(None, "model")),            # autoencoder decoder
     (r"dec_ff2/kernel$", P("model", None)),

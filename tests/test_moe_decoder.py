@@ -280,9 +280,12 @@ def test_the_grouped_products_equal_a_dense_pass_and_padding_is_inert():
     gate, up = (jnp.asarray(rng.normal(size=(E, d, f)) / 6, jnp.float32)
                 for _ in range(2))
     down = jnp.asarray(rng.normal(size=(E, f, d)) / 4, jnp.float32)
-    out, load_ = layers.routed_experts(h, r, real, gate, up, down, k)
-    top, chosen = jax.lax.top_k(r, k)
-    weight = jax.nn.softmax(top, axis=-1)
+    chosen, weight = layers.top_softmax(r, k)
+    out, load_ = layers.routed_experts(h, chosen, weight, real, gate, up,
+                                       down)
+    top, again = jax.lax.top_k(r, k)       # the rule: top-k, then softmax
+    assert np.array_equal(chosen, again)
+    assert np.array_equal(weight, jax.nn.softmax(top, axis=-1))
     dense = jnp.einsum("tef,efd->ted", jax.nn.relu(
         jnp.einsum("td,edf->tef", h, gate))
         * jnp.einsum("td,edf->tef", h, up), down)
@@ -294,8 +297,9 @@ def test_the_grouped_products_equal_a_dense_pass_and_padding_is_inert():
     assert np.array_equal(load_, np.bincount(
         np.asarray(chosen)[np.asarray(real)].ravel(), minlength=E))
     noisy = jnp.where(real[:, None], h, jnp.nan)    # whatever padding holds
-    again, _ = layers.routed_experts(noisy, jnp.where(real[:, None], r, 9.0),
-                                     real, gate, up, down, k)
+    again, _ = layers.routed_experts(
+        noisy, *layers.top_softmax(jnp.where(real[:, None], r, 9.0), k),
+        real, gate, up, down)
     assert np.array_equal(out, again)
 
 
@@ -315,6 +319,12 @@ def test_the_pallas_grouped_products_equal_the_ragged_ones():
     got = layers._experts_gmm(x, gate, up, down, load_, interpret=True)
     n = int(load_.sum())
     np.testing.assert_allclose(got[:n], want[:n], atol=1e-5)
+    # the gate's activation is the caller's, in both branches alike
+    silu = layers._experts_ragged(x, gate, up, down, load_, jax.nn.silu)
+    got = layers._experts_gmm(x, gate, up, down, load_, jax.nn.silu,
+                              interpret=True)
+    np.testing.assert_allclose(got[:n], silu[:n], atol=1e-5)
+    assert not np.allclose(silu[:n], want[:n], atol=1e-3)
 
 
 @pytest.fixture(scope="module")
@@ -340,25 +350,35 @@ def one_chip(four_chips):
     return SingleDeviceSharding(four_chips[0])
 
 
-def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(one_chip):
-    """``routed_experts`` at the published widths and a 256-row call's
-    spans, compiled for the v5e: the three grouped products are Pallas
+@pytest.mark.parametrize("d, f, k, rule", [
+    (2560, 768, 6, "top_softmax"), (2048, 1536, 4, "biased_sigmoid")])
+def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(
+        one_chip, d, f, k, rule):
+    """``routed_experts`` at the published widths of each routed
+    configuration and a 256-row call's spans, under its own rule of
+    choice, compiled for the v5e: the three grouped products are Pallas
     kernels (``gmm``) under the ``mlp`` scope, none is XLA's own
     ragged-dot kernel (which keeps no scope in the trace), and the
     temporaries stay under 1.5 GB."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    T, d, E, f, k = 256 * 64, 2560, 64, 768, 6
+    T, E = 256 * 64, 64
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    def route(h, r, real, g, u, dn):
+        if rule == "top_softmax":
+            return layers.routed_experts(h, *layers.top_softmax(r, k),
+                                         real, g, u, dn)
+        return layers.routed_experts(
+            h, *layers.biased_sigmoid(r, r[0], k, 1.8), real, g, u, dn,
+            jax.nn.silu)
+
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        compiled = jax.jit(
-            lambda h, r, real, g, u, dn: layers.routed_experts(
-                h, r, real, g, u, dn, k)).lower(
+        compiled = jax.jit(route).lower(
             S((T, d), jnp.bfloat16), S((T, E), jnp.float32),
             S((T,), jnp.bool_), S((E, d, f), jnp.bfloat16),
             S((E, d, f), jnp.bfloat16), S((E, f, d), jnp.bfloat16)).compile()
@@ -373,8 +393,10 @@ def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
-def test_four_chips_each_route_their_own_rows(four_chips):
-    """The whole model at ``SMALL`` compiled for a v5e 2 x 2 as the plan
+@pytest.mark.parametrize("arch", ["moe_decoder", "latent_moe_decoder"])
+def test_four_chips_each_route_their_own_rows(four_chips, arch):
+    """The whole model at ``SMALL`` (either routed block's) compiled for a
+    v5e 2 x 2 as the plan
     traces it (``mesh {data: 4}``, inside the mesh): the partitioner
     refuses a bare Pallas kernel ("Mosaic kernels cannot be automatically
     partitioned"), so each device runs the routed feed-forward on its own
@@ -384,11 +406,12 @@ def test_four_chips_each_route_their_own_rows(four_chips):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from odigos_tpu.parallel.sharding import compile_plan
 
+    small = arch_case(arch)[1].SMALL
     mesh = Mesh(np.array(four_chips).reshape(4), ("data",))
-    model = TraceTransformer(make_model_config("transformer", SMALL))
+    model = TraceTransformer(make_model_config("transformer", small))
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     plan = compile_plan(model, mesh, variables=shapes)
-    rows, L = 8, SMALL["max_len"]
+    rows, L = 8, small["max_len"]
 
     def S(shape, dtype, spec):
         return jax.ShapeDtypeStruct(shape, dtype,
@@ -408,7 +431,8 @@ def test_four_chips_each_route_their_own_rows(four_chips):
         jax.config.update("jax_enable_compilation_cache", True)
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "pallas_call" in line]
-    assert len(kernels) == 3 * SMALL["n_layers"]
+    assert len(kernels) == 3 * (small["n_layers"]
+                                - small.get("dense_layers", 0))
     assert "all-gather" not in text and "all-to-all" not in text
 
 
@@ -503,6 +527,8 @@ def test_the_config_refuses_what_does_not_compose():
         routed(window=0)
     with pytest.raises(ValueError, match="passes"):
         routed(passes=2)
+    with pytest.raises(ValueError, match="multiple of n_kv_heads"):
+        routed(n_kv_heads=0)
     with pytest.raises(ValueError, match="routed block's"):
         make_model_config("transformer", {"n_experts": 8})
     with pytest.raises(ValueError, match="routed block's"):
@@ -514,7 +540,14 @@ def test_the_config_refuses_what_does_not_compose():
 
 
 def test_each_block_kind_states_its_scopes():
-    assert set(BLOCK_PARTS) == {"encoder", "decoder", "moe"}
+    assert set(BLOCK_PARTS) == {"encoder", "decoder", "moe", "latent_moe"}
+    for arch in ("moe_decoder", "latent_moe_decoder"):  # both count
+        cfg = make_model_config("transformer", arch_case(arch)[1].SMALL)
+        assert cfg.routed and set(BLOCK_PARTS[cfg.block]) >= {"route", "mlp"}
+        assert cfg.call_counters == {
+            "moe.assignments": "odigos_anomaly_expert_assignments_total"}
+    assert not TransformerConfig().routed
+    assert TransformerConfig().call_counters == {}
     assert "route" in BLOCK_PARTS["moe"] and "norm" in BLOCK_PARTS["moe"]
     assert set(BLOCK_PARTS["moe"]) <= set(ARCH.PARTS)
     assert set(ARCH.PARTS.values()) == {"attn", "mlp", "route", "norm",
@@ -624,6 +657,9 @@ def test_score_spans_say_what_the_model_is_and_what_each_call_routed():
             # 8 experts, 2 a span: the busiest of 64 (layer, expert)
             # pairs lies between the mean and every span of a layer
             assert 1.0 <= a["moe.load_max_over_mean"] <= 8 / 2
+            # the fewest experts busy in any of the 8 layers: at least
+            # the 2 a span takes, at most all 8
+            assert 2 <= a["moe.experts_busy_min"] <= 8
         after = meter.snapshot()
         assert after[EXPERT_ASSIGNMENTS_METRIC] \
             - before.get(EXPERT_ASSIGNMENTS_METRIC, 0.0) \
@@ -685,7 +721,8 @@ def test_the_cell_is_the_benchmarks_by_entries_alone():
                              "experts_roofline.backlog"}
     for name in ("step_route_ms.backlog", "experts_roofline.backlog"):
         m = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and m["moves"] == "spans_per_s"
+        # a later routed cell appends its name; the accepted one stays
+        assert m["workloads"][0] == CELL and m["moves"] == "spans_per_s"
         assert m["layer"] == "model step"
 
 
