@@ -31,6 +31,7 @@ from flax import linen as nn
 from jax.sharding import PartitionSpec
 
 from ..features.featurizer import CAT_FIELDS
+from . import route_kernels
 
 
 class SpanEmbedder(nn.Module):
@@ -463,34 +464,26 @@ def biased_sigmoid(logits: jnp.ndarray, bias: jnp.ndarray, k: int,
                                                keepdims=True)
 
 
-def routed_experts(h: jnp.ndarray, which: jnp.ndarray, weight: jnp.ndarray,
-                   real: jnp.ndarray, gate: jnp.ndarray, up: jnp.ndarray,
-                   down: jnp.ndarray, act=nn.relu,
-                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The routed feed-forward over ``h`` (spans, d): each real span goes
-    to the k experts ``which`` (spans, k) names, weighted by ``weight``
-    (spans, k; float32), whatever rule chose them (``top_softmax``,
-    ``biased_sigmoid``); ``act`` is the gate's activation. The experts
-    run as grouped products over the assignments sorted by expert: no
-    span is dropped and there is no capacity. A slot that holds no span
-    (``real`` False) is sorted past the last expert, so it enters no
-    product, and comes back zero. Returns (spans, d) and the assignments
-    each expert took, (n_experts,) int32."""
-    spans, k = which.shape
-    n_experts = gate.shape[0]
+def _into_expert_order(h, order, k):
+    """The span rows ``h`` (spans, d) in expert order by XLA's gather, up
+    to whole tiles of the grouped products. Its table is (spans, d), which
+    the chip holds close: every slot's k rows at memory speed, those that
+    hold no span too."""
     with jax.named_scope("route"):
-        weight = weight * real[:, None]
-        expert = jnp.where(real[:, None], which, n_experts).reshape(-1)
-        order = jnp.argsort(expert, stable=True)
-        load = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0,
-                       dtype=jnp.int32)
-        whole = -order.shape[0] % GROUP_ROWS      # up to whole tiles
-        sorted_h = h[jnp.pad(order, (0, whole)) // k]
+        whole = -order.shape[0] % GROUP_ROWS
+        return h[jnp.pad(order, (0, whole)) // k]
+
+
+def _back_by_gather(h, order, weight, real, load, gate, up, down, act,
+                    experts):
+    """The grouped products ``experts`` over the rows of ``h`` in expert
+    order, and their rows back to span order by XLA's gather, weighed and
+    summed: every slot's k rows ride both gathers, those that hold no span
+    too. Returns (spans, d) in ``h.dtype`` and the rows moved."""
+    spans, k = weight.shape
+    y = _into_expert_order(h, order, k)
     with jax.named_scope("mlp"):
-        y = jax.lax.platform_dependent(sorted_h, gate, up, down, load,
-                                       tpu=partial(_experts_gmm, act=act),
-                                       default=partial(_experts_ragged,
-                                                       act=act))
+        y = experts(y, gate, up, down, load, act)
     with jax.named_scope("route"):
         # back to span order with the k-th choices as the leading axis,
         # (k, spans, d): summed over whole slabs, where (spans, k, d)
@@ -501,7 +494,78 @@ def routed_experts(h: jnp.ndarray, which: jnp.ndarray, weight: jnp.ndarray,
         # that it cannot be a NaN either
         y = jnp.where(real[None, :, None], y, 0).astype(jnp.float32)
         out = jnp.sum(y * weight.T[:, :, None], axis=0)
-    return out.astype(h.dtype), load
+    return out.astype(h.dtype), jnp.int32(2 * order.shape[0])
+
+
+def _back_by_dma(h, order, weight, real, load, gate, up, down, act,
+                 interpret: bool = False):
+    """The ``megablox.gmm`` products over the rows of ``h`` in expert
+    order, and their rows back by the DMA kernels of
+    ``route_kernels.combine``: a real assignment's row is moved once, a
+    slot that holds no span's not at all. Returns (spans, d) in
+    ``h.dtype`` and the rows moved."""
+    spans, k = weight.shape
+    y = _into_expert_order(h, order, k)
+    with jax.named_scope("mlp"):
+        y = _experts_gmm(y, gate, up, down, load, act, interpret)
+    with jax.named_scope("route"):
+        n_real = jnp.sum(real, dtype=jnp.int32) * k
+        out = route_kernels.combine(y, order, n_real, weight, real, h.dtype,
+                                    interpret)
+    return out, order.shape[0] + n_real
+
+
+# Shape-bucketing strategy of the jitted function here (the package hygiene
+# test asserts every jit path in models/ declares one)
+SHAPE_BUCKETING = {
+    "routed_experts": "traced inside the scoring entry that calls it, on "
+                      "that call's rows: the engine's BucketLadder rungs",
+}
+
+
+# jitted so that a stack's layers share one trace and one lowering: the
+# route's kernels take seconds a layer to lower, and a call's set-up pays
+# every lowering of its program
+@partial(jax.jit, static_argnames="act")
+def routed_experts(h: jnp.ndarray, which: jnp.ndarray, weight: jnp.ndarray,
+                   real: jnp.ndarray, gate: jnp.ndarray, up: jnp.ndarray,
+                   down: jnp.ndarray, act=nn.relu,
+                   ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The routed feed-forward over ``h`` (spans, d): each real span goes
+    to the k experts ``which`` (spans, k) names, weighted by ``weight``
+    (spans, k; float32), whatever rule chose them (``top_softmax``,
+    ``biased_sigmoid``); ``act`` is the gate's activation. The experts
+    run as grouped products over the assignments sorted by expert: no
+    span is dropped and there is no capacity. A slot that holds no span
+    (``real`` False) is sorted past the last expert, so it enters no
+    product, and comes back zero. The rows go into expert order by XLA's
+    gather (``_into_expert_order``); they come back by DMA on the TPU
+    where ``route_kernels`` takes their dtype and width
+    (``_back_by_dma``), by XLA's gather elsewhere. Returns (spans, d),
+    the assignments each expert took, (n_experts,) int32, and the rows
+    the route moved, int32."""
+    spans, k = which.shape
+    n_experts = gate.shape[0]
+    with jax.named_scope("route"):
+        weight = weight * real[:, None]
+        expert = jnp.where(real[:, None], which, n_experts).reshape(-1)
+        order = jnp.argsort(expert, stable=True)
+        load = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0,
+                       dtype=jnp.int32)
+    tpu = partial(_back_by_dma, act=act) if route_kernels.supported(h) \
+        else partial(_back_by_gather, act=act, experts=_experts_gmm)
+    out, moved = jax.lax.platform_dependent(
+        h, order, weight, real, load, gate, up, down, tpu=tpu,
+        default=partial(_back_by_gather, act=act, experts=_experts_ragged))
+    return out, load, moved
+
+
+def routed_counts(counts) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """What a stack's routed layers counted, each ``(load, moved)``:
+    the loads one row a layer, (routed layers, n_experts), and the rows
+    the route moved in all."""
+    loads, moved = zip(*counts)
+    return jnp.stack(loads), sum(moved)
 
 
 # the mesh axis a plan splits the packed rows over (parallel/sharding.py)
@@ -509,26 +573,26 @@ ROWS_AXIS = "data"
 
 
 def each_device_its_rows(route):
-    """``route(h, which, weight, real, gate, up, down) -> (out, load)``
-    (``routed_experts``) run by
+    """``route(h, which, weight, real, gate, up, down) -> (out, load,
+    moved)`` (``routed_experts``) run by
     each device on its own rows where the mesh in context splits the rows
     (a plan traces its call inside its mesh): the grouped products are
     Pallas kernels on the TPU, which the partitioner cannot split
     ("Mosaic kernels cannot be automatically partitioned"), and a span's
     experts need no other device's rows, the kernels being whole on every
-    device. The loads are summed over the devices. With no mesh in
-    context, or one device along the axis, ``route`` itself."""
+    device. The loads and the rows moved are summed over the devices. With
+    no mesh in context, or one device along the axis, ``route`` itself."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.shape.get(ROWS_AXIS, 1) == 1:
         return route
 
     def local(*args):
-        out, load = route(*args)
-        return out, jax.lax.psum(load, ROWS_AXIS)
+        out, *counts = route(*args)
+        return out, *(jax.lax.psum(c, ROWS_AXIS) for c in counts)
 
     rows, whole = PartitionSpec(ROWS_AXIS), PartitionSpec()
     return jax.shard_map(local, in_specs=(rows,) * 4 + (whole,) * 3,
-                         out_specs=(rows, whole), check_vma=False)
+                         out_specs=(rows, whole, whole), check_vma=False)
 
 
 class MoeBlock(nn.Module):
@@ -590,10 +654,10 @@ class MoeBlock(nn.Module):
                                  self.param_dtype, self.dtype)
         which, weight = top_softmax(logits.reshape(-1, self.n_experts),
                                     self.experts_per_span)
-        h, load = each_device_its_rows(routed_experts)(
+        h, *counts = each_device_its_rows(routed_experts)(
             h.reshape(-1, self.d_model), which, weight, mask.reshape(-1),
             *kernels)
-        return x + h.reshape(x.shape), load
+        return x + h.reshape(x.shape), counts
 
 
 # How the routed stack's embedder is drawn: each table at unit variance
@@ -616,8 +680,9 @@ class MoeDecoder(nn.Module):
     block rotates its queries and keys and whether its attention is cut to
     the last ``window`` spans of the trace: the two masks are built once
     and each block takes its own. Attention is causal within a trace, as
-    the looped stack's. Returns the normed output and, (n_layers,
-    n_experts), the assignments each expert of each layer took."""
+    the looped stack's. Returns the normed output, (n_layers, n_experts)
+    the assignments each expert of each layer took, and the rows the
+    route moved (``routed_counts``)."""
 
     service_vocab: int
     name_vocab: int
@@ -659,20 +724,20 @@ class MoeDecoder(nn.Module):
         with jax.named_scope("attn"):
             cos, sin = rotary_tables(positions, self.head_dim,
                                      self.rope_theta, self.dtype)
-        loads = []
+        counts = []
         for i, (rope, window) in enumerate(zip(self.rope_layout,
                                                self.window_layout)):
-            x, load = MoeBlock(
+            x, count = MoeBlock(
                 self.d_model, self.n_heads, self.n_kv_heads, self.head_dim,
                 self.n_experts, self.experts_per_span, self.d_expert,
                 bool(rope), self.dtype, self.param_dtype, self.norm_eps,
                 name=f"block_{i}")(x, mask, masks[bool(window)], cos, sin)
-            loads.append(load)
+            counts.append(count)
         with jax.named_scope("norm"):
             x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                            param_dtype=self.param_dtype,
                            name="final_rms")(x)
-        return x, jnp.stack(loads)
+        return (x, *routed_counts(counts))
 
 
 # scale of the selection bias's draw (normal, float32, rounded once to the
@@ -706,8 +771,8 @@ class LatentMoeBlock(nn.Module):
     plus, where ``shared_experts`` > 0, one SiLU-gated expert of width
     ``shared_experts * d_expert`` that every span takes; that of a layer
     that is not routed is one SwiGLU of width ``d_ff``, and it returns no
-    load. No bias but the router's selection bias; parameters are held in
-    ``param_dtype``."""
+    counts. No bias but the router's selection bias; parameters are held
+    in ``param_dtype``."""
 
     d_model: int
     n_heads: int
@@ -801,22 +866,24 @@ class LatentMoeBlock(nn.Module):
         which, weight = biased_sigmoid(
             logits.reshape(-1, self.n_experts), bias, self.experts_per_span,
             self.route_scale)
-        y, load = each_device_its_rows(partial(routed_experts, act=nn.silu))(
+        y, *counts = each_device_its_rows(
+            partial(routed_experts, act=nn.silu))(
             h.reshape(-1, self.d_model), which, weight, mask.reshape(-1),
             *kernels)
         x = x + y.reshape(x.shape)
         if self.shared_experts:
             x = x + swiglu(h, self.shared_experts * self.d_expert,
                            "shared_gate", "shared_up", "shared_down")
-        return x, load
+        return x, counts
 
 
 class LatentMoeDecoder(nn.Module):
     """Embedding trunk + a stack of latent routed blocks, each applied
     once: the first ``dense_layers`` with a dense feed-forward, the rest
     routed. Every layer rotates its rotary columns; attention is causal
-    within a trace. Returns the normed output and, (routed layers,
-    n_experts), the assignments each expert of each routed layer took."""
+    within a trace. Returns the normed output, (routed layers, n_experts)
+    the assignments each expert of each routed layer took, and the rows
+    the route moved (``routed_counts``)."""
 
     service_vocab: int
     name_vocab: int
@@ -860,9 +927,9 @@ class LatentMoeDecoder(nn.Module):
         with jax.named_scope("latent"):
             cos, sin = rotary_tables(positions, self.qk_rope_dim,
                                      self.rope_theta, self.dtype)
-        loads = []
+        counts = []
         for i in range(self.n_layers):
-            x, load = LatentMoeBlock(
+            x, count = LatentMoeBlock(
                 self.d_model, self.n_heads, self.q_rank, self.kv_rank,
                 self.qk_nope_dim, self.qk_rope_dim, self.v_dim, self.d_ff,
                 self.n_experts, self.experts_per_span, self.d_expert,
@@ -870,10 +937,10 @@ class LatentMoeDecoder(nn.Module):
                 i >= self.dense_layers, self.dtype, self.param_dtype,
                 self.norm_eps, name=f"block_{i}")(x, mask, attn_mask, cos,
                                                   sin)
-            if load is not None:
-                loads.append(load)
+            if count is not None:
+                counts.append(count)
         with jax.named_scope("norm"):
             x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                            param_dtype=self.param_dtype,
                            name="final_rms")(x)
-        return x, jnp.stack(loads)
+        return (x, *routed_counts(counts))

@@ -20,7 +20,7 @@ attention through ``q_rank`` and ``kv_rank`` with one rotary key a span, a
 sigmoid router with a selection bias over SiLU-gated experts beside
 ``shared_experts`` shared ones, the first ``dense_layers`` layers dense at
 ``d_ff``). A routed kind's ``score_packed_counted`` also returns what the
-router assigned.
+router assigned and how many rows its route moved.
 """
 
 from __future__ import annotations
@@ -282,12 +282,13 @@ class _TraceTransformerModule(nn.Module):
         h = backbone(categorical, continuous, mask, deterministic,
                      positions=positions, segments=segments)
         if c.routed:
-            # (routed layers, n_experts) assignments of the call, for
-            # whoever applies with the collection mutable
-            # (score_packed_counted)
-            h, load = h
+            # (routed layers, n_experts) assignments of the call and the
+            # rows its route moved, for whoever applies with the
+            # collection mutable (score_packed_counted)
+            h, load, moved = h
             if not self.is_initializing():    # params alone are variables
                 self.sow("moe", "load", load)
+                self.sow("moe", "rows_moved", moved)
         with jax.named_scope("head"):
             span_logit = nn.Dense(1, dtype=jnp.float32,
                                   name="span_head")(h)[..., 0]
@@ -372,20 +373,25 @@ class TraceTransformer:
         (spans x experts_per_span x routed layers), the busiest (layer,
         expert)'s over the mean one's, and the fewest experts that hold a
         span in any routed layer (a collapsed routing reads
-        experts_per_span here), under the names they have on the
-        ``tpu/score`` span."""
+        experts_per_span here); and the rows the route moved, counted by
+        the path that moved them (two a real assignment where the TPU's
+        kernels move them, two a slot's choice, real or not, where XLA's
+        gathers do), under the names they have on the ``tpu/score``
+        span."""
         mask = segments > 0
         (span_logit, _), state = self.module.apply(
             variables, categorical, continuous, mask,
             positions=positions, segments=segments, mutable=["moe"])
         (load,) = state["moe"]["load"]
+        (moved,) = state["moe"]["rows_moved"]
         with jax.named_scope("head"):
             total = load.sum()
             return jax.nn.sigmoid(span_logit), {
                 "moe.assignments": total,
                 "moe.load_max_over_mean":
                     load.max() * load.size / jnp.maximum(total, 1),
-                "moe.experts_busy_min": (load > 0).sum(axis=-1).min()}
+                "moe.experts_busy_min": (load > 0).sum(axis=-1).min(),
+                "moe.rows_moved": moved}
 
     def loss_fn(self, variables, categorical, continuous, mask,
                 span_labels, trace_labels, rngs=None):

@@ -34,7 +34,8 @@ from odigos_tpu.models.transformer import TraceTransformer
 from odigos_tpu.pdata import synthesize_traces
 from odigos_tpu.serving import EngineConfig, ScoringEngine
 from odigos_tpu.training import make_model_config
-from tests.test_moe_decoder import load, logit, packed_row
+from tests.test_moe_decoder import (check_way_back, check_whole_branch,
+                                     load, logit, packed_row)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 2**31 + 37
@@ -345,8 +346,8 @@ def test_the_grouped_products_equal_a_dense_pass_under_this_rule():
                 for _ in range(2))
     down = jnp.asarray(rng.normal(size=(E, f, d)) / 4, jnp.float32)
     which, weight = layers.biased_sigmoid(r, bias, k, s)
-    out, load_ = layers.routed_experts(h, which, weight, real, gate, up,
-                                       down, jax.nn.silu)
+    out, load_, moved = layers.routed_experts(h, which, weight, real, gate,
+                                              up, down, jax.nn.silu)
     dense = jnp.einsum("tef,efd->ted", jax.nn.silu(
         jnp.einsum("td,edf->tef", h, gate))
         * jnp.einsum("td,edf->tef", h, up), down)
@@ -357,12 +358,30 @@ def test_the_grouped_products_equal_a_dense_pass_under_this_rule():
     assert int(load_.sum()) == int(real.sum()) * k
     assert np.array_equal(load_, np.bincount(
         np.asarray(which)[np.asarray(real)].ravel(), minlength=E))
+    assert int(moved) == 2 * T * k      # XLA's gathers: every slot's, twice
     noisy = jnp.where(real[:, None], h, jnp.nan)    # whatever padding holds
-    again, _ = layers.routed_experts(noisy, which, weight, real, gate, up,
-                                     down, jax.nn.silu)
+    again, _, _ = layers.routed_experts(noisy, which, weight, real, gate, up,
+                                        down, jax.nn.silu)
     assert np.array_equal(out, again)
-    relu, _ = layers.routed_experts(h, which, weight, real, gate, up, down)
+    relu, _, _ = layers.routed_experts(h, which, weight, real, gate, up,
+                                       down)
     assert not np.allclose(relu, out)               # the caller's gate
+
+
+@pytest.mark.parametrize("spans, real, idle", [
+    # one expert takes nothing; the last tile of sorted rows part empty
+    (300, [i % 6 != 1 for i in range(300)], (2,)),
+    # slots that hold no span in the middle and at the end of the call
+    (160, [not 40 <= i < 64 and i < 150 for i in range(160)], ()),
+    # a slot count no span tile divides, every slot a span
+    (40, [True] * 40, (3,))])
+def test_the_way_back_by_dma_equals_xlas_gather(spans, real, idle):
+    check_way_back(spans, 4, 8, real, idle, seed=1)
+
+
+def test_the_tpus_branch_equals_the_gathers_around_the_same_products():
+    check_whole_branch(300, 4, 8, [i % 5 != 0 for i in range(300)],
+                       jax.nn.silu, seed=1)
 
 
 def test_padding_slots_change_no_real_spans_score(built):
@@ -685,6 +704,9 @@ def test_score_spans_say_what_the_model_is_and_what_each_call_routed():
             # of 8 experts, at least the 2 a span takes hold one
             assert 2 <= a["moe.experts_busy_min"] <= 8
             assert isinstance(a["moe.experts_busy_min"], int)
+            # XLA's gathers here: every dispatched slot's choices, twice
+            slots = np.prod([int(x) for x in a["device.shape"].split("x")])
+            assert a["moe.rows_moved"] == 2 * slots * per_span
         after = meter.snapshot()
         assert after[EXPERT_ASSIGNMENTS_METRIC] \
             - before.get(EXPERT_ASSIGNMENTS_METRIC, 0.0) \
@@ -710,6 +732,17 @@ def test_a_collapsed_routing_reads_in_the_programs_own_count(built):
     _, counts = model.score_packed_counted(collapsed, *args)
     assert int(counts["moe.experts_busy_min"]) == 2
     assert float(counts["moe.load_max_over_mean"]) == pytest.approx(8 / 2)
+
+
+def test_the_rows_moved_are_counted_by_the_path_that_moved_them(built):
+    """``moe.rows_moved`` beside the other counts: on the CPU every slot's
+    k rows into expert order and back in every routed layer, none in the
+    dense one."""
+    model, variables = built
+    args = packed_row([6, 5, 4], rows=2)
+    _, counts = model.score_packed_counted(variables, *args)
+    assert int(counts["moe.rows_moved"]) == 2 * args[2].size * SMALL[
+        "experts_per_span"] * ROUTED
 
 
 def test_serving_names_no_model_or_configuration():

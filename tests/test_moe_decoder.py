@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import json
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ from benchmark import architectures, gen, judge, reference, run
 from benchmark.tests.conftest import arch_case, stood_in_trace  # noqa: F401
 from odigos_tpu.features import featurize
 from odigos_tpu.features.featurizer import pack_sequences
-from odigos_tpu.models import layers
+from odigos_tpu.models import layers, route_kernels
 from odigos_tpu.models.layers import BLOCK_PARTS
 from odigos_tpu.models.transformer import TraceTransformer, TransformerConfig
 from odigos_tpu.pdata import synthesize_traces
@@ -281,8 +282,8 @@ def test_the_grouped_products_equal_a_dense_pass_and_padding_is_inert():
                 for _ in range(2))
     down = jnp.asarray(rng.normal(size=(E, f, d)) / 4, jnp.float32)
     chosen, weight = layers.top_softmax(r, k)
-    out, load_ = layers.routed_experts(h, chosen, weight, real, gate, up,
-                                       down)
+    out, load_, moved = layers.routed_experts(h, chosen, weight, real, gate,
+                                              up, down)
     top, again = jax.lax.top_k(r, k)       # the rule: top-k, then softmax
     assert np.array_equal(chosen, again)
     assert np.array_equal(weight, jax.nn.softmax(top, axis=-1))
@@ -296,8 +297,9 @@ def test_the_grouped_products_equal_a_dense_pass_and_padding_is_inert():
     assert int(load_.sum()) == int(real.sum()) * k
     assert np.array_equal(load_, np.bincount(
         np.asarray(chosen)[np.asarray(real)].ravel(), minlength=E))
+    assert int(moved) == 2 * T * k      # XLA's gathers: every slot's, twice
     noisy = jnp.where(real[:, None], h, jnp.nan)    # whatever padding holds
-    again, _ = layers.routed_experts(
+    again, _, _ = layers.routed_experts(
         noisy, *layers.top_softmax(jnp.where(real[:, None], r, 9.0), k),
         real, gate, up, down)
     assert np.array_equal(out, again)
@@ -325,6 +327,122 @@ def test_the_pallas_grouped_products_equal_the_ragged_ones():
                               interpret=True)
     np.testing.assert_allclose(got[:n], silu[:n], atol=1e-5)
     assert not np.allclose(silu[:n], want[:n], atol=1e-3)
+
+
+# the routed block's way back on the TPU (``route_kernels.combine``, in
+# interpret mode) against XLA's gather back, for both routed blocks' tests
+D, F = 256, 128      # the narrowest widths the kernels take
+
+
+def routing(spans, k, n_experts, real, idle=(), seed=0):
+    """(which, weight, real, order, load) as ``routed_experts`` makes
+    them: ``real`` (spans,) bool; no span takes an expert in ``idle``."""
+    rng = np.random.default_rng(seed)
+    busy = [e for e in range(n_experts) if e not in idle]
+    which = jnp.asarray(np.stack([rng.choice(busy, k, replace=False)
+                                  for _ in range(spans)]), jnp.int32)
+    real = jnp.asarray(real)
+    weight = jax.nn.softmax(jnp.asarray(rng.normal(size=(spans, k)),
+                                        jnp.float32)) * real[:, None]
+    expert = jnp.where(real[:, None], which, n_experts).reshape(-1)
+    order = jnp.argsort(expert, stable=True)
+    load = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0,
+                   dtype=jnp.int32)
+    return which, weight, real, order, load
+
+
+def sorted_rows(spans, k, seed=0, nan_from=None, exact=False):
+    """Expert rows in sorted order, bfloat16, up to whole tiles of the
+    grouped products; NaN from row ``nan_from`` on (what the products
+    leave past the last assignment is nobody's). ``exact``: sixteenths
+    under 4, whose sums weighted by sixty-fourths float32 holds with no
+    rounding, in any order and with or without a fused multiply-add
+    (which XLA's CPU compiler makes of some of the interpreted kernel's
+    products and not of others)."""
+    rng = np.random.default_rng(seed + 1)
+    rows = spans * k + (-spans * k) % layers.GROUP_ROWS
+    y = np.asarray(rng.normal(size=(rows, D)), np.float32)
+    if exact:
+        y = rng.integers(-64, 64, size=(rows, D)) / 16
+    if nan_from is not None:
+        y[nan_from:] = np.nan
+    return jnp.asarray(y, jnp.bfloat16)
+
+
+def xla_back(y, order, weight, real, dtype):
+    """XLA's gather back and weighted sum, as ``_back_by_gather`` has them."""
+    spans, k = weight.shape
+    g = y[jnp.argsort(order).reshape(spans, k).T]
+    g = jnp.where(real[None, :, None], g, 0).astype(jnp.float32)
+    return jnp.sum(g * weight.T[:, :, None], axis=0).astype(dtype)
+
+
+def check_way_back(spans, k, n_experts, real, idle=(), seed=0):
+    """The kernels' way back equals XLA's: to float32 rounding in float32,
+    bit for bit in bfloat16 on rows and weights whose sums are exact; a
+    slot that holds no span comes back zero; NaN past the last assignment
+    changes nothing."""
+    _, weight, real, order, _ = routing(spans, k, n_experts, real, idle,
+                                        seed)
+    n_real = jnp.sum(real, dtype=jnp.int32) * k
+    y = sorted_rows(spans, k, seed)
+    np.testing.assert_allclose(
+        route_kernels.combine(y, order, n_real, weight, real, jnp.float32,
+                              interpret=True),
+        xla_back(y, order, weight, real, jnp.float32), rtol=2e-6, atol=1e-6)
+    weight = jnp.round(weight * 64) / 64
+    combine = partial(route_kernels.combine, order=order, n_real=n_real,
+                      weight=weight, real=real, dtype=jnp.bfloat16,
+                      interpret=True)
+    exact = sorted_rows(spans, k, seed, exact=True)
+    got = combine(exact)
+    assert np.array_equal(got, xla_back(exact, order, weight, real,
+                                        jnp.bfloat16))
+    assert np.asarray(got, np.float32).any()
+    assert not np.asarray(got, np.float32)[~np.asarray(real)].any()
+    planted = sorted_rows(spans, k, seed, nan_from=int(n_real), exact=True)
+    assert np.array_equal(combine(planted), got)
+
+
+def check_whole_branch(spans, k, n_experts, real, act, seed=0):
+    """The TPU's branch of ``routed_experts`` (XLA's gather in, the
+    ``gmm`` products, the DMA way back), in interpret mode, against the
+    same products between XLA's two gathers: the same rows, a bfloat16
+    rounding apart where the sums' float32 roundings part; and the rows
+    each moved."""
+    _, weight, real, order, load = routing(spans, k, n_experts, real,
+                                           seed=seed)
+    rng = np.random.default_rng(seed + 2)
+    h = jnp.asarray(rng.normal(size=(spans, D)), jnp.bfloat16)
+    gate, up = (jnp.asarray(rng.normal(size=(n_experts, D, F)) / 16,
+                            jnp.bfloat16) for _ in range(2))
+    down = jnp.asarray(rng.normal(size=(n_experts, F, D)) / 11, jnp.bfloat16)
+    args = (h, order, weight, real, load, gate, up, down, act)
+    got, moved = layers._back_by_dma(*args, interpret=True)
+    want, all_moved = layers._back_by_gather(
+        *args, partial(layers._experts_gmm, interpret=True))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=2**-7,
+                               atol=2**-12)
+    # every slot's k rows in, a real assignment's back; XLA's: twice
+    assert int(moved) == spans * k + int(real.sum()) * k
+    assert int(all_moved) == 2 * spans * k
+
+
+@pytest.mark.parametrize("spans, real, idle", [
+    # one expert takes nothing; the last tile of sorted rows part empty
+    (200, [i % 5 != 3 for i in range(200)], (5,)),
+    # slots that hold no span in the middle and at the end of the call
+    (256, [not 90 <= i < 120 and i < 230 for i in range(256)], ()),
+    # a slot count no span tile divides, every slot a span
+    (72, [True] * 72, (0, 7))])
+def test_the_way_back_by_dma_equals_xlas_gather(spans, real, idle):
+    check_way_back(spans, 6, 8, real, idle)
+
+
+def test_the_tpus_branch_equals_the_gathers_around_the_same_products():
+    check_whole_branch(200, 6, 8, [i % 7 != 2 for i in range(200)],
+                       jax.nn.relu)
 
 
 @pytest.fixture(scope="module")
@@ -358,7 +476,10 @@ def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(
     configuration and a 256-row call's spans, under its own rule of
     choice, compiled for the v5e: the three grouped products are Pallas
     kernels (``gmm``) under the ``mlp`` scope, none is XLA's own
-    ragged-dot kernel (which keeps no scope in the trace), and the
+    ragged-dot kernel (which keeps no scope in the trace); the rows come
+    back by the route's two DMA kernels under ``route`` (to assignment
+    order, then weighed and summed), so that of XLA's gathers of the
+    model's width only the one into expert order is left; and the
     temporaries stay under 1.5 GB."""
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -387,8 +508,13 @@ def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "pallas_call" in line]
-    assert len(kernels) == 3
-    assert all("/mlp/" in line and "gmm" in line for line in kernels)
+    assert len(kernels) == 5
+    mlp = [line for line in kernels if "/mlp/" in line]
+    route = [line for line in kernels if "/route/" in line]
+    assert len(mlp) == 3 and all("gmm" in line for line in mlp)
+    assert len(route) == 2
+    assert sum(f",{d}]" in line and " gather(" in line
+               for line in text.splitlines()) == 1
     assert "ragged-dot" not in text and "ragged_dot" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
@@ -400,13 +526,14 @@ def test_four_chips_each_route_their_own_rows(four_chips, arch):
     traces it (``mesh {data: 4}``, inside the mesh): the partitioner
     refuses a bare Pallas kernel ("Mosaic kernels cannot be automatically
     partitioned"), so each device runs the routed feed-forward on its own
-    rows, three kernels a layer, and the only exchange of the stack is
-    the sum of the loads."""
+    rows, five kernels a layer (the three grouped products and the two of
+    the way back, at the width they take), and the only exchange of the
+    stack is the sum of the counts."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from odigos_tpu.parallel.sharding import compile_plan
 
-    small = arch_case(arch)[1].SMALL
+    small = {**arch_case(arch)[1].SMALL, "d_model": D, "dtype": "bfloat16"}
     mesh = Mesh(np.array(four_chips).reshape(4), ("data",))
     model = TraceTransformer(make_model_config("transformer", small))
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -431,7 +558,7 @@ def test_four_chips_each_route_their_own_rows(four_chips, arch):
         jax.config.update("jax_enable_compilation_cache", True)
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "pallas_call" in line]
-    assert len(kernels) == 3 * (small["n_layers"]
+    assert len(kernels) == 5 * (small["n_layers"]
                                 - small.get("dense_layers", 0))
     assert "all-gather" not in text and "all-to-all" not in text
 
@@ -660,6 +787,9 @@ def test_score_spans_say_what_the_model_is_and_what_each_call_routed():
             # the fewest experts busy in any of the 8 layers: at least
             # the 2 a span takes, at most all 8
             assert 2 <= a["moe.experts_busy_min"] <= 8
+            # XLA's gathers here: every dispatched slot's choices, twice
+            slots = np.prod([int(x) for x in a["device.shape"].split("x")])
+            assert a["moe.rows_moved"] == 2 * slots * per_span
         after = meter.snapshot()
         assert after[EXPERT_ASSIGNMENTS_METRIC] \
             - before.get(EXPERT_ASSIGNMENTS_METRIC, 0.0) \
@@ -668,6 +798,20 @@ def test_score_spans_say_what_the_model_is_and_what_each_call_routed():
             - before.get(LAYER_APPLICATIONS_METRIC, 0.0) == 3 * 8
     finally:
         eng.shutdown()
+
+
+def test_the_rows_moved_are_counted_by_the_path_that_moved_them(built):
+    """``moe.rows_moved`` beside the other counts of ``score_packed_counted``:
+    on the CPU, XLA's gathers move every slot's k rows into expert order
+    and back in every layer, the slots that hold no span too."""
+    model, variables = built
+    args = packed_row([6, 5, 4], rows=3)
+    _, counts = model.score_packed_counted(variables, *args)
+    slots = args[2].size                            # 3 rows of 16
+    assert int(counts["moe.rows_moved"]) == 2 * slots * SMALL[
+        "experts_per_span"] * SMALL["n_layers"]
+    assert int(counts["moe.assignments"]) == 15 * 3 * SMALL[
+        "experts_per_span"] * SMALL["n_layers"]
 
 
 def test_the_other_blocks_stamp_what_they_did():
