@@ -13,6 +13,11 @@ pipeline and its destination pipeline each end in one): a batch that leaves
 here carries the instant its oldest member first entered a batch processor,
 and a downstream one counts its own `timeout_s` from that instant. A batch
 from anywhere else carries none and is held from the moment it is consumed.
+
+What a span paid here is counted at every counted flush: each batch taken
+adds its spans times the wall from its own consume here to the flush, so
+two processors in series each count their own wait and never one wait
+twice.
 """
 
 from __future__ import annotations
@@ -33,6 +38,10 @@ FLUSH_METRIC = "odigos_batch_flush_total"
 # or the time its oldest member had been held upstream had already used
 # up this processor's timeout_s (no timer was armed for it)
 FLUSH_REASONS = ("size", "timeout", "inherited")
+# span-milliseconds held here, and the spans they were held for: their
+# ratio is the mean hold a span paid this processor
+HOLD_METRIC = "odigos_batch_hold_span_ms_total"
+HELD_METRIC = "odigos_batch_held_spans_total"
 
 # the instant rides in the batch's __dict__, beside the frozen pdata
 # classes' own caches: not a field, so no column, no attribute, not in
@@ -58,14 +67,15 @@ class BatchProcessor(Processor):
     def __init__(self, name: str, config: dict[str, Any]):
         super().__init__(name, config)
         self._lock = threading.Lock()
-        self._pending: list[SpanBatch] = []
+        # each pending batch with the instant it was consumed here
+        self._pending: list[tuple[SpanBatch, float]] = []
         self._pending_spans = 0
         # earliest batched_since of the pending batches; None when empty
         self._since: Optional[float] = None
         self._timer: Optional[threading.Timer] = None
         self._apply_sizing(config)
         self._wm_name: str | None = None
-        self._flush_keys: dict[str, str] | None = None
+        self._keys: dict[str, str] | None = None
 
     def _apply_sizing(self, config: dict[str, Any]) -> None:
         # ONE parse routine for __init__ and reconfigure — a default
@@ -83,17 +93,26 @@ class BatchProcessor(Processor):
             name = self._wm_name = FlowContext.watermark_name(self)
         return name
 
-    def _count_flush(self, reason: str) -> None:
-        keys = self._flush_keys
+    def _count_flush(self, reason: str,
+                     taken: list[tuple[SpanBatch, float]]) -> None:
+        keys = self._keys
         if keys is None:
             # resolved lazily, as the watermark name is: two pipelines'
             # `batch` stages must not share a series
             site = getattr(self, "_flow_site", None)
-            keys = self._flush_keys = {
+            pipeline = site[0] if site else "(none)"
+            keys = self._keys = {
                 r: labeled_key(FLUSH_METRIC, processor=self.name,
-                               pipeline=site[0] if site else "(none)",
-                               reason=r) for r in FLUSH_REASONS}
+                               pipeline=pipeline, reason=r)
+                for r in FLUSH_REASONS}
+            for metric in (HOLD_METRIC, HELD_METRIC):
+                keys[metric] = labeled_key(metric, processor=self.name,
+                                           pipeline=pipeline)
         meter.add(keys[reason])
+        now = time.monotonic()
+        meter.add(keys[HOLD_METRIC], 1e3 * sum(
+            len(batch) * (now - consumed) for batch, consumed in taken))
+        meter.add(keys[HELD_METRIC], sum(len(batch) for batch, _ in taken))
 
     def reconfigure(self, config: dict[str, Any]) -> None:
         """Live retune (ISSUE 14): pending spans are NOT dropped — a
@@ -117,7 +136,7 @@ class BatchProcessor(Processor):
         to_send: list[SpanBatch] = []
         reason = ""
         with self._lock:
-            self._pending.append(batch)
+            self._pending.append((batch, now))
             self._pending_spans += len(batch)
             FlowContext.watermark(self._watermark_name(), "pending_spans",
                                   self._pending_spans)
@@ -133,7 +152,8 @@ class BatchProcessor(Processor):
             self._send(to_send, since, reason)
 
     def _size_or_rearm_locked(
-            self, now: float) -> tuple[list[SpanBatch], float, str]:
+            self, now: float,
+    ) -> tuple[list[tuple[SpanBatch, float]], float, str]:
         """The one flush rule of consume and reconfigure: take the buffer
         if it is full, or if ``timeout_s`` from its oldest member's
         instant has already passed; else (re-)arm the timer for what is
@@ -153,9 +173,10 @@ class BatchProcessor(Processor):
             self._timer.start()
         return [], 0.0, ""
 
-    def _take_locked(self) -> tuple[list[SpanBatch], Optional[float]]:
-        """Empty the buffer: (its batches, the earliest instant among
-        them; None with nothing pending)."""
+    def _take_locked(
+            self) -> tuple[list[tuple[SpanBatch, float]], Optional[float]]:
+        """Empty the buffer: (its batches with their consume instants,
+        the earliest instant among them; None with nothing pending)."""
         taken, since = self._pending, self._since
         self._pending = []
         self._pending_spans = 0
@@ -177,18 +198,19 @@ class BatchProcessor(Processor):
                 self._send(taken, since, "timeout")
             except Exception:
                 # downstream refusal on the timer thread: the caller that
-                # could retry is long gone — count + drop, never kill the
-                # timer path (retries belong to exporters' own queues)
-                meter.add("odigos_batch_dropped_on_flush_total"
-                          f"{{processor={self.name}}}")
+                # could retry is long gone — drop, never kill the timer
+                # path (retries belong to exporters' own queues; the
+                # out-edge has counted the spans as failed)
+                pass
 
-    def _send(self, batches: list[SpanBatch], since: float,
+    def _send(self, taken: list[tuple[SpanBatch, float]], since: float,
               reason: str = "") -> None:
         """Merge and forward, every piece stamped with ``since``. An
         explicit ``flush()`` gives no reason and is not counted: it is
         a drain, not a batching decision."""
         if reason:
-            self._count_flush(reason)
+            self._count_flush(reason, taken)
+        batches = [batch for batch, _ in taken]
         merged = concat_any(batches)
         if not merged:
             return

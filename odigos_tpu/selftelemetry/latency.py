@@ -143,13 +143,17 @@ PREDICTED_BLAME = "predicted"
 # queue, device, wait) have none, because nobody works in them. Closed
 # like Stage, and held equal to the sites by the package-hygiene lint
 # (``TestAnnotationHygiene``). The benchmark's trace reduction
-# (benchmark/hosttrace.py) reads the ``engine/*`` names.
+# (benchmark/hosttrace.py) reads the ``engine/*`` names. Besides the
+# stages: the wait for the device inside a harvest (``engine/ready``),
+# and every collection of the interpreter's garbage collector, on
+# whichever thread it ran (``python/gc``, serving/gcisolation.py).
 ANNOTATIONS = (
     "wire/admission", "wire/decode",            # receiver thread
     "fastpath/featurize", "fastpath/enqueue",   # submit lane
     "engine/collect", "engine/pack", "engine/enqueue",
-    "engine/harvest", "engine/scatter",         # engine worker
+    "engine/harvest", "engine/ready", "engine/scatter",  # engine worker
     "lane/tag", "lane/forward",                 # retirement lane
+    "python/gc",                                # any thread
 )
 
 # bounded ring of recent frame clocks per recorder: the latencyz

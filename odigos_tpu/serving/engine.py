@@ -500,6 +500,8 @@ class SequenceBackend:
         self.last_real_rows: Optional[int] = None
         self.last_padding_waste: Optional[float] = None
         self.last_bucket_hit: Optional[bool] = None
+        # the last fetch's two parts, ms: (wait for the device, the copy)
+        self.last_harvest_ms: Optional[tuple[float, float]] = None
         self.variables = variables if variables is not None else \
             self.model.init(jax.random.PRNGKey(cfg.seed))
         # what every tpu/score span says of the model behind the call
@@ -635,17 +637,32 @@ class SequenceBackend:
 
     def fetch(self, handle: Any, call: int = -1) -> Any:
         """Harvest stage, the wait: block on the device result (the only
-        blocking host<->device interaction) and nothing else. Returns
-        the handle with the host array in the device array's place,
-        for ``harvest``."""
+        blocking host<->device interaction, ``engine/ready``), then copy
+        it and the call's counts to the host in one transfer, and nothing
+        else. Returns the handle with host values in the device arrays'
+        place, for ``harvest``; the two parts' walls are left in
+        ``last_harvest_ms``."""
+        import jax
+
         with annotate("engine/harvest", call=call):
-            host = np.asarray(handle[1], dtype=np.float32)
+            t0 = time.perf_counter()
+            with annotate("engine/ready", call=call):
+                jax.block_until_ready(handle[1])
+            t1 = time.perf_counter()
             if isinstance(handle, _HostCall):
                 # the call's own counts, of the program that just ended
-                return handle._replace(scores=host, counts={
-                    key: value.item()
-                    for key, value in (handle.counts or {}).items()})
-        return (handle[0], host) + tuple(handle[2:])    # the fused route's
+                scores, counts = jax.device_get(
+                    (handle.scores, handle.counts or {}))
+                fetched = handle._replace(
+                    scores=np.asarray(scores, dtype=np.float32),
+                    counts={key: value.item()
+                            for key, value in counts.items()})
+            else:                                        # the fused route's
+                fetched = (handle[0], np.asarray(handle[1], dtype=np.float32)
+                           ) + tuple(handle[2:])
+            self.last_harvest_ms = (1e3 * (t1 - t0),
+                                    1e3 * (time.perf_counter() - t1))
+        return fetched
 
     def call_attrs(self, handle: Any) -> dict[str, Any]:
         """What a call counted on the device, by the name it has on the
@@ -934,8 +951,9 @@ class _InflightGroup:
     held_ms: float = 0.0
     ahead_end_ns: Optional[int] = None
     # what the call counted on the device (backend.call_attrs), read once
-    # its result is fetched
+    # its result is fetched, and the fetch's two parts (last_harvest_ms)
     call_attrs: Optional[dict[str, Any]] = None
+    harvest_split: Optional[tuple[float, float]] = None
 
 
 class ScoringEngine:
@@ -1938,6 +1956,8 @@ class ScoringEngine:
             try:
                 with self._backend_lock:
                     handle = fetch(handle, grp.call)
+                    grp.harvest_split = getattr(backend, "last_harvest_ms",
+                                                None)
             except Exception as e:
                 self._harvest_failed(grp, backend, e)
                 return
@@ -2077,7 +2097,7 @@ class ScoringEngine:
             "harvest_ms": harvest_ms, "overlap_ms": grp.overlap_ms,
             "spans": grp.n_spans, "bucket_hit": grp.bucket_hit})
         self._annotate_score_span(grp, busy_frac, dt_ms, pack_ms,
-                                  harvest_ms)
+                                  harvest_ms, alone_ns / 1e6)
         grp.span.finish()
         meter.add(SCORED_METRIC, grp.n_spans)
         # exemplar: link this latency sample to the tpu/score self-trace
@@ -2094,12 +2114,13 @@ class ScoringEngine:
 
     def _annotate_score_span(self, grp: _InflightGroup, busy_frac: float,
                              dt_ms: float, pack_ms: float,
-                             harvest_ms: float) -> None:
+                             harvest_ms: float, alone_ms: float) -> None:
         """TPU-stage span attributes: device, coalesced batch shape,
-        padding waste, queue wait, per-stage split, pipeline overlap, and
-        the call's serial (``call`` on the engine/* trace annotations and
-        in the frames' stage_ns: which call, of which rung, a frame
-        rode)."""
+        padding waste, queue wait, per-stage split, pipeline overlap, the
+        fetch's wait and copy, the wall the call had the device to itself
+        and the rung's learned cost after it, and the call's serial
+        (``call`` on the engine/* trace annotations and in the frames'
+        stage_ns: which call, of which rung, a frame rode)."""
         sp = grp.span
         sp.set_attr("model", self.cfg.model)
         sp.set_attr("device",
@@ -2113,8 +2134,16 @@ class ScoringEngine:
         sp.set_attr("device_busy_frac", round(busy_frac, 4))
         sp.set_attr("pack_ms", round(pack_ms, 3))
         sp.set_attr("harvest_ms", round(harvest_ms, 3))
+        if grp.harvest_split is not None:
+            ready_ms, copy_ms = grp.harvest_split
+            sp.set_attr("harvest.ready_ms", round(ready_ms, 3))
+            sp.set_attr("harvest.copy_ms", round(copy_ms, 3))
+        sp.set_attr("call.alone_ms", round(alone_ms, 3))
         if grp.shape is not None:
             sp.set_attr("device.shape", "x".join(map(str, grp.shape)))
+            cost = self._rung_ms.get(grp.shape[0])
+            if cost is not None:
+                sp.set_attr("rung.cost_ms", round(cost, 3))
         if grp.real_rows is not None:
             sp.set_attr("rows.real", grp.real_rows)
         sp.set_attr("coalesce.closed", grp.closed)

@@ -27,12 +27,16 @@ allocator:
 * every collection — janitor-paced or threshold-triggered, any thread —
   is timed via ``gc.callbacks`` into the ``odigos_gc_pause_ms{gen=}``
   histogram, so "GC left the waterfall" is a measurable claim, not a
-  vibe.
+  vibe; and bracketed by a ``python/gc`` profiler annotation on the
+  collecting thread (``generation``, and ``collected`` on its way out),
+  so that a trace names a collection where the device sat idle.
 
 The callback deliberately never touches the meter (a threshold
 collection can fire INSIDE a meter lock hold — re-entering the meter
 from the callback would deadlock); it appends to a bounded pending ring
-the janitor drains into histograms.
+the janitor drains into histograms. The annotation takes no lock either:
+with no profiler session live it is an inactive TraceMe, and a process
+that has not imported jax opens none.
 
 Lifecycle: process-global singleton (``gc_plane``), refcounted —
 ``Collector.start`` starts it (config under ``service: {gc: {...}}``;
@@ -56,6 +60,7 @@ import time
 from collections import deque
 from typing import Any, Optional
 
+from ..selftelemetry.latency import annotate
 from ..utils.telemetry import labeled_key, meter
 
 GC_PAUSE_METRIC = "odigos_gc_pause_ms"
@@ -118,8 +123,8 @@ class GcPlane:
         # meter — see module doc), drained/published by the janitor
         self._pending: deque[tuple[int, float]] = deque(maxlen=1024)
         self._t0: Optional[int] = None
+        self._span: Optional[annotate] = None
         self._pauses = 0
-        self._pause_ms_total = 0.0
         self._pause_ms_max = 0.0
         self._collects = 0
         self._hints = 0
@@ -134,15 +139,20 @@ class GcPlane:
         # runs under the GIL on whatever thread triggered the collection
         # (collections never nest, so one scalar mark suffices)
         if phase == "start":
+            self._span = annotate(
+                "python/gc", generation=info.get("generation", 0)).__enter__()
             self._t0 = time.perf_counter_ns()
             return
+        span, self._span = self._span, None
+        if span is not None:
+            span.set(collected=info.get("collected", 0))
+            span.close()
         t0 = self._t0
         if t0 is None:
             return
         self._t0 = None
         ms = (time.perf_counter_ns() - t0) / 1e6
         self._pauses += 1
-        self._pause_ms_total += ms
         if ms > self._pause_ms_max:
             self._pause_ms_max = ms
         self._pending.append((int(info.get("generation", 0)), ms))
@@ -293,28 +303,13 @@ class GcPlane:
     def stats(self) -> dict[str, Any]:
         return {
             "pauses": self._pauses,
-            "pause_ms_total": round(self._pause_ms_total, 3),
             "pause_ms_max": round(self._pause_ms_max, 3),
-            "pause_ms_mean": round(
-                self._pause_ms_total / self._pauses, 4)
-            if self._pauses else 0.0,
             "janitor_collects": self._collects,
             "hints": self._hints,
             "frozen": self._frozen,
             "frozen_objects": gc.get_freeze_count() if self._frozen else 0,
-            "interval_s": self.interval_s,
             "running": self._starts > 0,
         }
-
-    def reset_stats(self) -> None:
-        """Per-run counters back to zero (soak/bench isolation); the
-        lifecycle state (thread, freeze, thresholds) is untouched."""
-        self._pauses = 0
-        self._pause_ms_total = 0.0
-        self._pause_ms_max = 0.0
-        self._collects = 0
-        self._hints = 0
-        self._pending.clear()
 
 
 gc_plane = GcPlane()

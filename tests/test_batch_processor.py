@@ -18,7 +18,8 @@ from odigos_tpu.components.api import Signal
 from odigos_tpu.components.connectors.forward import ForwardConnector
 from odigos_tpu.components.processors import batch as batch_mod
 from odigos_tpu.components.processors.batch import (
-    FLUSH_METRIC, FLUSH_REASONS, BatchProcessor, batched_since)
+    FLUSH_METRIC, FLUSH_REASONS, HELD_METRIC, HOLD_METRIC, BatchProcessor,
+    batched_since)
 from odigos_tpu.components.processors.traffic_metrics import (
     TrafficMetricsProcessor)
 from odigos_tpu.pdata import (
@@ -482,6 +483,82 @@ class TestFlushCounter:
         after = self.counts("batch", "traces/tracedb-all")
         assert after["inherited"] - before["inherited"] == 1.0
         assert after["timeout"] == before["timeout"]
+
+
+class TestHoldCounter:
+    """Each counted flush adds, for every batch it takes, the batch's
+    spans times the wall from its consume here to the flush."""
+
+    @staticmethod
+    def read(name, pipeline="(none)"):
+        snap = meter.snapshot()
+        return tuple(snap.get(labeled_key(m, processor=name,
+                                          pipeline=pipeline), 0.0)
+                     for m in (HOLD_METRIC, HELD_METRIC))
+
+    def moved(self, before, name, pipeline="(none)"):
+        after = self.read(name, pipeline)
+        return after[0] - before[0], after[1] - before[1]
+
+    def test_size_timeout_and_inherited_flushes(self, clock):
+        name = "batch/hold"
+        sink = Sink(clock.monotonic)
+        a, b = spans(), spans(seed=1)
+        p = BatchProcessor(name, {"send_batch_size": len(a) + len(b)})
+        p.set_consumer(sink)
+        before = self.read(name)
+        p.consume(a)
+        clock.advance(0.05)
+        p.consume(b)                            # size: a held 50 ms, b 0
+        hold, held = self.moved(before, name)
+        assert hold == pytest.approx(len(a) * 50.0)
+        assert held == len(a) + len(b)
+        before = self.read(name)
+        c = spans(seed=2)
+        p.consume(c)
+        clock.advance(0.3)                      # timeout at 0.2
+        hold, held = self.moved(before, name)
+        assert hold == pytest.approx(len(c) * 200.0) and held == len(c)
+        before = self.read(name)
+        d = stamped(spans(seed=3), clock.now - 0.25)
+        p.consume(d)                            # inherited: let go at once
+        assert self.moved(before, name) == (0.0, len(d))
+        assert len(sink.got) == 3
+
+    def test_a_drain_is_not_counted(self, clock):
+        name = "batch/hold-drain"
+        p = BatchProcessor(name, {})
+        p.set_consumer(Sink(clock.monotonic))
+        before = self.read(name)
+        p.consume(spans())
+        clock.advance(0.1)
+        p.flush()
+        assert self.moved(before, name) == (0.0, 0.0)
+
+    def test_two_in_series_count_each_wait_once(self, clock):
+        """The first holds three batches 0.2, 0.15 and 0.1 s; the second
+        lets their merge go at once (inherited) and counts no wait of
+        its own, though it holds the same spans."""
+        sink = Sink(clock.monotonic)
+        first, second = chain(sink, {}, {})
+        first._flow_site = ("traces/first", "batch", "traces")
+        second._flow_site = ("traces/second", "batch", "traces")
+        before = [self.read("batch", p) for p in ("traces/first",
+                                                  "traces/second")]
+        sizes = []
+        for k in range(3):
+            b = spans(seed=k)
+            sizes.append(len(b))
+            first.consume(b)
+            clock.advance(0.05)
+        clock.advance(1.0)
+        assert sink.sizes == [sum(sizes)]
+        hold1, held1 = self.moved(before[0], "batch", "traces/first")
+        hold2, held2 = self.moved(before[1], "batch", "traces/second")
+        assert hold1 == pytest.approx(
+            sizes[0] * 200.0 + sizes[1] * 150.0 + sizes[2] * 100.0)
+        assert held1 == held2 == sum(sizes)
+        assert hold2 == 0.0
 
 
 class TestRenderedGateway:

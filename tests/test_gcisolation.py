@@ -5,6 +5,7 @@ posture engages and restores cleanly."""
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import threading
 import time
@@ -224,3 +225,64 @@ class TestCollectorLifecycle:
             assert gc_plane.stats()["running"]
         finally:
             collector.shutdown()
+
+
+class TestCollectionsOnTheProfilersClock:
+    """Every collection is a ``python/gc`` annotation on the thread that
+    ran it, opened and closed by the callback that times it, which still
+    touches neither the meter nor a lock."""
+
+    @staticmethod
+    @contextlib.contextmanager
+    def alone(plane):
+        """``plane``'s callback as the interpreter's only one (earlier
+        planes of this process left theirs installed)."""
+        saved = gc.callbacks[:]
+        gc.callbacks[:] = [plane._gc_callback]
+        try:
+            yield
+        finally:
+            gc.callbacks[:] = saved
+
+    def test_a_collection_is_one_event_on_the_collecting_threads_line(
+            self, tmp_path):
+        import jax
+
+        from odigos_tpu.selftelemetry.latency import annotate
+        from tests.test_traceannotations import trace_events
+
+        plane = GcPlane()
+        with self.alone(plane):
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                with annotate("engine/pack", call=7):
+                    gc.collect(2)
+            finally:
+                jax.profiler.stop_trace()
+        events = trace_events(str(tmp_path))
+        (line, _, start, _), = [e for e in events if e[1] == "engine/pack"]
+        full = [(ln, s, args) for ln, name, s, args in events
+                if name == "python/gc" and args["generation"] == 2]
+        assert len(full) == 1
+        gc_line, gc_start, args = full[0]
+        assert gc_line == line and gc_start >= start
+        assert args["collected"] >= 0
+        assert plane._span is None and plane.stats()["pauses"] >= 1
+
+    def test_the_callback_touches_no_meter(self, monkeypatch):
+        from odigos_tpu.serving import gcisolation
+
+        touched = []
+
+        class Tripwire:
+            def __getattr__(self, name):
+                touched.append(name)
+                raise AssertionError(f"meter.{name} from the gc callback")
+
+        monkeypatch.setattr(gcisolation, "meter", Tripwire())
+        plane = GcPlane()
+        with self.alone(plane):
+            gc.collect()
+        assert touched == []
+        assert plane.stats()["pauses"] >= 1 and plane._span is None
+        assert plane._pending

@@ -732,6 +732,30 @@ def test_the_block_scores_the_same_on_a_data_mesh_of_four(monkeypatch):
         == len(batch) * SMALL["experts_per_span"] * SMALL["n_layers"]
 
 
+def test_scores_and_counts_come_to_the_host_in_one_transfer(monkeypatch):
+    """``fetch`` waits for the call, then copies its scores and every one
+    of its counts in one ``jax.device_get``; the counts are the host
+    numbers each count's own ``.item()`` gave, and the wait and the copy
+    are left for the call's span."""
+    batch = synthesize_traces(30, seed=7)
+    backend = ScoringEngine(engine_config(seed=5)).backend
+    handle = backend.dispatch(batch, featurize(batch))
+    assert len(handle.counts) >= 2
+    want = {key: value.item() for key, value in handle.counts.items()}
+    gets = []
+    device_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get", lambda tree: (
+        gets.append(tree), device_get(tree))[1])
+    fetched = backend.fetch(handle, call=3)
+    assert len(gets) == 1
+    assert backend.call_attrs(fetched) == want
+    assert all(type(v) in (int, float) for v in want.values())
+    np.testing.assert_array_equal(
+        fetched.scores, np.asarray(handle.scores, dtype=np.float32))
+    ready_ms, copy_ms = backend.last_harvest_ms
+    assert ready_ms >= 0.0 and copy_ms >= 0.0
+
+
 def test_the_fused_route_serves_the_block():
     from odigos_tpu.serving.fused import (PARITY_F32, extract_columns,
                                           routes_agree)

@@ -656,7 +656,8 @@ class TestAnnotationHygiene:
                 "decode", "tag", "forward"} <= labels
         for name in ANNOTATIONS:
             layer, _, stage = name.partition("/")
-            assert layer in ("wire", "fastpath", "engine", "lane"), name
+            assert layer in ("wire", "fastpath", "engine", "lane",
+                             "python"), name
             assert re.fullmatch(r"[a-z]+", stage), name
         # the waits have no annotation: nobody works in them
         for wait in ("submit", "queue", "device", "wait"):

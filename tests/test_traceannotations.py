@@ -8,6 +8,7 @@ parts are ``jax.named_scope``s that change nothing but names, and
 
 from __future__ import annotations
 
+import gc
 import glob
 import json
 import os
@@ -116,8 +117,10 @@ class TestAnnotate:
 # ------------------------------------------------- the traced short window
 
 
+# a collection runs on whichever thread allocated past a threshold, or
+# on the janitor's: python/gc may show on any line
 ROLE_OF = {"wire": "odigos-receiver", "fastpath": "odigos-submit-",
-           "engine": "odigos-engine", "lane": "odigos-lane-"}
+           "engine": "odigos-engine", "lane": "odigos-lane-", "python": ""}
 
 
 @pytest.fixture()
@@ -145,6 +148,7 @@ def traced_window(tmp_path):
                 exp.export(b)
                 want += len(b)
                 assert wait_for(lambda: sink.span_count == want)
+            gc.collect()
         finally:
             jax.profiler.stop_trace()
         exp.shutdown()
@@ -176,7 +180,8 @@ class TestTracedWindow:
         serials = by_stage["engine/enqueue"]
         assert len(serials) >= 2
         assert serials == list(range(serials[0], serials[0] + len(serials)))
-        for name in ("engine/pack", "engine/harvest", "engine/scatter"):
+        for name in ("engine/pack", "engine/harvest", "engine/ready",
+                     "engine/scatter"):
             assert by_stage[name] == serials, name
         assert [s.attrs["call.serial"] for s in spans] == serials
         # every frame rode one of the window's calls, in order
@@ -188,6 +193,47 @@ class TestTracedWindow:
         # one request a frame; the collect that waited for the first may
         # have begun before the session did, and then left no event
         assert sum(a["queued"] for a in collect) in (5, 6)
+
+
+    def test_the_wait_for_the_device_nests_inside_the_harvest(
+            self, traced_window):
+        """``engine/ready`` is the first part of each call's
+        ``engine/harvest``, on the same line; the rest is the copy."""
+        events, _, _ = traced_window
+        by = {}
+        for line, name, start, args in events:
+            if name in ("engine/harvest", "engine/ready"):
+                by.setdefault((name, int(args["call"])), []).append(
+                    (line, start))
+        harvests = {k[1]: v for k, v in by.items()
+                    if k[0] == "engine/harvest"}
+        assert harvests
+        for call, [(line, start)] in harvests.items():
+            [(ready_line, ready_start)] = by[("engine/ready", call)]
+            assert ready_line == line and ready_start >= start
+
+    def test_the_score_span_carries_the_split_and_the_learned_cost(
+            self, traced_window):
+        """``harvest.ready_ms`` and ``harvest.copy_ms`` are the fetch's
+        two parts; ``call.alone_ms`` the wall the call had the device to
+        itself and ``rung.cost_ms`` its rung's cost after this call's
+        update: each rung's costs are the engine's EWMA of its calls'
+        walls, in order."""
+        from odigos_tpu.serving.engine import _ewma
+
+        _, spans, _ = traced_window
+        assert spans
+        cost = {}
+        for sp in spans:
+            a = sp.attrs
+            assert a["harvest.ready_ms"] >= 0.0
+            assert a["harvest.copy_ms"] >= 0.0
+            assert a["harvest.ready_ms"] + a["harvest.copy_ms"] \
+                <= a["harvest_ms"] + 1e-3
+            rung = int(a["device.shape"].split("x")[0])
+            if a.get("bucket.hit") is not False:
+                cost[rung] = _ewma(cost.get(rung), a["call.alone_ms"])
+            assert a["rung.cost_ms"] == pytest.approx(cost[rung], abs=2e-3)
 
 
 # ------------------------------------------------------- the model's parts
