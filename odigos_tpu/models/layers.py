@@ -31,7 +31,7 @@ from flax import linen as nn
 from jax.sharding import PartitionSpec
 
 from ..features.featurizer import CAT_FIELDS
-from . import route_kernels
+from . import attention_kernel, route_kernels
 
 
 class SpanEmbedder(nn.Module):
@@ -83,7 +83,7 @@ class SpanEmbedder(nn.Module):
 
 
 class EncoderBlock(nn.Module):
-    """Pre-LN bidirectional transformer block with padding mask."""
+    """Pre-LN bidirectional transformer block (``encoder_attention``)."""
 
     d_model: int
     n_heads: int
@@ -93,14 +93,14 @@ class EncoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, attn_mask: jnp.ndarray,
-                 deterministic: bool = True) -> jnp.ndarray:
+                 deterministic: bool = True, segments=None) -> jnp.ndarray:
         # attn_mask: (T, 1, L, L) bool, True where attention is allowed
         with jax.named_scope("attn"):
             h = nn.LayerNorm(dtype=self.dtype)(x)
-            h = nn.MultiHeadDotProductAttention(
-                num_heads=self.n_heads, dtype=self.dtype,
-                dropout_rate=self.dropout, deterministic=deterministic,
-            )(h, h, mask=attn_mask)
+            h = encoder_attention(
+                h, attn_mask, segments, n_heads=self.n_heads,
+                dtype=self.dtype, dropout=self.dropout,
+                deterministic=deterministic)
             x = x + h
         with jax.named_scope("mlp"):
             h = nn.LayerNorm(dtype=self.dtype)(x)
@@ -185,7 +185,7 @@ class Encoder(nn.Module):
         for i in range(self.n_layers):
             x = EncoderBlock(self.d_model, self.n_heads, self.d_ff,
                              self.dtype, name=f"block_{i}")(
-                x, attn_mask, deterministic)
+                x, attn_mask, deterministic, segments)
         with jax.named_scope("final_norm"):
             return nn.LayerNorm(dtype=self.dtype, name="final_ln")(x)
 
@@ -569,7 +569,7 @@ def routed_counts(counts) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 
 # the mesh axis a plan splits the packed rows over (parallel/sharding.py)
-ROWS_AXIS = "data"
+ROWS_AXIS = attention_kernel.ROWS_AXIS
 
 
 def each_device_its_rows(route):
@@ -944,3 +944,46 @@ class LatentMoeDecoder(nn.Module):
                            param_dtype=self.param_dtype,
                            name="final_rms")(x)
         return (x, *routed_counts(counts))
+
+
+def encoder_attention(h, attn_mask, segments, n_heads: int, dtype,
+                      dropout: float, deterministic: bool):
+    """The encoder block's attention sublayer after its LayerNorm, as flax's
+    ``MultiHeadDotProductAttention`` (its parameters, under its name),
+    over ``attn_mask`` (T, 1, L, L). Given ``segments`` (packed rows,
+    row-local trace ids, 0 = padding) of a shape ``kernel_attends`` takes,
+    and no dropout at work, the TPU runs it as
+    ``attention_kernel.self_attention`` over the same parameters; every
+    other platform runs flax's."""
+    attention = nn.MultiHeadDotProductAttention(
+        num_heads=n_heads, dtype=dtype, dropout_rate=dropout,
+        deterministic=deterministic)
+    out = attention(h, h, mask=attn_mask)
+    mesh = jax.sharding.get_abstract_mesh()
+    if segments is None or (dropout and not deterministic) \
+            or not kernel_attends(dtype, *h.shape[:2], h.shape[-1], n_heads,
+                                  None if mesh.empty else dict(mesh.shape)):
+        return out
+    return jax.lax.platform_dependent(
+        h, segments, attention.variables["params"],
+        tpu=partial(attention_kernel.self_attention, heads=n_heads,
+                    dtype=dtype),
+        default=lambda *_: out)
+
+
+def kernel_attends(dtype: Any, rows: int, L: int, d_model: int, n_heads: int,
+                   mesh_shape: dict | None = None,
+                   platform: str = "tpu") -> bool:
+    """Whether the encoder block's attention over ``rows`` packed rows
+    runs as the ``attention_kernel`` on ``platform``: a TPU, the kernel
+    takes the shape each device sees, and no mesh axis but the rows'
+    splits the block (a Pallas kernel cannot be partitioned, and a
+    ``model`` axis splits the heads). ``mesh_shape`` is the mesh's axis
+    sizes, None for none."""
+    mesh_shape = mesh_shape or {}
+    shards = mesh_shape.get(ROWS_AXIS, 1)
+    others = [n for axis, n in mesh_shape.items() if axis != ROWS_AXIS]
+    return (platform == "tpu" and all(n == 1 for n in others)
+            and rows % shards == 0
+            and attention_kernel.supported(dtype, rows // shards, L,
+                                           d_model, n_heads))

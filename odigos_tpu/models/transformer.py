@@ -33,9 +33,9 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from . import jitstats
-from .layers import (BLOCK_PARTS, Encoder, LatentMoeDecoder, LoopedDecoder,
-                     MoeDecoder)
+from . import attention_kernel, jitstats
+from .layers import (BLOCK_PARTS, ROWS_AXIS, Encoder, LatentMoeDecoder,
+                     LoopedDecoder, MoeDecoder, kernel_attends)
 
 # Shape-bucketing strategy per jitted scoring entry point (the package
 # hygiene test asserts every jit path in models/ and parallel/ declares
@@ -408,6 +408,23 @@ class TraceTransformer:
         trace_bce = optax_sigmoid_bce(trace_logit, trace_labels)
         trace_loss = (trace_bce * valid).sum() / jnp.maximum(valid.sum(), 1.0)
         return span_loss + trace_loss
+
+    def attention_blocks(self, rows: int, L: int, platform: str,
+                         mesh_shape: dict | None = None,
+                         ) -> tuple[int, int] | None:
+        """How the encoder block's ``attention_kernel`` cuts a call of
+        ``rows`` packed rows of ``L`` on ``platform`` under a mesh of
+        ``mesh_shape``: (rows a block, devices along the rows), or None
+        where the executable holds no kernel, by the rule the block
+        dispatches on (``layers.kernel_attends``)."""
+        c = self.cfg
+        if c.block != "encoder" or not kernel_attends(
+                c.dtype, rows, L, c.d_model, c.n_heads, mesh_shape,
+                platform):
+            return None
+        shards = (mesh_shape or {}).get(ROWS_AXIS, 1)
+        return attention_kernel.block_rows(rows // shards, L,
+                                           c.d_model), shards
 
 
 # compile accounting for the class-level jitted scoring entry (shared by

@@ -438,6 +438,7 @@ class SequenceBackend:
 
         self.cfg = cfg
         self.mesh = mesh
+        self._attention_blocks: dict[int, tuple[int, int] | None] = {}
         model_config = cfg.model_config
         variables = None
         if cfg.checkpoint_path:
@@ -743,6 +744,30 @@ class SequenceBackend:
             fn = type(self.model).score_spans
             args = (self.model, self.variables, *zero)
         cost_ledger.capture(site, f"r{R}", fn, args)
+
+    def rung_attrs(self, rows: int, real_rows: int) -> dict[str, Any]:
+        """What a packed call of ``rows`` (its first ``real_rows``
+        filled) runs that its shape decides, for its ``tpu/score`` span:
+        the encoder's attention kernel or not, and the rows it skipped.
+        The kernel's blocks are worked out once a rung
+        (``TraceTransformer.attention_blocks`` on this backend's platform
+        and mesh). Nothing for a model without packed rows; the int8
+        scorer runs its own block, never the kernel."""
+        import jax
+
+        from ..models.attention_kernel import rows_skipped
+
+        if self.cfg.model != "transformer":
+            return {}
+        if rows not in self._attention_blocks:
+            self._attention_blocks[rows] = None if self._quantized \
+                is not None else self.model.attention_blocks(
+                    rows, self.max_len, jax.devices()[0].platform,
+                    dict(self.mesh.shape) if self.mesh is not None else None)
+        blocks = self._attention_blocks[rows]
+        return {"attn.kernel": blocks is not None,
+                "attn.rows_skipped": 0 if blocks is None
+                else rows_skipped(rows, real_rows, *blocks)}
 
 
 class _HostCall(NamedTuple):
@@ -2146,6 +2171,13 @@ class ScoringEngine:
                 sp.set_attr("rung.cost_ms", round(cost, 3))
         if grp.real_rows is not None:
             sp.set_attr("rows.real", grp.real_rows)
+        backend = grp.backend if grp.backend is not None else self.backend
+        rung_attrs = getattr(backend, "rung_attrs", None)
+        if rung_attrs is not None and grp.real_rows is not None \
+                and grp.shape is not None:
+            for key, value in rung_attrs(grp.shape[0],
+                                         grp.real_rows).items():
+                sp.set_attr(key, value)
         sp.set_attr("coalesce.closed", grp.closed)
         sp.set_attr("commit.when", grp.commit)
         sp.set_attr("commit.held_ms", round(grp.held_ms, 3))
@@ -2161,7 +2193,6 @@ class ScoringEngine:
         if grp.bucket_hit is not None:
             sp.set_attr("bucket.hit", grp.bucket_hit)
         sp.set_attr("call.serial", grp.call)
-        backend = grp.backend if grp.backend is not None else self.backend
         attrs = getattr(backend, "score_attrs", {})
         counted = grp.call_attrs or {}
         for key, value in {**attrs, **counted}.items():

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 from functools import partial
 
 import jax
@@ -519,6 +520,67 @@ def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def defined_by(text: str) -> dict[str, str]:
+    """Each instruction of a compiled program's text by its name: the
+    instruction's own line."""
+    out = {}
+    for line in text.splitlines():
+        name, eq, _ = line.strip().removeprefix("ROOT ").partition(" = ")
+        if eq and name.startswith("%"):
+            out[name] = line
+    return out
+
+
+def producer(lines: dict[str, str], name: str) -> str:
+    """The instruction behind ``name``, through bitcasts."""
+    line = lines[name]
+    while " bitcast(" in line:
+        name = line.split(" bitcast(", 1)[1].split(")", 1)[0]
+        line = lines[name]
+    return line
+
+
+@pytest.mark.parametrize("d, heads, rows", [
+    (1280, 16, 512), (1280, 16, 256), (256, 4, 256)])
+def test_the_encoder_attention_compiles_for_the_chip_as_one_kernel(
+        one_chip, d, heads, rows):
+    """The encoder block's attention over packed rows at ``vit-h14``'s
+    widths (both rungs its cells run) and the flagship's, compiled for the
+    v5e: one ``packed_attention`` kernel a layer under ``attn``, whose q,
+    k and v are the projections' own products (no copy of the activations
+    ahead of it)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    model = TraceTransformer(TransformerConfig(
+        d_model=d, n_heads=heads, n_layers=2, d_ff=4 * d, max_len=64))
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (jax.tree.map(lambda a: S(a.shape, a.dtype), variables),
+            S((rows, 64, 5), jnp.int32), S((rows, 64, 3), jnp.float32),
+            S((rows, 64), jnp.int32), S((rows, 64), jnp.int32))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(model._score_packed_impl).lower(
+            *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    lines = defined_by(text)
+    kernels = [line for line in lines.values()
+               if "tpu_custom_call" in line and "packed_attention" in line]
+    assert len(kernels) == 2
+    assert all("/attn/" in line for line in kernels)
+    for line in kernels:
+        operands = line.split("custom-call(", 1)[1].split(")", 1)[0]
+        q, k, v = re.findall(r"%[\w.-]+", operands)[3:6]
+        for name in (q, k, v):
+            assert " fusion(" in producer(lines, name)
+            assert "convolution" in producer(lines, name).split(" = ")[0]
+
+
 @pytest.mark.parametrize("arch", ["moe_decoder", "latent_moe_decoder"])
 def test_four_chips_each_route_their_own_rows(four_chips, arch):
     """The whole model at ``SMALL`` (either routed block's) compiled for a
@@ -560,6 +622,44 @@ def test_four_chips_each_route_their_own_rows(four_chips, arch):
                if "tpu_custom_call" in line and "pallas_call" in line]
     assert len(kernels) == 5 * (small["n_layers"]
                                 - small.get("dense_layers", 0))
+    assert "all-gather" not in text and "all-to-all" not in text
+
+
+def test_four_chips_each_attend_their_own_rows(four_chips):
+    """The encoder at the flagship's widths compiled for a v5e 2 x 2 as the
+    plan traces it (``mesh {data: 4}``, inside the mesh): each device runs
+    the attention kernel on its own rows, one kernel a layer, with no
+    exchange of rows between the devices."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from odigos_tpu.parallel.sharding import compile_plan
+
+    mesh = Mesh(np.array(four_chips).reshape(4), ("data",))
+    model = TraceTransformer(TransformerConfig(n_layers=2))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    plan = compile_plan(model, mesh, variables=shapes)
+    rows, L = 16, model.cfg.max_len
+
+    def S(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    args = (jax.tree.map(lambda a: S(a.shape, a.dtype, P()), shapes),
+            S((rows, L, 5), jnp.int32, P("data")),
+            S((rows, L, 3), jnp.float32, P("data")),
+            S((rows, L), jnp.int32, P("data")),
+            S((rows, L), jnp.int32, P("data")))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.set_mesh(mesh):
+            text = plan._packed_jit.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "packed_attention" in line]
+    assert len(kernels) == 2
+    assert f"bf16[{rows // 4 * L},256]" in kernels[0]
     assert "all-gather" not in text and "all-to-all" not in text
 
 
