@@ -1,4 +1,4 @@
-"""Shared flax modules: span embedding trunk + the transformer's four
+"""Shared flax modules: span embedding trunk + the transformer's five
 block kinds (``BLOCK_PARTS``): the pre-LN bidirectional encoder block under
 a learned position table; the decoder block (sandwich RMS norms, rotary
 positions, causal attention within a trace, SwiGLU, no biases) whose stack
@@ -10,9 +10,13 @@ ahead of attention that sends each span to ``experts_per_span`` of
 latent routed block (latent attention: low-rank queries, one compressed
 key/value and one rotary key a span shared by every head; a sigmoid router
 with a selection bias over SiLU-gated experts beside a shared expert; the
-stack's leading layers dense). Both routed blocks run one dispatch,
-``routed_experts``, each under its own rule of choice (``top_softmax``,
-``biased_sigmoid``).
+stack's leading layers dense); and the post-norm routed block (grouped
+query heads normed head by head, rotary positions and a window layer by
+layer, an RMS norm on each sublayer's output and none ahead of it, the
+latent block's sigmoid router, shared expert and dense leading layers,
+and a device that may hold a share of a layer's experts). The routed
+blocks run one dispatch, ``routed_experts``, each under its own rule of
+choice (``top_softmax``, ``biased_sigmoid``).
 
 MXU discipline (see /opt/skills/guides/pallas_guide.md and SURVEY.md env
 notes): feature dims multiples of 128, bfloat16 activations with float32
@@ -134,12 +138,17 @@ PARTS = ("embed", "attn_mask", "attn", "mlp", "final_norm", "head")
 # the rotary columns, the shared key's broadcast and the concatenations;
 # its ``attn`` is the core and the output product alone) and ``dense``
 # (the shared expert of a routed layer and a dense layer's feed-forward).
+# The post-norm routed block has ``dense`` too and adds ``qk``: the
+# per-head RMS norms of queries and keys and the rotary of the layers that
+# have it, between the projections and the core (both ``attn``).
 BLOCK_PARTS = {
     "encoder": PARTS,
     "decoder": ("embed", "attn_mask", "attn", "mlp", "norm", "head"),
     "moe": ("embed", "attn_mask", "attn", "route", "mlp", "norm", "head"),
     "latent_moe": ("embed", "attn_mask", "latent", "attn", "route", "mlp",
                    "dense", "norm", "head"),
+    "postnorm_moe": ("embed", "attn_mask", "qk", "attn", "route", "mlp",
+                     "dense", "norm", "head"),
 }
 
 
@@ -479,7 +488,9 @@ def _back_by_gather(h, order, weight, real, load, gate, up, down, act,
     """The grouped products ``experts`` over the rows of ``h`` in expert
     order, and their rows back to span order by XLA's gather, weighed and
     summed: every slot's k rows ride both gathers, those that hold no span
-    too. Returns (spans, d) in ``h.dtype`` and the rows moved."""
+    too. ``real`` is (spans,) or, under a held share, (spans, k): a
+    choice it leaves out comes back zero. Returns (spans, d) in
+    ``h.dtype`` and the rows moved."""
     spans, k = weight.shape
     y = _into_expert_order(h, order, k)
     with jax.named_scope("mlp"):
@@ -492,7 +503,9 @@ def _back_by_gather(h, order, weight, real, load, gate, up, down, act,
         # a row past the last expert's holds whatever the grouped product
         # left there: its weight is zero, and it is taken out whole so
         # that it cannot be a NaN either
-        y = jnp.where(real[None, :, None], y, 0).astype(jnp.float32)
+        keep = real[None, :, None] if real.ndim == 1 \
+            else real.T[:, :, None]
+        y = jnp.where(keep, y, 0).astype(jnp.float32)
         out = jnp.sum(y * weight.T[:, :, None], axis=0)
     return out.astype(h.dtype), jnp.int32(2 * order.shape[0])
 
@@ -502,14 +515,17 @@ def _back_by_dma(h, order, weight, real, load, gate, up, down, act,
     """The ``megablox.gmm`` products over the rows of ``h`` in expert
     order, and their rows back by the DMA kernels of
     ``route_kernels.combine``: a real assignment's row is moved once, a
-    slot that holds no span's not at all. Returns (spans, d) in
+    slot that holds no span's not at all; ``real`` is (spans,) or, under
+    a held share, (spans, k), and then a choice of an expert held
+    elsewhere is a slot that holds nothing. Returns (spans, d) in
     ``h.dtype`` and the rows moved."""
     spans, k = weight.shape
     y = _into_expert_order(h, order, k)
     with jax.named_scope("mlp"):
         y = _experts_gmm(y, gate, up, down, load, act, interpret)
     with jax.named_scope("route"):
-        n_real = jnp.sum(real, dtype=jnp.int32) * k
+        n_real = jnp.sum(real, dtype=jnp.int32) * (k if real.ndim == 1
+                                                    else 1)
         out = route_kernels.combine(y, order, n_real, weight, real, h.dtype,
                                     interpret)
     return out, order.shape[0] + n_real
@@ -526,10 +542,10 @@ SHAPE_BUCKETING = {
 # jitted so that a stack's layers share one trace and one lowering: the
 # route's kernels take seconds a layer to lower, and a call's set-up pays
 # every lowering of its program
-@partial(jax.jit, static_argnames="act")
+@partial(jax.jit, static_argnames=("act", "first"))
 def routed_experts(h: jnp.ndarray, which: jnp.ndarray, weight: jnp.ndarray,
                    real: jnp.ndarray, gate: jnp.ndarray, up: jnp.ndarray,
-                   down: jnp.ndarray, act=nn.relu,
+                   down: jnp.ndarray, act=nn.relu, first: int | None = None,
                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The routed feed-forward over ``h`` (spans, d): each real span goes
     to the k experts ``which`` (spans, k) names, weighted by ``weight``
@@ -541,14 +557,31 @@ def routed_experts(h: jnp.ndarray, which: jnp.ndarray, weight: jnp.ndarray,
     product, and comes back zero. The rows go into expert order by XLA's
     gather (``_into_expert_order``); they come back by DMA on the TPU
     where ``route_kernels`` takes their dtype and width
-    (``_back_by_dma``), by XLA's gather elsewhere. Returns (spans, d),
-    the assignments each expert took, (n_experts,) int32, and the rows
-    the route moved, int32."""
+    (``_back_by_dma``), by XLA's gather elsewhere.
+
+    ``first`` is for a device that holds a share of a layer's experts
+    (expert parallelism): the router chose over all of them, ``which``
+    names them so, and this device holds the ``gate.shape[0]`` experts
+    from ``first`` on. A choice of an expert held elsewhere becomes a
+    slot that holds nothing, as a padding slot is: sorted past the last
+    held expert and weighed zero, so the output is the part of the
+    feed-forward that the held experts give, under the weights the
+    router gave over all k choices. None: every expert is held here.
+
+    Returns (spans, d), the assignments each held expert took,
+    (gate.shape[0],) int32, and the rows the route moved, int32."""
     spans, k = which.shape
     n_experts = gate.shape[0]
     with jax.named_scope("route"):
-        weight = weight * real[:, None]
-        expert = jnp.where(real[:, None], which, n_experts).reshape(-1)
+        if first is not None:
+            # a choice, not a span, holds an assignment here or not
+            which = which - first
+            real = real[:, None] & (which >= 0) & (which < n_experts)
+            weight = weight * real
+            expert = jnp.where(real, which, n_experts).reshape(-1)
+        else:
+            weight = weight * real[:, None]
+            expert = jnp.where(real[:, None], which, n_experts).reshape(-1)
         order = jnp.argsort(expert, stable=True)
         load = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0,
                        dtype=jnp.int32)
@@ -674,6 +707,19 @@ ROUTED_EMBED_INITS = (
     nn.initializers.variance_scaling(1.0, "fan_out", "normal"))
 
 
+def window_masks(mask: jnp.ndarray, positions: jnp.ndarray,
+                 segments: jnp.ndarray | None, window: int,
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The two masks of a stack whose layers attend to the whole trace or
+    to its last ``window`` spans, each (T, 1, L, L), built once: causal
+    within a trace (``causal_mask``), and that cut to pairs fewer than
+    ``window`` positions apart. A layer takes the one its layout names."""
+    with jax.named_scope("attn_mask"):
+        whole = causal_mask(mask, positions, segments)
+        near = positions[..., None] - positions[..., None, :] < window
+        return whole[:, None], (whole & near)[:, None]
+
+
 class MoeDecoder(nn.Module):
     """Embedding trunk + a stack of routed blocks, each applied once.
     ``rope_layout`` and ``window_layout`` say layer by layer whether the
@@ -716,11 +762,7 @@ class MoeDecoder(nn.Module):
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(mask.shape[-1]),
                                          mask.shape)
-        with jax.named_scope("attn_mask"):
-            whole = causal_mask(mask, positions, segments)
-            near = positions[..., None] - positions[..., None, :] \
-                < self.window
-            masks = (whole[:, None], (whole & near)[:, None])
+        masks = window_masks(mask, positions, segments, self.window)
         with jax.named_scope("attn"):
             cos, sin = rotary_tables(positions, self.head_dim,
                                      self.rope_theta, self.dtype)
@@ -750,6 +792,53 @@ class MoeDecoder(nn.Module):
 # of the published cut kept 31 of 64 experts busy where 41 are at zero
 # and 37-38 at 0.02 (PERF.md section 6, PR 37).
 SELECTION_BIAS_SCALE = 0.02
+
+
+# a SwiGLU's three kernels: a dense layer's, and the shared expert's
+# beside the routed ones
+DENSE = ("gate_proj", "up_proj", "down_proj")
+SHARED = ("shared_gate", "shared_up", "shared_down")
+
+
+def swiglu(dense, h: jnp.ndarray, width: int, d_model: int,
+           names: tuple[str, str, str] = DENSE) -> jnp.ndarray:
+    """(silu(h Wg) * (h Wu)) Wd, ``width`` wide, under scope ``dense``:
+    a dense layer's feed-forward or a shared expert, each kernel made by
+    ``dense(features, name)`` in the calling module."""
+    gate, up, down = names
+    with jax.named_scope("dense"):
+        h = nn.silu(dense(width, gate)(h)) * dense(width, up)(h)
+        return dense(d_model, down)(h)
+
+
+def sigmoid_experts(module: nn.Module, dense, h: jnp.ndarray,
+                    mask: jnp.ndarray, n_experts: int, k: int,
+                    d_expert: int, scale: float, held: int | None = None,
+                    first: int | None = None) -> tuple[jnp.ndarray, Any]:
+    """The routed experts of a layer under ``biased_sigmoid``, as parameters
+    of ``module`` (the selection bias its own first parameter, beside
+    ``dense``'s router and the expert kernels): the router's ``n_experts``
+    logits read from ``h`` in float32, k chosen by score plus bias and
+    weighed by score, and the SiLU-gated experts run by
+    ``routed_experts``. ``held`` experts from ``first`` on are this
+    device's share (None: all ``n_experts``). Returns (h's shape) and the
+    route's counts."""
+    d = h.shape[-1]
+    with jax.named_scope("route"):
+        logits = dense(n_experts, "router", jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)(h)
+        bias = module.param("router_bias",
+                            _rounded(nn.initializers.normal(
+                                SELECTION_BIAS_SCALE)),
+                            (n_experts,), module.param_dtype)
+    kernels = expert_kernels(n_experts if held is None else held, d,
+                             d_expert, module.param_dtype, module.dtype)
+    which, weight = biased_sigmoid(logits.reshape(-1, n_experts), bias, k,
+                                   scale)
+    y, *counts = each_device_its_rows(
+        partial(routed_experts, act=nn.silu, first=first))(
+        h.reshape(-1, d), which, weight, mask.reshape(-1), *kernels)
+    return y.reshape(h.shape), counts
 
 
 class LatentMoeBlock(nn.Module):
@@ -810,12 +899,6 @@ class LatentMoeBlock(nn.Module):
             with jax.named_scope("norm"):
                 return rms(name, h)
 
-        def swiglu(h: jnp.ndarray, width: int, gate: str, up: str,
-                   down: str) -> jnp.ndarray:
-            with jax.named_scope("dense"):
-                h = nn.silu(dense(width, gate)(h)) * dense(width, up)(h)
-                return dense(self.d_model, down)(h)
-
         def expand(latent: jnp.ndarray, name: str, first: int,
                    second: int) -> tuple[jnp.ndarray, jnp.ndarray]:
             """Out of a latent to every head's ``first`` and ``second``
@@ -852,28 +935,14 @@ class LatentMoeBlock(nn.Module):
         x = x + h
         h = norm("mlp_norm", x)
         if not self.routed:
-            return x + swiglu(h, self.d_ff, "gate_proj", "up_proj",
-                              "down_proj"), None
-        with jax.named_scope("route"):
-            logits = dense(self.n_experts, "router", jnp.float32,
-                           precision=jax.lax.Precision.HIGHEST)(h)
-            bias = self.param("router_bias",
-                              _rounded(nn.initializers.normal(
-                                  SELECTION_BIAS_SCALE)),
-                              (self.n_experts,), self.param_dtype)
-        kernels = expert_kernels(self.n_experts, self.d_model, self.d_expert,
-                                 self.param_dtype, self.dtype)
-        which, weight = biased_sigmoid(
-            logits.reshape(-1, self.n_experts), bias, self.experts_per_span,
-            self.route_scale)
-        y, *counts = each_device_its_rows(
-            partial(routed_experts, act=nn.silu))(
-            h.reshape(-1, self.d_model), which, weight, mask.reshape(-1),
-            *kernels)
-        x = x + y.reshape(x.shape)
+            return x + swiglu(dense, h, self.d_ff, self.d_model), None
+        y, counts = sigmoid_experts(
+            self, dense, h, mask, self.n_experts, self.experts_per_span,
+            self.d_expert, self.route_scale)
+        x = x + y
         if self.shared_experts:
-            x = x + swiglu(h, self.shared_experts * self.d_expert,
-                           "shared_gate", "shared_up", "shared_down")
+            x = x + swiglu(dense, h, self.shared_experts * self.d_expert,
+                           self.d_model, SHARED)
         return x, counts
 
 
@@ -937,6 +1006,164 @@ class LatentMoeDecoder(nn.Module):
                 i >= self.dense_layers, self.dtype, self.param_dtype,
                 self.norm_eps, name=f"block_{i}")(x, mask, attn_mask, cos,
                                                   sin)
+            if count is not None:
+                counts.append(count)
+        with jax.named_scope("norm"):
+            x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           param_dtype=self.param_dtype,
+                           name="final_rms")(x)
+        return (x, *routed_counts(counts))
+
+
+class PostNormMoeBlock(nn.Module):
+    """Post-norm routed decoder block: no norm ahead of a sublayer, an RMS
+    norm on each sublayer's output before it joins the residual stream.
+
+    Attention has ``n_heads`` query heads of ``head_dim`` over
+    ``n_kv_heads`` key/value heads; each head's query and key is RMS-normed
+    over its own columns (one scale of ``head_dim`` for all query heads,
+    one for all key heads) and then rotated where ``rope`` says: a layer
+    without it has no positions at all (its mask, the caller's, carries
+    the causal order).
+
+    The feed-forward of a ``routed`` layer reads the raw residual stream:
+    ``sigmoid_experts`` over ``n_experts`` SiLU-gated experts of width
+    ``d_expert``, of which this device holds ``experts_held`` from
+    ``first_expert_held`` on (the router and the weights over all k choices
+    are the whole layer's; the held share's part is what is computed),
+    plus, where ``shared_experts`` > 0, a shared expert every span takes;
+    that of a layer that is not routed is one SwiGLU of width ``d_ff``,
+    and it returns no counts. No bias but the router's selection bias;
+    parameters are held in ``param_dtype``."""
+
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    d_ff: int
+    n_experts: int
+    experts_held: int
+    first_expert_held: int
+    experts_per_span: int
+    d_expert: int
+    shared_experts: int
+    route_scale: float
+    rope: bool
+    routed: bool
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, mask: jnp.ndarray,
+                 attn_mask: jnp.ndarray, cos: jnp.ndarray,
+                 sin: jnp.ndarray) -> tuple[jnp.ndarray, Any]:
+        def dense(features: int, name: str, dtype: Any = self.dtype,
+                  **kw) -> nn.Dense:
+            return nn.Dense(features, use_bias=False, dtype=dtype,
+                            param_dtype=self.param_dtype,
+                            kernel_init=rounded_lecun(), name=name, **kw)
+
+        def rms(name: str, h: jnp.ndarray) -> jnp.ndarray:
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              param_dtype=self.param_dtype, name=name)(h)
+
+        def norm(name: str, h: jnp.ndarray) -> jnp.ndarray:
+            with jax.named_scope("norm"):
+                return rms(name, h)
+
+        H, K, hd = self.n_heads, self.n_kv_heads, self.head_dim
+        with jax.named_scope("attn"):
+            q = dense(H * hd, "q_proj")(x).reshape(x.shape[:-1] + (H, hd))
+            k = dense(K * hd, "k_proj")(x).reshape(x.shape[:-1] + (K, hd))
+            v = dense(K * hd, "v_proj")(x).reshape(x.shape[:-1] + (K, hd))
+        with jax.named_scope("qk"):
+            q, k = rms("q_norm", q), rms("k_norm", k)
+            if self.rope:
+                q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+        with jax.named_scope("attn"):
+            h = attention(q, k, v, attn_mask, self.dtype)
+            h = dense(self.d_model, "o_proj")(
+                h.reshape(x.shape[:-1] + (H * hd,)))
+        x = x + norm("attn_out_norm", h)
+        if not self.routed:
+            return x + norm("mlp_out_norm",
+                            swiglu(dense, x, self.d_ff, self.d_model)), None
+        f, counts = sigmoid_experts(
+            self, dense, x, mask, self.n_experts, self.experts_per_span,
+            self.d_expert, self.route_scale, self.experts_held,
+            None if self.experts_held == self.n_experts
+            else self.first_expert_held)
+        if self.shared_experts:
+            f = f + swiglu(dense, x, self.shared_experts * self.d_expert,
+                           self.d_model, SHARED)
+        return x + norm("mlp_out_norm", f), counts
+
+
+class PostNormMoeDecoder(nn.Module):
+    """Embedding trunk + a stack of post-norm routed blocks, each applied
+    once: the first ``dense_layers`` with a dense feed-forward, the rest
+    routed. ``rope_layout`` and ``window_layout`` say layer by layer
+    whether the block rotates its queries and keys and whether its
+    attention is cut to the last ``window`` spans of the trace
+    (``window_masks``). Returns the normed output, (routed layers,
+    experts_held) the assignments each held expert of each routed layer
+    took, and the rows the route moved (``routed_counts``)."""
+
+    service_vocab: int
+    name_vocab: int
+    attr_vocab: int
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    n_layers: int
+    dense_layers: int
+    d_ff: int
+    n_experts: int
+    experts_held: int
+    first_expert_held: int
+    experts_per_span: int
+    d_expert: int
+    shared_experts: int
+    route_scale: float
+    rope_layout: tuple[int, ...]
+    window_layout: tuple[int, ...]
+    window: int
+    rope_theta: float
+    norm_eps: float
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, categorical, continuous, mask,
+                 deterministic: bool = True,
+                 positions: jnp.ndarray | None = None,
+                 segments: jnp.ndarray | None = None):
+        with jax.named_scope("embed"):
+            x = SpanEmbedder(self.service_vocab, self.name_vocab,
+                             self.attr_vocab, self.d_model, self.dtype,
+                             *ROUTED_EMBED_INITS,
+                             name="embed")(categorical, continuous)
+            x = x * mask[..., None].astype(self.dtype)
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(mask.shape[-1]),
+                                         mask.shape)
+        masks = window_masks(mask, positions, segments, self.window)
+        with jax.named_scope("qk"):
+            cos, sin = rotary_tables(positions, self.head_dim,
+                                     self.rope_theta, self.dtype)
+        counts = []
+        for i, (rope, window) in enumerate(zip(self.rope_layout,
+                                               self.window_layout)):
+            x, count = PostNormMoeBlock(
+                self.d_model, self.n_heads, self.n_kv_heads, self.head_dim,
+                self.d_ff, self.n_experts, self.experts_held,
+                self.first_expert_held, self.experts_per_span,
+                self.d_expert, self.shared_experts, self.route_scale,
+                bool(rope), i >= self.dense_layers, self.dtype,
+                self.param_dtype, self.norm_eps, name=f"block_{i}")(
+                x, mask, masks[bool(window)], cos, sin)
             if count is not None:
                 counts.append(count)
         with jax.named_scope("norm"):

@@ -182,11 +182,12 @@ def _weigh(words: jax.Array, weight: jax.Array, real: jax.Array, dtype,
            interpret: bool) -> jax.Array:
     """``sum_j weight[t, j] * row j of span t`` in float32, j in order:
     ``words`` (k, T, d/2) uint32 word rows (as the module says),
-    ``weight`` (T, k) float32, ``real`` (T, 1) int32; a span that is not
-    real comes back zero whatever its rows hold. Returns (T, d) in
-    ``dtype``."""
+    ``weight`` (T, k) float32, ``real`` (T, 1) int32, or (T, k) choice by
+    choice; a span that is not real, or a choice that is not, comes back
+    zero whatever its rows hold. Returns (T, d) in ``dtype``."""
     k, T, W = words.shape
     tile = _tile(T, max(16, STAGING_BYTES // (2 * k * W * 4)))
+    by_choice = real.shape[1] > 1
 
     def kernel(words_ref, weight_ref, real_ref, out_ref):
         def rows(a, c):
@@ -195,9 +196,10 @@ def _weigh(words: jax.Array, weight: jax.Array, real: jax.Array, dtype,
             lo = hi = jnp.zeros((16, W), jnp.float32)
             for j in range(k):
                 word = words_ref[j, at, :]
+                mine = ok[:, j:j + 1] if by_choice else ok
                 # a bfloat16 is the high half of the float32 it widens to
                 for half, bits in ((0, word << 16), (1, word & HIGH)):
-                    term = jnp.where(ok, jax.lax.bitcast_convert_type(
+                    term = jnp.where(mine, jax.lax.bitcast_convert_type(
                         bits, jnp.float32) * w[:, j:j + 1], 0.0)
                     if half:
                         hi = hi + term
@@ -214,7 +216,7 @@ def _weigh(words: jax.Array, weight: jax.Array, real: jax.Array, dtype,
         grid=(T // tile,),
         in_specs=[pl.BlockSpec((k, tile, W), lambda i: (0, i, 0)),
                   pl.BlockSpec((tile, k), lambda i: (i, 0)),
-                  pl.BlockSpec((tile, 1), lambda i: (i, 0))],
+                  pl.BlockSpec((tile, real.shape[1]), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile, 2 * W), lambda i: (i, 0)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -230,8 +232,9 @@ def combine(y: jax.Array, order: jax.Array, n_real: jax.Array,
     in float32, j in order, in ``dtype``; ``y`` (m, d) bfloat16 in the
     sorted order ``order`` (T k,) makes (assignment t k + j at row r
     where order[r] = t k + j), its first ``n_real`` rows real; ``weight``
-    (T, k) float32 and ``real`` (T,) bool. A span that is not real comes
-    back zero. Returns (T, d)."""
+    (T, k) float32 and ``real`` (T,) bool, or (T, k) where a choice and
+    not a span holds an assignment (a held share of the experts). A span
+    or a choice that is not real comes back zero. Returns (T, d)."""
     T, k = weight.shape
     m, d = y.shape
     spans = -(-T // 16) * 16
@@ -242,6 +245,7 @@ def combine(y: jax.Array, order: jax.Array, n_real: jax.Array,
                            interpret)).reshape(k, spans, d // 2)
     pad = ((0, spans - T), (0, 0))
     out = _weigh(words, jnp.pad(weight.astype(jnp.float32), pad),
-                 jnp.pad(real[:, None].astype(jnp.int32), pad), dtype,
+                 jnp.pad((real[:, None] if real.ndim == 1 else real)
+                         .astype(jnp.int32), pad), dtype,
                  interpret)
     return out[:T]

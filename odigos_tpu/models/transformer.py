@@ -9,7 +9,7 @@ in bfloat16, data-parallel across the mesh (odigos_tpu.parallel).
 Default dims are MXU-shaped: d_model 256, d_ff 1024, heads 4 — all multiples
 of the 128-lane tile.
 
-``TransformerConfig.block`` picks one of four block kinds (``layers.py``
+``TransformerConfig.block`` picks one of five block kinds (``layers.py``
 ``BLOCK_PARTS``): the default pre-LN bidirectional ``encoder``; the
 ``decoder`` block whose stack is looped ``passes`` times on the device; the
 routed ``moe`` block (grouped query heads, rotary positions and a
@@ -19,8 +19,14 @@ experts of which a span takes ``experts_per_span``, parameters held in
 attention through ``q_rank`` and ``kv_rank`` with one rotary key a span, a
 sigmoid router with a selection bias over SiLU-gated experts beside
 ``shared_experts`` shared ones, the first ``dense_layers`` layers dense at
-``d_ff``). A routed kind's ``score_packed_counted`` also returns what the
-router assigned and how many rows its route moved.
+``d_ff``); and the post-norm routed ``postnorm_moe`` block (an RMS norm
+on each sublayer's output and none ahead of it, grouped query heads whose
+queries and keys are normed head by head, rotary and a window layer by
+layer, the latent block's sigmoid router, shared expert and dense leading
+layers, and a device that holds ``experts_held`` of the router's
+``n_experts`` from ``first_expert_held`` on). A routed kind's
+``score_packed_counted`` also returns what the router assigned and how
+many rows its route moved.
 """
 
 from __future__ import annotations
@@ -35,7 +41,8 @@ from flax import linen as nn
 
 from . import attention_kernel, jitstats
 from .layers import (BLOCK_PARTS, ROWS_AXIS, Encoder, LatentMoeDecoder,
-                     LoopedDecoder, MoeDecoder, kernel_attends)
+                     LoopedDecoder, MoeDecoder, PostNormMoeDecoder,
+                     kernel_attends)
 
 # Shape-bucketing strategy per jitted scoring entry point (the package
 # hygiene test asserts every jit path in models/ and parallel/ declares
@@ -54,8 +61,12 @@ SHAPE_BUCKETING = {
 
 
 # expert assignments of real spans, as a routed model's own top-k counted
-# them on the device (spans x experts a span x layers a call)
+# them on the device (spans x experts a span x layers a call); of a
+# device that holds a share of a layer's experts, those its experts took
 EXPERT_ASSIGNMENTS_METRIC = "odigos_anomaly_expert_assignments_total"
+# every choice the router made for real spans, held here or not (spans x
+# experts a span x routed layers), where the device holds a share
+EXPERT_CHOICES_METRIC = "odigos_anomaly_expert_choices_total"
 
 
 @dataclass(frozen=True)
@@ -93,9 +104,24 @@ class TransformerConfig:
     # sigmoid scores plus a selection bias to experts_per_span of
     # n_experts SiLU-gated experts d_expert wide, weighted by the unbiased
     # scores normalised and times route_scale, beside shared_experts
-    # experts every span takes. passes is the decoder block's; rope_theta
-    # and norm_eps are the decoder and the routed blocks'; _BLOCK_KEYS
-    # says which of the keys from n_kv_heads down each routed block takes.
+    # experts every span takes. "postnorm_moe": the post-norm routed block
+    # (no norm ahead of a sublayer, an RMS norm on each sublayer's output
+    # before the residual add, causal within a trace, each layer applied
+    # once): the routed block's grouped heads, rope_layout, window_layout
+    # and window, each head's query and key RMS-normed over its head_dim
+    # columns before the rotary (a layer without rotary has no
+    # positions), the latent block's dense_layers, d_ff, sigmoid router
+    # with its selection bias over n_experts, route_scale and
+    # shared_experts, the router reading the raw residual stream; this
+    # device holds experts_held of the n_experts (0: all of them) from
+    # first_expert_held on, and computes the part of the feed-forward
+    # they give (expert parallelism's share of a layer); weight_seed, where
+    # set, is the one key every weight is drawn from, whatever key init is
+    # handed: a deployment that serves one checkpoint, not a draw a run.
+    # passes is the
+    # decoder block's; rope_theta and norm_eps are the decoder and the
+    # routed blocks'; _BLOCK_KEYS says which of the keys from n_kv_heads
+    # down each routed block takes.
     block: str = "encoder"
     passes: int = 1
     rope_theta: float = 10000.0
@@ -117,6 +143,9 @@ class TransformerConfig:
     shared_experts: int = 0
     dense_layers: int = 0
     route_scale: float = 1.0
+    experts_held: int = 0
+    first_expert_held: int = 0
+    weight_seed: int | None = None
 
     def __post_init__(self) -> None:
         if self.block not in BLOCK_PARTS:
@@ -137,13 +166,19 @@ class TransformerConfig:
                              f"keys are the routed block's "
                              f"({', '.join(kinds)})")
         faults = {"moe": self._moe_faults,
-                  "latent_moe": self._latent_moe_faults}.get(
+                  "latent_moe": self._latent_moe_faults,
+                  "postnorm_moe": self._postnorm_moe_faults}.get(
             self.block, list)()
         if faults:
             raise ValueError(f"block {self.block!r} does not compose: "
                              + "; ".join(faults))
 
     def _moe_faults(self) -> list[str]:
+        return self._grouped_faults() + self._expert_faults()
+
+    def _grouped_faults(self) -> list[str]:
+        """Of grouped query heads laid out layer by layer (rotary and
+        window), what does not compose."""
         faults = []
         if not (self.n_kv_heads > 0 and self.head_dim > 0
                 and self.n_heads % max(self.n_kv_heads, 1) == 0):
@@ -153,7 +188,6 @@ class TransformerConfig:
         if self.head_dim % 2:
             faults.append(f"head_dim {self.head_dim} is odd: rotary "
                           f"positions pair its columns")
-        faults += self._expert_faults()
         if not len(self.rope_layout) == len(self.window_layout) \
                 == self.n_layers:
             faults.append(f"rope_layout ({len(self.rope_layout)}) and "
@@ -180,7 +214,12 @@ class TransformerConfig:
         if self.qk_rope_dim % 2:
             faults.append(f"qk_rope_dim {self.qk_rope_dim} is odd: rotary "
                           f"positions pair its columns")
-        faults += self._expert_faults()
+        return faults + self._sigmoid_faults()
+
+    def _sigmoid_faults(self) -> list[str]:
+        """Of a stack of leading dense layers and routed ones under the
+        sigmoid router beside shared experts, what does not compose."""
+        faults = self._expert_faults()
         if not 0 <= self.dense_layers < self.n_layers:
             faults.append(f"dense_layers {self.dense_layers} of n_layers "
                           f"{self.n_layers} leaves no routed layer (a "
@@ -190,11 +229,34 @@ class TransformerConfig:
                           f"route_scale {self.route_scale}")
         return faults
 
+    def _postnorm_moe_faults(self) -> list[str]:
+        faults = self._grouped_faults() + self._sigmoid_faults()
+        first, held = self.held_experts
+        if not (held >= 1 and first >= 0
+                and first + held <= self.n_experts):
+            faults.append(f"experts_held {self.experts_held} from "
+                          f"first_expert_held {first} do not lie within "
+                          f"n_experts {self.n_experts}")
+        return faults
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(the first expert held on this device, how many): all of
+        ``n_experts`` unless ``experts_held`` says a share."""
+        return self.first_expert_held, self.experts_held or self.n_experts
+
+    @property
+    def shares_experts(self) -> bool:
+        """Whether this device holds a share of each routed layer's
+        experts and not all of them."""
+        return self.block == "postnorm_moe" \
+            and self.held_experts[1] < self.n_experts
+
     @property
     def routed(self) -> bool:
         """Whether the block routes spans to experts (and so counts its
         assignments on the device)."""
-        return self.block in ("moe", "latent_moe")
+        return self.block in ("moe", "latent_moe", "postnorm_moe")
 
     @property
     def layer_applications(self) -> int:
@@ -206,24 +268,32 @@ class TransformerConfig:
         """What every ``tpu/score`` span says of the model behind the
         call: the block kind and how many blocks a span passes through;
         of a routed block also its experts and how many a span takes; of
-        the routed block how many layers rotate and how many have a
-        window, of the latent routed block its attention's ranks, its
-        shared experts and how many leading layers are dense."""
+        the routed and the post-norm routed blocks how many layers
+        rotate and how many have a window, of the latent routed block its
+        attention's ranks, of both sigmoid-routed blocks their shared
+        experts and how many leading layers are dense, and of the
+        post-norm routed block which of the router's experts this device
+        holds."""
         attrs = {"model.block": self.block, "model.passes": self.passes,
                  "model.layer_applications": self.layer_applications}
         if self.routed:
             attrs.update({"model.experts": self.n_experts,
                           "model.experts_per_span": self.experts_per_span})
-        if self.block == "moe":
+        if self.block in ("moe", "postnorm_moe"):
             attrs.update({
                 "model.layers_rotary": sum(map(bool, self.rope_layout)),
                 "model.layers_window": sum(map(bool, self.window_layout))})
         if self.block == "latent_moe":
             attrs.update({"model.attention": "latent",
                           "model.q_rank": self.q_rank,
-                          "model.kv_rank": self.kv_rank,
-                          "model.shared_experts": self.shared_experts,
+                          "model.kv_rank": self.kv_rank})
+        if self.block in ("latent_moe", "postnorm_moe"):
+            attrs.update({"model.shared_experts": self.shared_experts,
                           "model.layers_dense": self.dense_layers})
+        if self.block == "postnorm_moe":
+            first, held = self.held_experts
+            attrs.update({"model.experts_held": held,
+                          "model.first_expert_held": first})
         return attrs
 
     @property
@@ -231,6 +301,9 @@ class TransformerConfig:
         """Which of the numbers a call counts on the device
         (``score_packed_counted``, by span attribute name) is added to
         which counter; nothing for a block that counts nothing."""
+        if self.shares_experts:
+            return {"moe.assignments": EXPERT_ASSIGNMENTS_METRIC,
+                    "moe.choices": EXPERT_CHOICES_METRIC}
         if self.routed:
             return {"moe.assignments": EXPERT_ASSIGNMENTS_METRIC}
         return {}
@@ -239,13 +312,16 @@ class TransformerConfig:
 # the routed blocks' own keys and the blocks that take each; any other
 # block refuses one that is set
 _BLOCK_KEYS = {
-    **{k: ("latent_moe", "moe") for k in (
+    **{k: ("latent_moe", "moe", "postnorm_moe") for k in (
         "n_experts", "experts_per_span", "d_expert", "param_dtype")},
-    **{k: ("moe",) for k in (
+    **{k: ("moe", "postnorm_moe") for k in (
         "n_kv_heads", "head_dim", "rope_layout", "window_layout", "window")},
     **{k: ("latent_moe",) for k in (
-        "q_rank", "kv_rank", "qk_nope_dim", "qk_rope_dim", "v_dim",
-        "shared_experts", "dense_layers", "route_scale")}}
+        "q_rank", "kv_rank", "qk_nope_dim", "qk_rope_dim", "v_dim")},
+    **{k: ("latent_moe", "postnorm_moe") for k in (
+        "shared_experts", "dense_layers", "route_scale")},
+    **{k: ("postnorm_moe",) for k in (
+        "experts_held", "first_expert_held", "weight_seed")}}
 
 
 class _TraceTransformerModule(nn.Module):
@@ -271,6 +347,16 @@ class _TraceTransformerModule(nn.Module):
                 c.experts_per_span, c.d_expert, c.rope_layout,
                 c.window_layout, c.window, c.rope_theta, c.norm_eps,
                 c.dtype, c.param_dtype, name="encoder")
+        elif c.block == "postnorm_moe":
+            first, held = c.held_experts
+            backbone = PostNormMoeDecoder(
+                c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
+                c.n_heads, c.n_kv_heads, c.head_dim, c.n_layers,
+                c.dense_layers, c.d_ff, c.n_experts, held, first,
+                c.experts_per_span, c.d_expert, c.shared_experts,
+                c.route_scale, c.rope_layout, c.window_layout, c.window,
+                c.rope_theta, c.norm_eps, c.dtype, c.param_dtype,
+                name="encoder")
         else:
             backbone = LatentMoeDecoder(
                 c.service_vocab, c.name_vocab, c.attr_vocab, c.d_model,
@@ -332,7 +418,11 @@ class TraceTransformer:
 
     def init(self, rng: jax.Array, sample_cat=None, sample_cont=None,
              sample_mask=None):
+        """The model's variables drawn from ``rng``, or from
+        ``PRNGKey(weight_seed)`` where the configuration fixes one."""
         c = self.cfg
+        if c.weight_seed is not None:
+            rng = jax.random.PRNGKey(c.weight_seed)
         if sample_cat is None:
             from ..features.featurizer import CAT_FIELDS, CONT_FIELDS
             width = len(CAT_FIELDS) + c.attr_slots
@@ -377,7 +467,12 @@ class TraceTransformer:
         the path that moved them (two a real assignment where the TPU's
         kernels move them, two a slot's choice, real or not, where XLA's
         gathers do), under the names they have on the ``tpu/score``
-        span."""
+        span. Where the device holds a share of each routed layer's
+        experts, the assignments, the busiest and the fewest busy are
+        those of the held experts, and two more say what the router
+        chose over all of them: ``moe.choices`` (real spans x
+        experts_per_span x routed layers, held here or not) and
+        ``moe.held_share``, the assignments over the choices."""
         mask = segments > 0
         (span_logit, _), state = self.module.apply(
             variables, categorical, continuous, mask,
@@ -386,12 +481,19 @@ class TraceTransformer:
         (moved,) = state["moe"]["rows_moved"]
         with jax.named_scope("head"):
             total = load.sum()
-            return jax.nn.sigmoid(span_logit), {
+            scores = jax.nn.sigmoid(span_logit)
+            counts = {
                 "moe.assignments": total,
                 "moe.load_max_over_mean":
                     load.max() * load.size / jnp.maximum(total, 1),
                 "moe.experts_busy_min": (load > 0).sum(axis=-1).min(),
                 "moe.rows_moved": moved}
+            if self.cfg.shares_experts:
+                choices = mask.sum(dtype=jnp.int32) * (
+                    self.cfg.experts_per_span * load.shape[0])
+                counts["moe.choices"] = choices
+                counts["moe.held_share"] = total / jnp.maximum(choices, 1)
+            return scores, counts
 
     def loss_fn(self, variables, categorical, continuous, mask,
                 span_labels, trace_labels, rngs=None):

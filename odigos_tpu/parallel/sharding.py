@@ -140,10 +140,12 @@ PARTITION_RULES: tuple[tuple[str, P], ...] = (
     # d_model)) are replicated: the grouped products run over rows sorted
     # by expert, a data-dependent split that no static spec of d_expert
     # or of the expert axis divides, and an expert axis over "model" needs
-    # a layer told which experts it holds and an exchange of spans
-    # (ROADMAP C5). The latent routed block's selection bias goes with its
-    # router. WHOLE_ONLY below refuses a "model" axis for them rather than
-    # replicating 95% of the parameters in silence.
+    # an exchange of spans between the devices (ROADMAP C5): a layer told
+    # which experts it holds is the post-norm routed block's, whose
+    # expert kernels are this device's share alone and whose other
+    # kernels fall under the rules above. The sigmoid routers' selection
+    # bias goes with its router. WHOLE_ONLY below refuses a "model" axis
+    # for them rather than replicating most of the parameters in silence.
     (WHOLE_ONLY, P()),
     (r"dec_ff1/kernel$", P(None, "model")),            # autoencoder decoder
     (r"dec_ff2/kernel$", P("model", None)),

@@ -517,7 +517,7 @@ def test_the_config_refuses_what_does_not_compose():
     moe = arch_case("moe_decoder")[1].SMALL
     for key, value in (("kv_rank", 8), ("shared_experts", 1),
                        ("dense_layers", 1), ("route_scale", 1.8)):
-        with pytest.raises(ValueError, match=key + r".*\(latent_moe\)"):
+        with pytest.raises(ValueError, match=key + r".*\(latent_moe[,)]"):
             make_model_config("transformer", {**moe, key: value})
     cfg = make_model_config("transformer", dict(SMALL, dtype="bfloat16"))
     assert cfg.param_dtype == jnp.bfloat16 and hash(cfg) is not None
@@ -525,7 +525,8 @@ def test_the_config_refuses_what_does_not_compose():
 
 
 def test_the_block_states_its_scopes():
-    assert set(BLOCK_PARTS) == {"encoder", "decoder", "moe", "latent_moe"}
+    assert set(BLOCK_PARTS) == {"encoder", "decoder", "moe", "latent_moe",
+                                "postnorm_moe"}
     mine = BLOCK_PARTS["latent_moe"]
     assert set(mine) - set(BLOCK_PARTS["moe"]) == {"latent", "dense"}
     assert set(mine) == set(ARCH.PARTS)
@@ -782,18 +783,28 @@ def test_the_cell_is_the_benchmarks_by_entries_alone():
                              "step_dense_ms.backlog"}
     for name in ("step_latent_ms.backlog", "step_dense_ms.backlog"):
         m = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and m["moves"] == "spans_per_s"
+        # a later cell with the part appends its name; the accepted one
+        # stays
+        assert m["workloads"][0] == CELL and m["moves"] == "spans_per_s"
         assert (m["layer"], m["source"], m["unit"], m["better"]) \
             == ("model step", "device_trace", "ms", "lower")
-    # additions alone: the entries stand last in their lists, and the
-    # cell's name last in every list it was appended to
-    assert bench["workloads"][-1] is cell
-    assert bench["configs"][-1]["name"] == "glm-4.7-flash"
-    assert [m["name"] for m in bench["per_layer"][-2:]] \
-        == ["step_latent_ms.backlog", "step_dense_ms.backlog"]
+    # additions alone: the entries stand after every one that was there
+    # before them, and the cell's name after the earlier cells' in every
+    # list it was appended to (a later cell's entries stand after it)
+    earlier = ("vit-h14.backlog", "ouro-2.6b.backlog",
+               "smallthinker-21b-a3b.backlog")
+    names = [w["name"] for w in bench["workloads"]]
+    assert names.index(CELL) == len(earlier) + 1     # vit-h14.steady too
+    configs = [c["name"] for c in bench["configs"]]
+    assert configs.index("glm-4.7-flash") == 3
+    per_layer = [m["name"] for m in bench["per_layer"]]
+    at = per_layer.index("step_latent_ms.backlog")
+    assert per_layer[at + 1] == "step_dense_ms.backlog"
+    assert at > per_layer.index("experts_roofline.backlog")
     for m in bench["end_to_end"] + bench["per_layer"]:
         if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL
+            w = m["workloads"]
+            assert all(w.index(x) < w.index(CELL) for x in earlier if x in w)
 
 
 @pytest.mark.parametrize("seed", [21, 2**31 + 22])
