@@ -515,12 +515,22 @@ def _back_by_dma(h, order, weight, real, load, gate, up, down, act,
     """The ``megablox.gmm`` products over the rows of ``h`` in expert
     order, and their rows back by the DMA kernels of
     ``route_kernels.combine``: a real assignment's row is moved once, a
-    slot that holds no span's not at all; ``real`` is (spans,) or, under
-    a held share, (spans, k), and then a choice of an expert held
-    elsewhere is a slot that holds nothing. Returns (spans, d) in
-    ``h.dtype`` and the rows moved."""
+    slot that holds no span's not at all. ``real`` is (spans,), and the
+    rows go in by XLA's gather, every slot's k; or, under a held share,
+    (spans, k): a choice of an expert held elsewhere is then a slot that
+    holds nothing, and the rows go in by DMA too, a held choice's once
+    (``route_kernels.into_expert_order``: the rows ``_into_expert_order``
+    gives up to the last held choice, and no other). Returns (spans, d)
+    in ``h.dtype`` and the rows moved."""
     spans, k = weight.shape
-    y = _into_expert_order(h, order, k)
+    if real.ndim == 1:
+        y, moved_in = _into_expert_order(h, order, k), order.shape[0]
+    else:
+        with jax.named_scope("route"):
+            moved_in = jnp.sum(real, dtype=jnp.int32)
+            rows = -(-order.shape[0] // GROUP_ROWS) * GROUP_ROWS
+            y = route_kernels.into_expert_order(h, order, k, moved_in, rows,
+                                                interpret)
     with jax.named_scope("mlp"):
         y = _experts_gmm(y, gate, up, down, load, act, interpret)
     with jax.named_scope("route"):
@@ -528,7 +538,7 @@ def _back_by_dma(h, order, weight, real, load, gate, up, down, act,
                                                     else 1)
         out = route_kernels.combine(y, order, n_real, weight, real, h.dtype,
                                     interpret)
-    return out, order.shape[0] + n_real
+    return out, moved_in + n_real
 
 
 # Shape-bucketing strategy of the jitted function here (the package hygiene
@@ -554,10 +564,11 @@ def routed_experts(h: jnp.ndarray, which: jnp.ndarray, weight: jnp.ndarray,
     run as grouped products over the assignments sorted by expert: no
     span is dropped and there is no capacity. A slot that holds no span
     (``real`` False) is sorted past the last expert, so it enters no
-    product, and comes back zero. The rows go into expert order by XLA's
-    gather (``_into_expert_order``); they come back by DMA on the TPU
-    where ``route_kernels`` takes their dtype and width
-    (``_back_by_dma``), by XLA's gather elsewhere.
+    product, and comes back zero. On the TPU, where ``route_kernels``
+    takes the rows' dtype and width (``_back_by_dma``), the rows go into
+    expert order by XLA's gather (``_into_expert_order``), or by DMA, a
+    held choice's alone, under a held share (below), and come back by
+    DMA; elsewhere they go and come back by XLA's gathers.
 
     ``first`` is for a device that holds a share of a layer's experts
     (expert parallelism): the router chose over all of them, ``which``

@@ -1,16 +1,24 @@
-"""The way back of the routed block's rows on the TPU, as two Pallas
-kernels: each real assignment's row of the grouped products' output, in
-expert order, is moved by one DMA to its place in assignment order
-(``_scatter``), and a span's k rows are then read in bulk, weighed in
-float32 and summed in the order j = 0..k-1 (``_weigh``). Rows of slots
-that hold no span are never moved. ``routed_experts`` (``layers.py``)
-runs ``combine`` on the TPU after the grouped products; every other
-platform keeps XLA's gather back.
+"""The routed block's rows on the TPU as Pallas DMA kernels. The way
+back, ``combine``, is two: each real assignment's row of the grouped
+products' output, in expert order, is moved by one DMA to its place in
+assignment order (``_scatter``), and a span's k rows are then read in
+bulk, weighed in float32 and summed in the order j = 0..k-1 (``_weigh``).
+Rows of slots that hold no span are never moved. The way in under a held
+share of the experts, ``into_expert_order``, is one (``_gather``): each
+held choice's span row is moved by one DMA to its place in expert order,
+and no other row is. ``routed_experts`` (``layers.py``) runs
+``combine`` on the TPU after the grouped products, and
+``into_expert_order`` before them where the device holds a share of the
+experts; every other platform keeps XLA's gathers.
 
 Why not XLA's gather: it reads rows at memory speed from a table the chip
-can hold close, as the span rows into expert order are (spans x d, 84 MB
-in the 256-row call), and five times slower from one it cannot, as the
-expert rows back are (spans x k x d, 503 MB; PERF.md section 6).
+can hold close, as the span rows into expert order are where every expert
+is held (spans x d, 84 MB in the 256-row call: 0.80 ms a layer, where a
+DMA way in ran 5.81), and five times slower from one it cannot, as the
+expert rows back are (spans x k x d, 503 MB; PERF.md section 6). And it
+writes every slot's k rows, where under a held share most of them are
+choices of experts held elsewhere, which nothing reads (15 of 16 at
+K-EXAONE's share of 8 of 128 experts).
 
 A bfloat16 row is not a unit a DMA can move in XLA's tiled layout: a tile
 is 16 rows x 128 columns and two rows share each 32-bit word. What a DMA
@@ -21,7 +29,10 @@ word c of a row holds its column c in the low half and column c + d/2 in
 the high half (a bfloat16 is the high half of the float32 it widens to,
 so either half widens with one shift or one mask).
 
-Both take ``interpret`` for the CPU (tests); their tiles follow from d, k
+The way in splits the span rows into word rows the same way, once, and
+joins the two rows of a word back in VMEM before a tile leaves.
+
+All take ``interpret`` for the CPU (tests); their tiles follow from d, k
 and the row counts they are given.
 """
 
@@ -176,6 +187,146 @@ def _scatter(y: jax.Array, dest: jax.Array, n_real: jax.Array, rows: int,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(jnp.reshape(n_real, (1,)).astype(jnp.int32), by_tile, y)
+
+
+def _gather(words: jax.Array, src: jax.Array, n_held: jax.Array, dtype,
+            interpret: bool) -> jax.Array:
+    """Word rows (as the module says; (a, d/256, 8, 1, 128) pieces,
+    ``_rows``) to rows of ``dtype`` in the order ``src`` (m,) names: row r
+    of the (m, d) result for r < ``n_held`` is word row ``src[r]`` joined,
+    one DMA a row. A tile of the result past ``n_held`` is neither written
+    nor zeroed; the rows of the last tile written past ``n_held`` hold
+    whatever the tile's staging held.
+
+    A tile's row DMAs are issued in the grid step of that tile and waited
+    for in the next one, which joins the tile's two rows a word into
+    ``dtype``, as the previous tile's rows land, and sends it out whole."""
+    _, pieces, _, _, _ = words.shape
+    W = pieces * LANES
+    m = src.shape[0]
+    tile = _tile(m, 512)
+    n_tiles = m // tile
+    by_tile = src.reshape(n_tiles, 1, tile)
+
+    def kernel(n_ref, src_ref, words_hbm, out_hbm, got, rows, row_sem,
+               out_sem, state):
+        i = pl.program_id(0)
+        slot = i % 2
+        n = n_ref[0]
+
+        def row(t, r, s):
+            # word row t to row r of the tile, with the rows of its parity
+            return pltpu.make_async_copy(
+                words_hbm.at[pl.ds(t >> 3, 1), :, pl.ds(t & 7, 1)],
+                got.at[s, r & 1, pl.ds(r >> 4, 1), :, pl.ds((r >> 1) & 7, 1)],
+                row_sem.at[s])
+
+        def out(tile_no, s):
+            return pltpu.make_async_copy(
+                rows.at[s], out_hbm.at[pl.ds(tile_no * tile, tile)],
+                out_sem.at[s])
+
+        def finish(tile_no, s):
+            def landed(_, c):
+                row(0, 0, s).wait()
+                return c
+            jax.lax.fori_loop(0, state[s], landed, 0)
+
+            @pl.when(state[2 + s] > 0)
+            def _():
+                out(0, s).wait()   # the tile this slot sent two tiles ago
+
+            def join(a, c):
+                # word rows 8a..8a+7 of the even and of the odd rows to the
+                # tile's rows 16a..16a+15, two a word
+                at = pl.ds(pl.multiple_of(a * 16, 16), 16)
+                for p in range(pieces):
+                    even = got[s, 0, a, p, :, 0, :]
+                    odd = got[s, 1, a, p, :, 0, :]
+                    rows[s, at, pl.ds(p * LANES, LANES)] = pltpu.bitcast(
+                        (even & LOW) | (odd << 16), dtype)
+                    rows[s, at, pl.ds(W + p * LANES, LANES)] = pltpu.bitcast(
+                        (even >> 16) | (odd & HIGH), dtype)
+                return c
+            jax.lax.fori_loop(0, tile // 16, join, 0)
+            out(tile_no, s).start()
+            state[2 + s] = 1
+
+        @pl.when(i == 0)
+        def _():
+            for at in range(4):   # rows issued, then a tile sent, a slot
+                state[at] = 0
+
+        @pl.when(i * tile < n)
+        def _():
+            def issue(r, c):
+                row(src_ref[0, 0, r], r, slot).start()
+                return c
+            count = jnp.minimum(tile, n - i * tile)   # held rows: a prefix
+            jax.lax.fori_loop(0, count, issue, 0)
+            state[slot] = count
+
+        @pl.when((i > 0) & ((i - 1) * tile < n))
+        def _():
+            finish(i - 1, 1 - slot)
+
+        @pl.when(i == n_tiles - 1)
+        def _():
+            @pl.when(i * tile < n)
+            def _():
+                finish(i, slot)
+
+            for s in range(2):
+                @pl.when(state[2 + s] > 0)
+                def _():
+                    out(0, s).wait()
+
+    def current(i, n_ref):
+        # the last tile that holds a row: past it the indices stay put
+        return (jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0) // tile), 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((m, 2 * W), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_tiles,),
+            in_specs=[pl.BlockSpec((1, 1, tile), current,
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((2, 2, tile // 16, pieces, 8, 1,
+                                        LANES), jnp.uint32),
+                            pltpu.VMEM((2, tile, 2 * W), dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((4,), jnp.int32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret, name="into_expert_order",
+    )(jnp.reshape(n_held, (1,)).astype(jnp.int32), by_tile, words)
+
+
+def into_expert_order(h: jax.Array, order: jax.Array, k: int,
+                      n_held: jax.Array, rows: int,
+                      interpret: bool = False) -> jax.Array:
+    """The way in under a held share: ``out[r] = h[order[r] // k]`` for
+    r < ``n_held``, the held choices, which the sort put first; ``h`` (T,
+    d) bfloat16, ``order`` (T k,) the sorted order (as ``combine`` has
+    it). Returns (``rows``, d), a whole number of the grouped products'
+    tiles; its rows from ``n_held`` on are nobody's, and the tiles past
+    the last held row are not written at all."""
+    T, d = h.shape
+    W = d // 2
+    spans = -(-T // 8) * 8
+    # each span row as a word row: column c low, column c + d/2 high
+    lo, hi = (jax.lax.bitcast_convert_type(part, jnp.uint16).astype(
+        jnp.uint32) for part in (h[:, :W], h[:, W:]))
+    words = jnp.pad(lo | (hi << 16), ((0, spans - T), (0, 0)))
+    words = words.reshape(spans // 8, 8, W // LANES, LANES).transpose(
+        0, 2, 1, 3).reshape(spans // 8, W // LANES, 8, 1, LANES)
+    src = jnp.pad(order // k, (0, rows - order.shape[0]))
+    return _gather(words, src.astype(jnp.int32), n_held, h.dtype, interpret)
 
 
 def _weigh(words: jax.Array, weight: jax.Array, real: jax.Array, dtype,

@@ -483,9 +483,11 @@ def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(
     XLA's own ragged-dot kernel (which keeps no scope in the trace); the
     rows come back by the route's two DMA kernels under ``route`` (to
     assignment order, then weighed and summed), so that of XLA's gathers
-    of the model's width only the one into expert order is left; and the
-    temporaries stay under ``most`` (1.5 GB at widths to 2560; the share's
-    every slot's eight rows of 6144 in expert order are 1.6 GB alone)."""
+    of the model's width only the one into expert order is left, and
+    under a held share not that one either: the rows go in by a third DMA
+    kernel under ``route``; and the temporaries stay under ``most`` (1.5
+    GB at widths to 2560; the share's eight rows of 6144 a slot in expert
+    order are 1.6 GB alone)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     T = 256 * 64
@@ -512,15 +514,17 @@ def test_the_routed_experts_compile_for_the_chip_as_pallas_kernels(
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     text = compiled.as_text()
+    way_in = held < E
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "pallas_call" in line]
-    assert len(kernels) == 5
+    assert len(kernels) == 5 + way_in
     mlp = [line for line in kernels if "/mlp/" in line]
     route = [line for line in kernels if "/route/" in line]
     assert len(mlp) == 3 and all("gmm" in line for line in mlp)
-    assert len(route) == 2
+    assert len(route) == 2 + way_in
+    assert sum("into_expert_order" in line for line in route) == way_in
     assert sum(f",{d}]" in line and " gather(" in line
-               for line in text.splitlines()) == 1
+               for line in text.splitlines()) == 1 - way_in
     assert "ragged-dot" not in text and "ragged_dot" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < most
 
@@ -595,8 +599,9 @@ def test_four_chips_each_route_their_own_rows(four_chips, arch):
     refuses a bare Pallas kernel ("Mosaic kernels cannot be automatically
     partitioned"), so each device runs the routed feed-forward on its own
     rows, five kernels a layer (the three grouped products and the two of
-    the way back, at the width they take), and the only exchange of the
-    stack is the sum of the counts."""
+    the way back, at the width they take; six under the post-norm block's
+    held share, whose rows go in by DMA too), and the only exchange of
+    the stack is the sum of the counts."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from odigos_tpu.parallel.sharding import compile_plan
@@ -626,8 +631,9 @@ def test_four_chips_each_route_their_own_rows(four_chips, arch):
         jax.config.update("jax_enable_compilation_cache", True)
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "pallas_call" in line]
-    assert len(kernels) == 5 * (small["n_layers"]
-                                - small.get("dense_layers", 0))
+    per_layer = 5 + ("experts_held" in small)
+    assert len(kernels) == per_layer * (small["n_layers"]
+                                        - small.get("dense_layers", 0))
     assert "all-gather" not in text and "all-to-all" not in text
 
 

@@ -379,6 +379,53 @@ def test_the_way_back_by_dma_takes_a_held_share(spans, first):
     assert not np.isnan(np.asarray(got, np.float32)).any()
 
 
+def held_order(spans, k, case, seed=0):
+    """(order, n_held): a sorted order whose first ``n_held`` rows are the
+    held choices. ``case``: "share", ``held_routing``'s share of 4 of 32
+    experts; "one", one held expert that every span chose, as its third
+    choice (a span's row each, in span order); "none", "every" or a
+    count, that many rows of a shuffled order."""
+    if case == "share":
+        _, mine, order, _ = held_routing(spans, k, 32, 8, 4,
+                                         [True] * spans, seed)
+        return order, int(jnp.sum(mine))
+    if case == "one":
+        expert = jnp.tile(jnp.asarray([1, 1, 0] + [1] * (k - 3)), spans)
+        return jnp.argsort(expert, stable=True), spans
+    n_held = {"none": 0, "every": spans * k}.get(case, case)
+    return jnp.asarray(np.random.default_rng(seed).permutation(spans * k)), \
+        n_held
+
+
+@pytest.mark.parametrize("spans, d, case", [
+    (200, 512, "none"), (64, 512, "every"),
+    (200, 512, 603),                # neither 16 nor a tile divides it
+    (256, 512, "share"), (72, 512, "one"),
+    (64, 6144, "share"), (40, 6144, "one")])
+def test_the_way_in_by_dma_moves_the_held_rows_alone(spans, d, case):
+    """The kernel's way in (``route_kernels.into_expert_order``, interpret
+    mode): the rows up to the last held choice are XLA's gather's,
+    ``h[order // k]``, bit for bit; the tiles past it (of 512 rows, the
+    grouped products' tile) are not written at all: the interpreter
+    starts the output as NaN, and NaN is what they hold."""
+    k = 8
+    order, n_held = held_order(spans, k, case)
+    rows = -(-spans * k // layers.GROUP_ROWS) * layers.GROUP_ROWS
+    rng = np.random.default_rng(spans + d)
+    h = jnp.asarray(rng.normal(size=(spans, d)), jnp.bfloat16)
+    got = route_kernels.into_expert_order(h, order, k, jnp.int32(n_held),
+                                          rows, interpret=True)
+    assert got.shape == (rows, d) and got.dtype == jnp.bfloat16
+    want = layers._into_expert_order(h, order, k)
+
+    def bits(a):
+        return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))
+
+    assert np.array_equal(bits(got[:n_held]), bits(want[:n_held]))
+    unwritten = -(-n_held // layers.GROUP_ROWS) * layers.GROUP_ROWS
+    assert np.isnan(np.asarray(got[unwritten:], np.float32)).all()
+
+
 def held_xla_back(y, order, weight, mine, dtype):
     """XLA's gather back and weighted sum under a held share, as
     ``_back_by_gather`` has them: a choice not held is taken out whole."""
@@ -389,11 +436,13 @@ def held_xla_back(y, order, weight, mine, dtype):
 
 
 def test_the_tpus_branch_takes_a_held_share():
-    """The TPU's branch of ``routed_experts`` under a held share (XLA's
-    gather in, the ``gmm`` products over the held assignments alone, the
-    DMA way back), in interpret mode, against the same products between
-    XLA's two gathers; and the rows each moved: every slot's k rows in, a
-    held assignment's back."""
+    """The TPU's branch of ``routed_experts`` under a held share (the DMA
+    way in, the ``gmm`` products over the held assignments alone, the DMA
+    way back), in interpret mode, against the same products between XLA's
+    two gathers; and the rows each moved: a held assignment's in and
+    back, where XLA's gathers move every slot's k rows each way. The way
+    in leaves NaN (the interpreter's unwritten memory) in the tiles past
+    the last held row, and none of it reaches the output."""
     spans, k = 200, 8
     real = [i % 7 != 2 for i in range(spans)]
     weight, mine, order, load_ = held_routing(spans, k, 32, 8, 4, real, 1)
@@ -402,15 +451,78 @@ def test_the_tpus_branch_takes_a_held_share():
     gate, up = (jnp.asarray(rng.normal(size=(4, D, F)) / 16, jnp.bfloat16)
                 for _ in range(2))
     down = jnp.asarray(rng.normal(size=(4, F, D)) / 11, jnp.bfloat16)
+    into = route_kernels.into_expert_order(h, order, k, load_.sum(),
+                                           2048, interpret=True)
+    assert int(load_.sum()) < 512       # the first tile of four written
+    assert np.isnan(np.asarray(into[512:], np.float32)).all()
     args = (h, order, weight, mine, load_, gate, up, down, jax.nn.silu)
     got, moved = layers._back_by_dma(*args, interpret=True)
     want, all_moved = layers._back_by_gather(
         *args, partial(layers._experts_gmm, interpret=True))
+    assert not np.isnan(np.asarray(got, np.float32)).any()
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), rtol=2**-7,
                                atol=2**-12)
-    assert int(moved) == spans * k + int(load_.sum())
+    assert int(moved) == 2 * int(load_.sum())
     assert int(all_moved) == 2 * spans * k
+
+
+def stripped_program(arch: str, counted: bool) -> str:
+    """The model at ``arch``'s ``SMALL`` (d 256, bfloat16: the width the
+    route's kernels take) lowered for the TPU, on eight packed rows, each
+    Pallas kernel's body printed without locations and digested: a
+    kernel's serialized body carries its caller's file and line, which an
+    edit above it moves."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    small = {**arch_case(arch)[1].SMALL, "d_model": D, "dtype": "bfloat16"}
+    model = TraceTransformer(make_model_config("transformer", small))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    S, L = jax.ShapeDtypeStruct, small["max_len"]
+    score = model.score_packed_counted if counted else model.score_packed
+    text = jax.jit(score).trace(
+        shapes, S((8, L, 5), jnp.int32), S((8, L, 3), jnp.float32),
+        S((8, L), jnp.int32), S((8, L), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    def body(m):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(m.group(1))).operation \
+                .get_asm(enable_debug_info=False)
+        return hashlib.sha256(asm.encode()).hexdigest()
+
+    return re.sub(r"(?<=\\22body\\22: \\22)([A-Za-z0-9+/=]+)", body, text)
+
+
+# the whole-range routed blocks' programs for the TPU (``stripped_program``,
+# sha256, the first 16 digits), plain and counted: what they lowered to
+# before the way in by DMA, which the held share alone takes
+WHOLE_RANGE = {("moe_decoder", False): "9bdf14729b4a81ce",
+               ("moe_decoder", True): "3a58f33877fa3587",
+               ("latent_moe_decoder", False): "3f9a8b3499210efd",
+               ("latent_moe_decoder", True): "1f5143331642c77a"}
+
+
+@pytest.mark.parametrize("arch, counted", sorted(WHOLE_RANGE))
+def test_the_whole_range_lowers_as_before_the_way_in_by_dma(arch, counted):
+    import hashlib
+
+    text = stripped_program(arch, counted)
+    assert "into_expert_order" not in text
+    assert "tpu_custom_call" in text
+    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert digest == WHOLE_RANGE[arch, counted]
+
+
+def test_a_held_share_lowers_the_way_in_by_dma():
+    text = stripped_program("postnorm_moe_decoder", True)
+    assert text.count('kernel_name = "into_expert_order"') == 1
 
 
 # ------------------------------------------------------------- the sizes
